@@ -22,6 +22,8 @@ Layering (mirrors SURVEY.md §7):
   perf/       — scheduler_perf-style benchmark harness
 """
 
+import os
+
 import jax
 
 # Score and resource arithmetic is int64 for bit-identical parity with the
@@ -38,11 +40,22 @@ jax.config.update("jax_enable_x64", True)
 jax.config.update("jax_default_matmul_precision", "highest")
 
 # Persist XLA compilations across processes: the batch pass compiles once per
-# (profile, schema, batch-size) and those shapes are stable run-to-run.
-try:  # pragma: no cover - best effort on experimental backends
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_kubernetes_tpu")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:  # noqa: BLE001
-    pass
+# (profile, schema, batch-size) and those shapes are stable run-to-run.  The
+# directory is part of the cache key, so it is a fixed place: wherever
+# JAX_COMPILATION_CACHE_DIR says (jax reads the variable itself; no directory
+# is set in code then), else .jax_cache beside the package.
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache",
+        ),
+    )
+# Every program persists, not only those that took over a second: a
+# process on a machine that is thrown away (or a serve child of a test)
+# otherwise recompiles the dozens of small ones each time it starts, and a
+# program near the threshold lands in the cache on some runs and not others.
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 __version__ = "0.1.0"

@@ -329,7 +329,20 @@ def cmd_serve(args) -> int:
         journal_lease, journal = _open_journal(
             args.journal_dir, fsync=args.journal_fsync == "always"
         )
+    # The device contract (utils.require_device): asked only once the
+    # leases are held — a parked standby must not sit on the chip — and
+    # before the socket binds, so nothing ever listens without a device.
+    # The health frame and /healthz carry what THIS process got.
+    from .utils import require_device
+
+    device = require_device()
+    print(
+        f"device: platform={device['platform']} "
+        f"kind={device['device_kind']} count={device['n_devices']}",
+        flush=True,
+    )
     health = {"leader": True, "leaseFile": args.lease_file} if lease else {}
+    health.update(device)
     if journal is not None:
         health["journalDir"] = args.journal_dir
     if fleet_owner is not None:
@@ -419,6 +432,13 @@ def cmd_recover(args) -> int:
                 for uid, pr in sorted(sched.cache.pods.items())
                 if pr.bound
             },
+            # Journaled binds whose node no snapshot holds yet: durable,
+            # parked until the host relists the node (the journal keeps
+            # decisions, the host keeps the cluster).
+            "pending_bindings": {
+                uid: d["node"]
+                for uid, d in sorted(sched._recovered_bindings.items())
+            },
         }
         print(json.dumps(summary, indent=2, sort_keys=True))
     finally:
@@ -427,7 +447,7 @@ def cmd_recover(args) -> int:
 
 
 def cmd_bench(args) -> int:
-    from .benchmarks.harness import main as bench_main
+    from .benchmarks.harness import main as bench_main, row_failed
 
     if args.profile_dir:
         # Device-side visibility (SURVEY §5: "add JAX profiler traces on
@@ -435,11 +455,11 @@ def cmd_bench(args) -> int:
         import jax
 
         with jax.profiler.trace(args.profile_dir):
-            bench_main(args.workloads or None)
+            rows = bench_main(args.workloads or None)
         print(f"jax profiler trace written to {args.profile_dir}")
     else:
-        bench_main(args.workloads or None)
-    return 0
+        rows = bench_main(args.workloads or None)
+    return 1 if any(row_failed(r) for r in rows) else 0
 
 
 def _parse_hetero_pools(spec: str) -> tuple:

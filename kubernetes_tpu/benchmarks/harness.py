@@ -204,6 +204,12 @@ def run_workload(
         "batches": m.batches,
         "preemptions": m.preemptions,
         "deferred": m.deferred,
+        # A row that quarantined pods or recovered from an engine fault
+        # measured the recovery path, not the workload: the entry points
+        # exit non-zero on either (the registry was reset after warmup,
+        # so these cover the measured window).
+        "engine_faults": int(sched._engine_fault_counter.total()),
+        "quarantined": sched.queue.depths()["quarantine"],
         # Conflict-aware packing + carried DomTables (ISSUE 13): how many
         # measured batches reordered, the residual same-chunk collisions
         # their plans accepted, and the carry hit/rebuild split — the
@@ -1268,23 +1274,44 @@ _register(
 )
 
 
+def row_failed(r: dict) -> bool:
+    """A result row that must fail its run: the child died or hung
+    (``error``), or the measured window hit the engine-fault recovery
+    path."""
+    return bool(
+        r.get("error") or r.get("engine_faults") or r.get("quarantined")
+    )
+
+
 def main(
     names: list[str] | None = None, pipeline_depth: int | None = None
 ) -> list[dict]:
+    """Run workloads IN THIS PROCESS (the sweep's subprocess leaf, and
+    ``python -m kubernetes_tpu bench``).  Asks for the device first —
+    no accelerator is an error unless JAX_PLATFORMS names cpu — and
+    every printed row names it."""
+    from ..utils import require_device
+
     if names:
         unknown = [n for n in names if n not in WORKLOADS]
         if unknown:
             raise SystemExit(
                 f"unknown workload(s): {unknown}; available: {sorted(WORKLOADS)}"
             )
+    device = require_device()
     results = []
     for name, w in WORKLOADS.items():
         if names and name not in names:
             continue
-        r = run_workload(w, pipeline_depth=pipeline_depth)
+        r = {**run_workload(w, pipeline_depth=pipeline_depth), **device}
         print(json.dumps(r), flush=True)
         results.append(r)
     return results
+
+
+# Per-row wall limit of the isolated sweep: the slowest recorded row is
+# minutes, so a child past this is hung, not slow.
+ROW_TIMEOUT_S = 1800.0
 
 
 def main_isolated(
@@ -1293,9 +1320,12 @@ def main_isolated(
     """Run each workload in a FRESH subprocess — the sweep analog of
     scheduler_perf's per-case process isolation.  A long-lived process
     accumulates host allocator/GC pressure that degrades later workloads
-    ~1.5-2× versus their solo numbers (r2: secrets 16× in-sweep vs 29×
-    solo); XLA compiles stay warm across processes via the persistent
-    compilation cache (kubernetes_tpu/__init__.py)."""
+    versus their solo numbers; XLA compiles stay warm across processes
+    via the persistent compilation cache (kubernetes_tpu/__init__.py).
+    This parent never touches the device — each child is, in turn, the
+    one process that holds it.  A child that dies or outlasts
+    ROW_TIMEOUT_S becomes an ``error`` row and the sweep carries on;
+    the caller exits non-zero if any row failed (``row_failed``)."""
     import subprocess
     import sys as _sys
 
@@ -1308,6 +1338,9 @@ def main_isolated(
             raise SystemExit(
                 f"unknown workload(s): {unknown}; available: {sorted(known)}"
             )
+    from ..utils import refuse_if_holding_device
+
+    refuse_if_holding_device("a benchmark child")
     selected = [
         n for n in list(WORKLOADS) + list(INTEGRATED) if not names or n in names
     ]
@@ -1332,18 +1365,25 @@ def main_isolated(
                 "(serve child runs at default depth)",
                 file=_sys.stderr,
             )
-        proc = subprocess.run(argv, capture_output=True, text=True)
-        line = ""
-        for ln in proc.stdout.splitlines():
-            ln = ln.strip()
-            if ln.startswith("{"):
-                line = ln
-        if not line:
-            line = json.dumps(
-                {"name": name, "error": (proc.stderr or "no output")[-400:]}
+        try:
+            proc = subprocess.run(
+                argv, capture_output=True, text=True, timeout=ROW_TIMEOUT_S
             )
-        print(line, flush=True)
-        results.append(json.loads(line))
+        except subprocess.TimeoutExpired:
+            row = {"name": name, "error": f"timed out after {ROW_TIMEOUT_S}s"}
+        else:
+            lines = [
+                ln.strip() for ln in proc.stdout.splitlines()
+                if ln.strip().startswith("{")
+            ]
+            row = json.loads(lines[-1]) if lines else {"name": name}
+            if (proc.returncode or not lines) and not row_failed(row):
+                row["error"] = (
+                    f"rc={proc.returncode}: "
+                    + (proc.stderr or "no output")[-400:]
+                )
+        print(json.dumps(row), flush=True)
+        results.append(row)
     return results
 
 
@@ -1357,11 +1397,10 @@ if __name__ == "__main__":
         depth = int(args[i + 1])
         args = args[:i] + args[i + 2:]
     if args and args[0] == "--isolated":
-        main_isolated(args[1:] or None, pipeline_depth=depth)
-    elif len(args) == 1:
-        # single workload: in-process (the subprocess leaf)
-        main(args, pipeline_depth=depth)
+        rows = main_isolated(args[1:] or None, pipeline_depth=depth)
     elif not args:
-        main_isolated(None, pipeline_depth=depth)  # default sweep
+        rows = main_isolated(None, pipeline_depth=depth)  # default sweep
     else:
-        main(args, pipeline_depth=depth)
+        # named workload(s): in-process (the subprocess leaf)
+        rows = main(args, pipeline_depth=depth)
+    sys.exit(1 if any(row_failed(r) for r in rows) else 0)

@@ -236,11 +236,14 @@ INTEGRATED = {
 
 
 def main(names=None):
+    from ..utils import require_device
+
+    device = require_device()
     results = []
     for name, kw in INTEGRATED.items():
         if names and name not in names:
             continue
-        r = run_integrated(name, **kw)
+        r = {**run_integrated(name, **kw), **device}
         print(json.dumps(r), flush=True)
         results.append(r)
     return results

@@ -9,8 +9,8 @@ dependence on each other once the device pass has been dispatched:
 - **device(k)** — the compiled pass, running asynchronously on the
   accelerator from dispatch until the completion fetch;
 - **commit/journal(k-1)** — host bookkeeping plus the write-ahead
-  journal's durability barrier (the fsync bill BENCH_r06 measured at
-  37.8s of a 76.2s wall).
+  journal's durability barrier (one fsync per append, before group
+  commit, was about half the serial loop's wall on the CPU box).
 
 This module supplies the two pieces that turn the loop into a real
 pipeline (the generalization of PR 6's ``post_dispatch_hook``

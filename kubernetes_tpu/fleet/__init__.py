@@ -1,8 +1,8 @@
 """Partitioned scheduler fleet: N scheduler processes, each owning a
 disjoint node shard behind its own lease-epoch fence and WAL journal.
 
-The single-process scheduler is fast (BENCH_r05: 10k pods/s at 5k
-nodes), but millions of users means more than one scheduler process.
+The single-process scheduler schedules a whole batch per device pass,
+but millions of users means more than one scheduler process.
 This package composes the primitives PRs 3–6 built — `FileLease` epoch
 fencing, the write-ahead journal, the flight recorder, the soak harness
 — into a horizontally scalable control plane, the shape Tesserae

@@ -931,9 +931,11 @@ def _launch_serve(
     a per-child LOG FILE in the artifact directory, never an unread
     PIPE — a chatty child (cycle-span logging, takeover restarts) would
     otherwise block on a full pipe mid-soak and read as a hung owner."""
+    from ..utils import refuse_if_holding_device
+
+    refuse_if_holding_device(f"serve child {label!r}")
     log_path = os.path.join(out_dir, f"{label}.log")
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env["TPU_FLIGHT_DIR"] = out_dir
     log = open(log_path, "a", encoding="utf-8")
     try:

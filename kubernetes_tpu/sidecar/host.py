@@ -54,6 +54,21 @@ def _key(kind: str, obj) -> str:
     return obj.uid if kind == "Pod" else obj.name
 
 
+def _pin_fallback_to_cpu() -> None:
+    """The degraded engine is HOST-side evaluation: a client that shares
+    a machine with its sidecar must not race it for the chip (the sidecar
+    holds it, and a second process that asks for it fails or hangs).  A
+    process that has not initialised a JAX backend yet is held to the CPU
+    one here; a process that already has one keeps it — the race is over
+    either way."""
+    import jax
+
+    from ..utils import backend_initialized
+
+    if not backend_initialized():
+        jax.config.update("jax_platforms", "cpu")
+
+
 class BreakerOpen(ConnectionError):
     """The circuit breaker tripped: the sidecar keeps failing and calls
     now degrade to host-side evaluation instead of hammering it."""
@@ -121,7 +136,7 @@ class ResyncingClient:
         )
         # Wire round-trip attribution (the host half of the flight
         # recorder's phase story: what the sidecar's own phases can't see
-        # is the tunnel + retry + resync cost of reaching it).
+        # is the socket + retry + resync cost of reaching it).
         self._rt_hist = self.registry.histogram(
             "scheduler_sidecar_round_trip_duration_seconds",
             "Wire round-trip duration of sidecar calls (retries and "
@@ -436,6 +451,7 @@ class ResyncingClient:
         if self._fallback is None:
             from ..scheduler import TPUScheduler
 
+            _pin_fallback_to_cpu()
             fb = (self.fallback_factory or TPUScheduler)()
             for ns, labels in self._ns_labels.items():
                 fb.builder.set_namespace_labels(ns, dict(labels))

@@ -1,12 +1,9 @@
 #!/usr/bin/env python
 """Declarative bench/SLO regression sentinel (ISSUE 16 tentpole c).
 
-PR 3's journal_guard and PR 11's flagship floor were two hand-rolled
-ad-hoc checks; this generalizes them into ONE declarative guard table
-evaluated over the committed BENCH_*/SOAK_*/OBS_TAX trajectory:
+ONE declarative guard table evaluated over a bench.py payload and the
+committed SOAK_*/OBS_TAX artifacts:
 
-  headline           ratio vs the newest committed bench point
-  flagship           ratio vs its newest committed point
   journal_fsyncs     group commit must stay group commit (a per-append
                      fsync regression is ~3 orders of magnitude)
   overlap_coverage   the pipeline's overlap must stay engaged
@@ -21,18 +18,21 @@ evaluated over the committed BENCH_*/SOAK_*/OBS_TAX trajectory:
   lint_suppressions  tpulint suppression budget (pragmas are documented
                      exceptions, not a pressure valve)
 
-Each guard has a WARN boundary (reported, tunnel weather happens — see
-README measurement discipline) and a HARD floor (exit 1: beyond any
-weather, a real regression).  ``bench.py`` embeds the same evaluation as
-a ``sentinel`` block in every payload it prints, and the tier-1 gate
-runs ``--check`` against the committed trajectory — a regressing PR
-fails BEFORE it records an artifact.
+There is no throughput-ratio row: the table guards structure and
+recorded gates, and speed is judged from chip runs of the benchmark
+(ROADMAP S0), not against a committed CPU-box number.
+
+Each guard has a WARN boundary (reported) and a HARD floor (exit 1: a
+real regression).  ``bench.py`` embeds the same evaluation as a
+``sentinel`` block in every payload it prints, and the tier-1 gate runs
+``--check`` against the committed artifacts — a regressing PR fails
+BEFORE it records an artifact.
 
 Stdlib-only (loaded by file path from bench.py and the tier-1 test):
 
     python scripts/bench_sentinel.py --check
     python scripts/bench_sentinel.py --payload fresh_payload.json
-    JAX_PLATFORMS=cpu python bench.py | python scripts/bench_sentinel.py --payload -
+    python bench.py | python scripts/bench_sentinel.py --payload -
 """
 
 from __future__ import annotations
@@ -49,7 +49,6 @@ REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 # The guard table.  ``value`` paths index into the bench payload under
 # test; ``source`` guards read their value from a committed artifact
 # family instead (newest round wins).  Ops:
-#   ratio_min — value / reference must stay >= warn (warn) / hard (fail)
 #   ratio_paths_max — value / denom (``denom_path``, SAME source doc)
 #               must stay <= warn / hard — for artifacts that record
 #               their own baseline next to the measurement
@@ -58,24 +57,6 @@ REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 # ``budget_key`` (slo_p99) scales warn/hard off the payload's recorded
 # budget instead of a constant.
 GUARDS = (
-    {
-        "name": "headline",
-        "value": ("value",),
-        "reference": {"family": "BENCH_r*.json", "path": ("value",)},
-        "op": "ratio_min",
-        "warn": 0.95,
-        "hard": 0.70,
-        "why": "headline pods/s vs the newest committed trajectory point",
-    },
-    {
-        "name": "flagship",
-        "value": ("flagship", "value"),
-        "reference": {"family": "BENCH_r*.json", "path": ("flagship", "value")},
-        "op": "ratio_min",
-        "warn": 0.95,
-        "hard": 0.70,
-        "why": "interpodaffinity worst case vs its newest committed point",
-    },
     {
         "name": "journal_fsyncs",
         "value": ("detail", "journal", "fsyncs"),
@@ -333,30 +314,6 @@ def _eval_guard(guard: dict, payload: dict | None, root: str) -> dict:
             out["missing"] = "/".join(guard["budget_key"])
             return out
         warn, hard = warn * budget, hard * budget
-    if guard["op"] == "ratio_min":
-        ref_path = newest_artifact(root, guard["reference"]["family"])
-        if ref_path is None:
-            out["status"] = "missing"
-            out["missing"] = guard["reference"]["family"]
-            return out
-        out["reference_file"] = os.path.basename(ref_path)
-        try:
-            ref = _dig(load_payload(ref_path), guard["reference"]["path"])
-        except (OSError, ValueError):
-            ref = None
-        if not ref:
-            out["status"] = "missing"
-            out["missing"] = "/".join(guard["reference"]["path"])
-            return out
-        out["reference"] = ref
-        ratio = float(value) / float(ref)
-        out["ratio"] = round(ratio, 4)
-        out["warn_below"], out["hard_below"] = warn, hard
-        if ratio < hard:
-            out["status"] = "hard_fail"
-        elif ratio < warn:
-            out["status"] = "warn"
-        return out
     if guard["op"] == "ratio_paths_max":
         if not denom:
             out["status"] = "missing"
@@ -389,10 +346,14 @@ def _eval_guard(guard: dict, payload: dict | None, root: str) -> dict:
 
 
 def evaluate(payload: dict | None, root: str = REPO) -> dict:
-    """Evaluate the guard table against one bench payload (None = the
-    artifact-only guards).  The returned block is what bench.py embeds
-    as ``payload["sentinel"]``."""
-    guards = [_eval_guard(g, payload, root) for g in GUARDS]
+    """Evaluate the guard table against one bench payload.  ``None`` =
+    only the guards that read no payload (committed artifacts and the
+    live tree).  The returned block is what bench.py embeds as
+    ``payload["sentinel"]``."""
+    guards = [
+        _eval_guard(g, payload, root) for g in GUARDS
+        if payload is not None or "value" not in g
+    ]
     hard = [g["name"] for g in guards if g["status"] == "hard_fail"]
     warns = [g["name"] for g in guards if g["status"] == "warn"]
     missing = [g["name"] for g in guards if g["status"] == "missing"]
@@ -406,17 +367,12 @@ def evaluate(payload: dict | None, root: str = REPO) -> dict:
 
 
 def check_committed(root: str = REPO) -> dict:
-    """``--check``: the tier-1 gate.  The newest committed bench point
-    IS the payload under test — the ratio guards degenerate to 1.0 (the
-    trajectory cannot regress against itself) while the absolute floors
-    (fsync count, overlap coverage, SLO budget, obs tax) re-verify that
-    the committed artifacts still clear the table; any unreadable or
-    schema-drifted artifact surfaces as ``missing``."""
-    newest = newest_artifact(root, "BENCH_r*.json")
-    payload = load_payload(newest) if newest else None
-    block = evaluate(payload, root)
-    block["checked"] = os.path.basename(newest) if newest else None
-    return block
+    """``--check``: the tier-1 gate.  No payload: the artifact-sourced
+    guards (obs tax, the fairness and production soaks) re-verify that
+    the committed artifacts still clear the table and the live guards
+    measure the tree; any unreadable or schema-drifted artifact surfaces
+    as ``missing``."""
+    return evaluate(None, root)
 
 
 def _print_table(block: dict) -> None:
@@ -424,14 +380,10 @@ def _print_table(block: dict) -> None:
         mark = {"pass": "ok  ", "warn": "WARN", "hard_fail": "FAIL",
                 "missing": "miss"}[g["status"]]
         if "ratio" in g:
-            lim = (
-                f"warn>{g['warn_above']} hard>{g['hard_above']}"
-                if "warn_above" in g
-                else f"warn<{g['warn_below']} hard<{g['hard_below']}"
-            )
-            src = g.get("reference_file") or g.get("source_file", "?")
+            lim = f"warn>{g['warn_above']} hard>{g['hard_above']}"
             detail = (
-                f"ratio {g['ratio']} vs {g.get('reference')} ({src}; {lim})"
+                f"ratio {g['ratio']} vs {g.get('reference')} "
+                f"({g.get('source_file', '?')}; {lim})"
             )
         elif "value" in g:
             lim = (
@@ -451,7 +403,7 @@ def main(argv=None) -> int:
     mode = ap.add_mutually_exclusive_group(required=True)
     mode.add_argument(
         "--check", action="store_true",
-        help="evaluate the committed trajectory (the tier-1 gate)",
+        help="evaluate the committed artifacts (the tier-1 gate)",
     )
     mode.add_argument(
         "--payload", metavar="FILE",
@@ -479,12 +431,10 @@ def main(argv=None) -> int:
         print(json.dumps(block, indent=1, sort_keys=True))
     else:
         _print_table(block)
-        if block.get("checked"):
-            print(f"sentinel: checked {block['checked']}")
     if block["hard_failures"]:
         print(
             f"sentinel: HARD FAIL — {', '.join(block['hard_failures'])} "
-            "breached the floor (beyond tunnel variance)",
+            "breached the floor",
             file=sys.stderr,
         )
         return 1

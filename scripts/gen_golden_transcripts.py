@@ -31,9 +31,9 @@ import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
+# CPU-only, on purpose: the fixtures pin bytes on the wire, not device
+# behaviour, and must regenerate identically on any machine.
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 from kubernetes_tpu.api import serialize, types as t  # noqa: E402
 from kubernetes_tpu.api.wrappers import make_node, make_pod  # noqa: E402

@@ -21,7 +21,9 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# CPU-only, on purpose: the mesh is 8 VIRTUAL host devices — a
+# partitioning-correctness and curve-shape tool, never a place to read time.
+os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (

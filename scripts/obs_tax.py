@@ -39,7 +39,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 GATE = 0.02  # <= 2% throughput cost
 
@@ -122,12 +121,17 @@ def main() -> int:
     ap.add_argument("--runs", type=int, default=2,
                     help="A/B pairs (interleaved on/off)")
     args = ap.parse_args()
+    from kubernetes_tpu.utils import require_device
+
+    device = require_device()
+    print(f"obs_tax: platform: {device['platform']} "
+          f"({device['device_kind']} x{device['n_devices']})", flush=True)
     on_runs: list[float] = []
     off_runs: list[float] = []
     exports: list[dict] = []
     explain_shares: list[float] = []
     for i in range(args.runs):
-        # Interleave: on, off, on, off — slow-window drift hits both.
+        # Interleave: on, off, on, off — drift hits both legs alike.
         r_on = run_once(True)
         v_on = r_on["pods_per_sec_all_in"]
         exports.append(r_on["export"])
@@ -166,7 +170,8 @@ def main() -> int:
         # the all-in rate above, i.e. to the headline tax).
         "explain_tax": round(max(explain_shares), 4) if explain_shares else 0.0,
         "environment": {
-            "backend": os.environ.get("JAX_PLATFORMS", ""),
+            "backend": device["platform"],
+            "device_kind": device["device_kind"],
             "python": platform.python_version(),
             "machine": platform.machine(),
         },

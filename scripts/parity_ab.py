@@ -15,7 +15,10 @@ Usage:
   python scripts/parity_ab.py --default [nodes] [pods]   # FULL default
       profile with preemption ON: bindings + nominations + victim sets
       diffed against tests/oracle_full.FullOracleScheduler.
-Prints one JSON line: {"parity": true/false, "mismatches": N, ...}.
+Prints one JSON line: {"parity": true/false, "mismatches": N, ...,
+"platform": ..., "device_kind": ..., "n_devices": N} — the device is asked
+for first (kubernetes_tpu.utils.require_device): no accelerator is an
+error unless JAX_PLATFORMS names cpu.
 """
 
 import json
@@ -31,6 +34,7 @@ from kubernetes_tpu.framework.config import DEFAULT_PROFILE, fit_only_profile  #
 from kubernetes_tpu.ops.common import registered_subset  # noqa: E402
 from kubernetes_tpu.scheduler import TPUScheduler  # noqa: E402
 from kubernetes_tpu.sidecar import SidecarClient, SidecarServer  # noqa: E402
+from kubernetes_tpu.utils import require_device  # noqa: E402
 from test_parity import OracleScheduler, _nodes, _pod  # noqa: E402
 
 
@@ -89,6 +93,7 @@ def main_default(n_nodes: int = 1000, n_pending: int = 1200) -> dict:
 
     from oracle_full import FullOracleScheduler, build_fixture
 
+    device = require_device()
     nodes, bound, pending, pdbs, objs = build_fixture(n_nodes, n_pending, volumes=True)
     prof = replace(
         registered_subset(DEFAULT_PROFILE), percentage_of_nodes_to_score=None
@@ -181,6 +186,7 @@ def main_default(n_nodes: int = 1000, n_pending: int = 1200) -> dict:
         "sample": dict(list(sorted(mm_bind.items()))[:3]),
         "nom_ok": got_nom == want_nom,
         "vic_ok": got_vic == want_vic,
+        **device,
     }
     if mm_bind:
         out["first_divergence"] = _explain_first_mismatch(sched, mm_bind)
@@ -189,6 +195,7 @@ def main_default(n_nodes: int = 1000, n_pending: int = 1200) -> dict:
 
 
 def main(n_nodes: int = 304, n_pods: int = 200) -> dict:
+    device = require_device()
     nodes = _nodes(n_nodes)
     prof = replace(fit_only_profile(), percentage_of_nodes_to_score=None)
 
@@ -218,6 +225,7 @@ def main(n_nodes: int = 304, n_pods: int = 200) -> dict:
         "nodes": n_nodes,
         "mismatches": len(mismatches),
         "sample": dict(list(mismatches.items())[:3]),
+        **device,
     }
     if mismatches:
         out["first_divergence"] = _explain_first_mismatch(sched, mismatches)

@@ -519,12 +519,13 @@ def _measured_matrix_table(matrix: dict) -> str:
 
 
 def bench_report(doc: dict) -> str:
-    """Render one bench payload (bench.py stdout / BENCH_rNN.json):
-    headline + flagship, then the PR 16 blocks — the sentinel guard
-    table and the measured-matrix provenance stamp."""
+    """Render one bench payload (bench.py stdout): headline + flagship
+    and the device they ran on, then the PR 16 blocks — the sentinel
+    guard table and the measured-matrix provenance stamp."""
     out = [
         f"bench payload: {doc.get('metric')} = {doc.get('value')} "
         f"{doc.get('unit', '')}".rstrip()
+        + f" on {doc.get('platform', '?')} ({doc.get('device_kind', '?')})"
     ]
     fl = doc.get("flagship") or {}
     if fl:
@@ -544,9 +545,9 @@ def bench_report(doc: dict) -> str:
             if "ratio" in g:
                 detail = (
                     f"ratio {g['ratio']} vs {g.get('reference')} "
-                    f"[{g.get('reference_file', '?')}]"
+                    f"[{g.get('source_file', '?')}]"
                 )
-                limits = f"warn<{g.get('warn_below')} hard<{g.get('hard_below')}"
+                limits = f"warn>{g.get('warn_above')} hard>{g.get('hard_above')}"
             elif "value" in g:
                 src = f" [{g['source_file']}]" if "source_file" in g else ""
                 detail = f"value {g['value']}{src}"

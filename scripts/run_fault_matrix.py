@@ -60,7 +60,10 @@ import tempfile
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# CPU-only, on purpose: a correctness tool that SIGKILLs and multiplies
+# processes (up to N owners + standbys alive at once), and one chip
+# belongs to one process.  Every process it starts inherits this.
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 FAULT_KINDS = ("hang", "crash", "partial_write", "slow")
 FRAME_KINDS = ("add", "remove", "schedule")

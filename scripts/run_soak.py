@@ -35,7 +35,18 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+def _environment() -> dict:
+    """Where the artifact was recorded: the platform JAX runs on in THIS
+    process (the determinism legs run here), never the JAX_PLATFORMS
+    variable."""
+    from kubernetes_tpu.utils import require_device
+
+    return {
+        "backend": require_device()["platform"],
+        "python": platform.python_version(),
+        "machine": platform.machine(),
+    }
 
 
 def r06_config(args) -> "SoakConfig":
@@ -554,11 +565,7 @@ def run_tenant(args) -> int:
         "determinism_check": check,
         "observability_check": obs_check,
     }
-    doc["environment"] = {
-        "backend": os.environ.get("JAX_PLATFORMS", ""),
-        "python": platform.python_version(),
-        "machine": platform.machine(),
-    }
+    doc["environment"] = _environment()
     with open(args.out, "w", encoding="utf-8") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
         f.write("\n")
@@ -868,11 +875,7 @@ def run_tenant_fair(args) -> int:
         "arming_check": arming_check,
         "hashed_tier_check": hashed_check,
     }
-    doc["environment"] = {
-        "backend": os.environ.get("JAX_PLATFORMS", ""),
-        "python": platform.python_version(),
-        "machine": platform.machine(),
-    }
+    doc["environment"] = _environment()
     with open(args.out, "w", encoding="utf-8") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
         f.write("\n")
@@ -1006,11 +1009,7 @@ def run_fleet(args) -> int:
             return 1
     if not args.skip_scaling:
         artifact["scaling"] = fleet_scaling_sweep(args, cfg)
-    artifact["environment"] = {
-        "backend": os.environ.get("JAX_PLATFORMS", ""),
-        "python": platform.python_version(),
-        "machine": platform.machine(),
-    }
+    artifact["environment"] = _environment()
     with open(args.out, "w", encoding="utf-8") as f:
         json.dump(artifact, f, indent=1, sort_keys=True)
         f.write("\n")
@@ -1590,11 +1589,7 @@ def run_prod(args) -> int:
         "production_gates": gates,
         "determinism_check": pre["determinism_check"],
         "resume_twin_check": pre["resume_twin_check"],
-        "environment": {
-            "backend": os.environ.get("JAX_PLATFORMS", ""),
-            "python": platform.python_version(),
-            "machine": platform.machine(),
-        },
+        "environment": _environment(),
     }
     with open(args.out, "w", encoding="utf-8") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
@@ -1723,6 +1718,10 @@ def main() -> int:
     ap.add_argument("--scaling-seconds", type=float, default=45.0,
                     help="duration of each scaling-sweep point")
     args = ap.parse_args()
+    # The determinism legs run in this process: ask for the device before
+    # any of them (no accelerator is an error unless JAX_PLATFORMS names
+    # cpu — which every recorded soak does; see the verify skill).
+    _environment()
     if (
         args.autoscale or args.tenant or args.tenant_fair or args.prod
     ) and not args.shards:
@@ -1804,11 +1803,7 @@ def main() -> int:
     )
     artifact = strip_private(run_soak(cfg))
     artifact["determinism_check"] = check
-    artifact["environment"] = {
-        "backend": os.environ.get("JAX_PLATFORMS", ""),
-        "python": platform.python_version(),
-        "machine": platform.machine(),
-    }
+    artifact["environment"] = _environment()
     with open(args.out, "w", encoding="utf-8") as f:
         json.dump(artifact, f, indent=1, sort_keys=True)
         f.write("\n")
