@@ -1,0 +1,72 @@
+"""Cluster objects as the wire carries them, built from a configuration
+file's literal templates.
+
+A configuration holds one node template and one pod template in the
+sidecar's canonical JSON (what the program's own serializer emits for the
+same objects), with ``{name}``, ``{i}`` and the configuration's own
+cyclic variables (``{zone}`` ...) as placeholders inside string values.
+Object ``i`` gets ``prefix + str(i % count)`` for each cycle; a pod's
+``{namespace}`` is the configuration's ``pod.namespaces.initial`` for the
+first ``initial`` pods (the source's initPods) and ``.measured`` for the
+rest.  Everything is bytes before the window opens; nothing here touches
+the program.
+"""
+
+from __future__ import annotations
+
+import json
+import random
+
+
+def _fill(text: str, name: str, i: int, cycles: dict) -> str:
+    out = text.replace("{name}", name).replace("{i}", str(i))
+    for var, spec in cycles.items():
+        out = out.replace("{" + var + "}", f"{spec['prefix']}{i % spec['count']}")
+    return out
+
+
+class Nodes:
+    """The cluster's nodes in the order the seed shuffled them into (the
+    order of arrival decides each node's device row)."""
+
+    def __init__(self, config: dict, seed: int):
+        cluster = config["cluster"]
+        text = json.dumps(cluster["node_template"], sort_keys=True)
+        cycles = cluster.get("cycles", {})
+        order = list(range(cluster["nodes"]))
+        random.Random(seed).shuffle(order)
+        self.names = [f"node-{i}" for i in order]
+        self.jsons = [
+            _fill(text, f"node-{i}", i, cycles).encode() for i in order
+        ]
+
+
+class Pods:
+    """``count`` pods of the configuration's template, named from the seed,
+    the first ``initial`` of them in the initial pods' namespace.
+    ``uids[k]`` is the uid the sidecar derives for pod ``k`` (namespace/name,
+    the template leaving ``metadata.uid`` empty)."""
+
+    def __init__(self, config: dict, seed: int, count: int, initial: int = 0, tag: str = "p"):
+        from . import wire
+
+        pod = config["pod"]
+        text = json.dumps(pod["template"], sort_keys=True)
+        cycles = pod.get("cycles", {})
+        rng = random.Random((seed << 1) ^ 0x5EED)
+        spaces = pod.get("namespaces") or {}
+        own = pod["template"]["metadata"].get("namespace") or "default"
+        ns = [spaces.get("initial", own)] * min(initial, count)
+        ns += [spaces.get("measured", own)] * (count - len(ns))
+        self.names = [
+            f"{tag}-{k}-{rng.getrandbits(32):08x}" for k in range(count)
+        ]
+        self.uids = [f"{s}/{n}" for s, n in zip(ns, self.names)]
+        self.jsons = [
+            _fill(text, n, k, cycles).replace("{namespace}", ns[k]).encode()
+            for k, n in enumerate(self.names)
+        ]
+        self.frames = [wire.schedule_frame(j) for j in self.jsons]
+
+    def __len__(self) -> int:
+        return len(self.uids)

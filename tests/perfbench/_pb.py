@@ -1,0 +1,34 @@
+"""Shared by the benchmark's tests: the repo root on the path, and the
+command a test uses to run a cell as the driver would."""
+
+import json
+import os
+import subprocess
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+def bench() -> dict:
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
+        return json.load(f)
+
+
+def run_cell(workload: str, out: str, seconds: float = 1.5, trace: int = 0,
+             rehearsal: bool = True, extra=(), env_extra=None, timeout: float = 420.0):
+    """``perfbench/run.py`` in a child, on the CPU.  (returncode, stdout
+    lines, stderr)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)  # one CPU device, as a serve child gets
+    env.update(env_extra or {})
+    argv = [sys.executable, os.path.join(ROOT, "perfbench", "run.py"),
+            "--workload", workload, "--seed", "2400000777", "--seconds", str(seconds),
+            "--trace", str(trace), "--out", out] + list(extra)
+    if rehearsal:
+        argv.append("--rehearsal")
+    p = subprocess.run(argv, cwd=ROOT, env=env, capture_output=True, text=True, timeout=timeout)
+    return p.returncode, p.stdout.splitlines(), p.stderr

@@ -1,0 +1,201 @@
+"""The window rules, on synthetic timelines: a fake sidecar with a fake
+clock stands where the server is."""
+
+import random
+
+import numpy as np
+import pytest
+
+import _pb
+from perfbench import loops
+
+
+class FakeClock:
+    def __init__(self):
+        self.t = 100.0
+
+    def __call__(self):
+        return self.t
+
+
+class FakeSidecar:
+    """A sidecar that decides ``batch`` hinted pods per wire call, each call
+    costing ``batch_s`` (plus a stall on chosen calls), and pushes the
+    co-scheduled decisions before the response."""
+
+    def __init__(self, clock, batch=8, batch_s=1.0, stalls=None):
+        self.clock, self.batch, self.batch_s = clock, batch, batch_s
+        self.stalls = stalls or {}
+        self.hinted: list[str] = []
+        self.pushed: dict[str, str] = {}
+        self.map: dict[str, str] = {}
+        self.calls = 0
+        self.order: list = []
+
+    # conn
+    def call_raw(self, frame):
+        self.hinted.extend(frame)
+        self.clock.t += 0.001
+
+    def schedule_raw(self, frame):
+        self.calls += 1
+        uid = frame
+        take = [uid] + [u for u in self.hinted if u != uid][: self.batch - 1]
+        self.hinted = [u for u in self.hinted if u not in take]
+        self.clock.t += self.batch_s + self.stalls.get(self.calls, 0.0)
+        for u in take[1:]:
+            self.pushed[u] = "n1"
+        return "n1"
+
+    # push
+    def pop(self, uid):
+        return self.map.pop(uid, None)
+
+    def drain(self):
+        self.map.update(self.pushed)
+        self.pushed = {}
+
+    def note(self, uid, node):
+        self.order.append((uid, node))
+
+
+class FakePods:
+    def __init__(self, n):
+        self.uids = [f"u{i}" for i in range(n)]
+        self.frames = list(self.uids)
+
+
+def _closed(seconds, stalls=None, backlog=20, batch=8):
+    clock = FakeClock()
+    side = FakeSidecar(clock, batch=batch, stalls=stalls)
+    pods = FakePods(400)
+    hints = [pods.uids[a: a + backlog] for a in range(0, 400, backlog)]
+    w = loops.closed_loop(side, side, pods, hints, 0, backlog, seconds, clock=clock)
+    return w, side
+
+
+def test_window_closes_on_a_backlog_boundary_and_never_cuts_a_batch():
+    # backlogs of 20 in batches of 8, 8 and 4, 3.003 s each.  Whatever
+    # length is asked, the pods answered are whole backlogs: the same mix
+    # of full and short batches in every window.
+    for seconds in (0.5, 3.0, 3.01, 5.0, 7.3, 11.0):
+        w, side = _closed(seconds)
+        assert w.asked % 20 == 0 and w.asked > 0, (seconds, w.asked)
+        assert w.seconds >= seconds - 1e-9
+        assert w.seconds < seconds + 3.004  # at most one backlog past it
+        # it ends at the last answer, which is when the last batch landed
+        assert w.t_close == pytest.approx(w.answer_t[-1])
+        assert w.misses == side.calls == 3 * w.asked // 20
+
+
+def test_rate_is_all_pods_over_all_time_and_a_stall_lowers_it():
+    calm, _ = _closed(10.0)
+    stalled, _ = _closed(10.0, stalls={3: 2.5})
+    rate = lambda w: w.bound / w.seconds  # noqa: E731
+    assert rate(stalled) < rate(calm) * 0.85
+    # no median of pieces could hide it: the stall is inside the window
+    assert stalled.seconds >= 10.0
+
+
+def test_window_opens_at_the_first_hint_frame():
+    w, _ = _closed(3.0)
+    assert w.t_open == 100.0
+    assert w.hint_frames >= 1 and w.answer_t[0] > w.t_open
+
+
+def test_percentile_matches_numpy():
+    rng = random.Random(5)
+    xs = [rng.random() for _ in range(997)]
+    for q in (50, 95, 99, 0, 100):
+        assert loops.percentile(xs, q) == pytest.approx(np.percentile(xs, q))
+
+
+def test_open_loop_latency_runs_from_the_due_time():
+    """A sidecar that takes 30 ms a batch: a pod due while a batch is in
+    flight waits it out, its hint goes out before the next wire call, and
+    its latency counts the wait."""
+    import time
+
+    class Side(FakeSidecar):
+        def __init__(self):
+            super().__init__(time.perf_counter, batch=64)
+            self.frames = 0
+
+        def call_raw(self, frame):
+            self.hinted.extend(frame)
+            self.frames += 1
+
+        def schedule_raw(self, frame):
+            take = [frame] + [u for u in self.hinted if u != frame]
+            self.hinted = []
+            time.sleep(0.03)
+            for u in take[1:]:
+                self.pushed[u] = "n1"
+            return "n1"
+
+    side = Side()
+    pods = FakePods(50)
+    offsets = [0.004 * (k + 1) for k in range(50)]  # 250 pods/s for 0.2 s
+    w = loops.open_loop(side, side, pods, 0, offsets, lambda a, z: pods.uids[a:z])
+    assert w.asked == 50 and w.bound == 50
+    lat = [a - d for a, d in zip(w.answer_t, w.due_t)]
+    assert min(lat) >= 0.0
+    # a pod waits out the rest of a 30 ms batch and then its own
+    assert loops.percentile(lat, 50) >= 0.015
+    # batches of about 250/s x 30 ms = 7 or 8 pods, not one pod each
+    assert 4 <= w.misses <= 12 and w.hits == 50 - w.misses
+    # every pod was hinted, in frames that precede the wire calls
+    assert w.hint_frames == side.frames and side.frames <= 50
+    # the window closed when the last pod due in it was answered
+    assert w.t_close == pytest.approx(w.answer_t[-1])
+    assert w.t_close - w.t_open >= offsets[-1]
+    assert all(x >= 0 for x in w.lag_s)
+
+
+def test_open_schedule_is_the_same_work_for_every_seed():
+    from perfbench import traffic
+
+    mix = {"rate_pods_per_s": 200}
+    a = traffic.open_offsets(mix, 5.0, 1)
+    b = traffic.open_offsets(mix, 5.0, 2**31 + 5)
+    assert len(a) == len(b) == 1000
+    assert a != b
+    gaps = lambda xs: sorted(round(y - x, 9) for x, y in zip([0.0] + xs[:-1], xs))  # noqa: E731
+    assert gaps(a)[:-1] == pytest.approx(gaps(b)[:-1], abs=1e-8)
+    assert a == sorted(a) and a[-1] < 5.0
+    # exponential gaps: the coefficient of variation of a Poisson process
+    g = np.diff([0.0] + a)
+    assert 0.9 < g.std() / g.mean() < 1.1
+    # a study's other rate scales every segment
+    assert len(traffic.open_offsets(mix, 5.0, 1, rate_override=100.0)) == 500
+    # a mix may give its rates piecewise; a single rate is one piece
+    steps = dict(mix, segments=[{"share": 0.5, "rate_pods_per_s": 100}, {"share": 0.5, "rate_pods_per_s": 300}])
+    c = traffic.open_offsets(steps, 5.0, 1)
+    assert len(c) == 1000 and sum(1 for x in c if x < 2.5) == 250
+
+
+def test_the_arrivals_readers_time_every_pod_from_its_due_time():
+    """The median and the observed tails, as their reader files give them,
+    on a window whose answers are known: 100 pods due 10 ms apart, each
+    answered 200 ms after it was due but for five that waited out a stall."""
+    import os
+
+    from perfbench import report
+
+    w = loops.Window(t_open=50.0)
+    w.due_t = [50.0 + 0.01 * k for k in range(100)]
+    w.answer_t = [d + (2.0 if 40 <= k < 45 else 0.2) for k, d in enumerate(w.due_t)]
+    w.nodes = ["n1"] * 100
+    w.lag_s = [0.0002] * 99 + [0.004]
+    raw = {"window": w, "records": [], "scrape0": {}, "scrape1": {}, "config": {}, "mix": {},
+           "device": {"kind": "TPU v5 lite"}, "setup_s": 1.0}
+    ctx = report.Ctx(raw, None)
+    home = os.path.join(_pb.ROOT, "perfbench")
+    read = lambda name: report.load_reader(home, name).read(ctx)  # noqa: E731
+    assert read("decision_p50_ms") == pytest.approx(200.0)
+    lat = [a - d for a, d in zip(w.answer_t, w.due_t)]
+    assert read("decision_p95_ms.observed") == pytest.approx(np.percentile(lat, 95) * 1e3)
+    assert read("decision_p99_ms.observed") == pytest.approx(2000.0)
+    assert read("generator_lag_p99_ms") == pytest.approx(np.percentile(w.lag_s, 99) * 1e3)
+    # the rate's reader has nothing to say of a window that bound nothing
+    assert read("pods_per_s") is None
