@@ -391,6 +391,11 @@ def cmd_serve(args) -> int:
     # process exits — the last evidence an operator gets from a pod
     # being terminated.  SIGKILL is the chaos harness's business.
     sched.flight.install_sigterm()
+    # The collector's pauses, counted from here on
+    # (scheduler_gc_collections_total, scheduler_gc_pause_seconds_total).
+    from .framework.tracing import PROCESS
+
+    PROCESS.hook_gc()
     try:
         srv.serve_forever()
     except KeyboardInterrupt:

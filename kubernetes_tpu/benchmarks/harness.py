@@ -159,13 +159,14 @@ def run_workload(
     # summed from the scheduler_phase_duration_seconds family — their sum
     # over wall time is the coverage the bench guard reports (journal
     # append/fsync and the speculative frontend's hint_decode are
-    # sub-slices of / overlap the tiled phases and stay out of the sum).
+    # sub-slices of / overlap the tiled phases and stay out of the sum;
+    # so does every span observed under its own name, `layer/name`).
     phases: dict[str, float] = {}
     fam = m.registry.histograms.get("scheduler_phase_duration_seconds")
     if fam is not None:
         for key, h in sorted(fam.cells.items()):
             label = dict(key).get("phase")
-            if label and h.n:
+            if label and h.n and "/" not in label:
                 phases[label] = round(h.total, 6)
     tiled = sum(
         v for k, v in phases.items()

@@ -493,65 +493,74 @@ def build_pass(
 
         def eval_pod(state, dctx, pf, step_idx, start):
             """One reference scheduling cycle's decision (no commit)."""
-            feasible = state.valid
-            fail_mask = jnp.uint32(0)
-            bit = 0
-            for op in filter_ops:
-                if op.filter is not None:
-                    ok = op.filter(state, pf, dctx)
-                    newly = feasible & ~ok
-                    fail_mask = fail_mask | jnp.where(
-                        newly.any(), jnp.uint32(1 << bit), jnp.uint32(0)
+            # Named scopes are metadata only: every device op's name says
+            # which stage (and which plugin) it serves, in the lowered HLO
+            # and in a profiler trace.  The names are a contract
+            # (perfbench/spans.py matches them).  A plugin's scope spells
+            # its whole path because the vmap in `step` wraps the outer
+            # one: `vmap(pass/eval)/pass/eval/<plugin>/...`.
+            with jax.named_scope("pass/eval"):
+                feasible = state.valid
+                fail_mask = jnp.uint32(0)
+                bit = 0
+                for op in filter_ops:
+                    if op.filter is not None:
+                        with jax.named_scope(f"pass/eval/{op.name}"):
+                            ok = op.filter(state, pf, dctx)
+                        newly = feasible & ~ok
+                        fail_mask = fail_mask | jnp.where(
+                            newly.any(), jnp.uint32(1 << bit), jnp.uint32(0)
+                        )
+                        bit += 1
+                        feasible &= ok
+                pos = None
+                processed = jnp.int32(0)
+                if truncated:
+                    # Truncate the feasible set to the first `limit` feasible
+                    # nodes in rotated zone-interleaved order (the sequential
+                    # findNodesThatPassFilters semantics): positions sort, the
+                    # limit-th smallest is the cutoff; processedNodes is the
+                    # (limit+1)-th feasible position (the node whose check
+                    # tripped the cancel) or the whole list.
+                    nvalid = jnp.sum(state.valid.astype(jnp.int32))
+                    nv = jnp.maximum(nvalid, 1)
+                    limit = _num_to_find(nvalid)
+                    big = jnp.int32(2**30)
+                    pos = jnp.where(
+                        state.valid,
+                        (inv["order_pos"] - start.astype(jnp.int32)) % nv,
+                        big,
                     )
-                    bit += 1
-                    feasible &= ok
-            pos = None
-            processed = jnp.int32(0)
-            if truncated:
-                # Truncate the feasible set to the first `limit` feasible
-                # nodes in rotated zone-interleaved order (the sequential
-                # findNodesThatPassFilters semantics): positions sort, the
-                # limit-th smallest is the cutoff; processedNodes is the
-                # (limit+1)-th feasible position (the node whose check
-                # tripped the cancel) or the whole list.
-                nvalid = jnp.sum(state.valid.astype(jnp.int32))
-                nv = jnp.maximum(nvalid, 1)
-                limit = _num_to_find(nvalid)
-                big = jnp.int32(2**30)
-                pos = jnp.where(
-                    state.valid,
-                    (inv["order_pos"] - start.astype(jnp.int32)) % nv,
-                    big,
+                    total_feas = jnp.sum(feasible.astype(jnp.int32))
+                    fpos = jnp.sort(jnp.where(feasible, pos, big))
+                    n = fpos.shape[0]
+                    over = total_feas > limit
+                    cutoff = fpos[jnp.clip(limit - 1, 0, n - 1)]
+                    feasible = jnp.where(over, feasible & (pos <= cutoff), feasible)
+                    processed = jnp.where(over, fpos[jnp.clip(limit, 0, n - 1)], nvalid)
+                total = jnp.zeros(schema.N, jnp.int64)
+                for op, weight in score_ops:
+                    if op.score is not None:
+                        # Plugin scores are pre-normalized to [0, MaxNodeScore]
+                        # over the feasible (post-truncation) set; the framework
+                        # applies the weight (runtime/framework.go:1188).
+                        with jax.named_scope(f"pass/eval/{op.name}"):
+                            total += op.score(state, pf, dctx, feasible) * jnp.int64(weight)
+                tie_rand = _hash_u32(
+                    jnp.uint32(profile.tie_break_seed) * jnp.uint32(2654435761)
+                    + step_idx.astype(jnp.uint32)
                 )
-                total_feas = jnp.sum(feasible.astype(jnp.int32))
-                fpos = jnp.sort(jnp.where(feasible, pos, big))
-                n = fpos.shape[0]
-                over = total_feas > limit
-                cutoff = fpos[jnp.clip(limit - 1, 0, n - 1)]
-                feasible = jnp.where(over, feasible & (pos <= cutoff), feasible)
-                processed = jnp.where(over, fpos[jnp.clip(limit, 0, n - 1)], nvalid)
-            total = jnp.zeros(schema.N, jnp.int64)
-            for op, weight in score_ops:
-                if op.score is not None:
-                    # Plugin scores are pre-normalized to [0, MaxNodeScore]
-                    # over the feasible (post-truncation) set; the framework
-                    # applies the weight (runtime/framework.go:1188).
-                    total += op.score(state, pf, dctx, feasible) * jnp.int64(weight)
-            tie_rand = _hash_u32(
-                jnp.uint32(profile.tie_break_seed) * jnp.uint32(2654435761)
-                + step_idx.astype(jnp.uint32)
-            )
-            pick, best, _ties = select_host(feasible, total, tie_rand, pos)
-            # Nominated-node fast path (schedule_one.go:491–502): a pod
-            # whose preemption nominated a node takes it whenever it is
-            # feasible, without re-ranking the whole cluster.
-            nomr = pf.get("nominated_row")
-            if nomr is not None:
-                safe_nom = jnp.maximum(nomr, 0)
-                use_nom = (nomr >= 0) & feasible[safe_nom]
-                pick = jnp.where(use_nom, safe_nom, pick)
-                best = jnp.where(use_nom, total[safe_nom], best)
-            return pick, best, jnp.sum(feasible.astype(jnp.int32)), fail_mask, processed
+                pick, best, _ties = select_host(feasible, total, tie_rand, pos)
+                # Nominated-node fast path (schedule_one.go:491–502): a pod
+                # whose preemption nominated a node takes it whenever it is
+                # feasible, without re-ranking the whole cluster.
+                nomr = pf.get("nominated_row")
+                if nomr is not None:
+                    safe_nom = jnp.maximum(nomr, 0)
+                    use_nom = (nomr >= 0) & feasible[safe_nom]
+                    pick = jnp.where(use_nom, safe_nom, pick)
+                    best = jnp.where(use_nom, total[safe_nom], best)
+                return pick, best, jnp.sum(feasible.astype(jnp.int32)), fail_mask, processed
 
         def step(carry, xs):
             state, group_dom, et_dom, start = carry
@@ -570,53 +579,55 @@ def build_pass(
             att = pf["valid"] & (picks >= 0)  # attempting placement
             defer = jnp.zeros((c,), jnp.bool_)
             if c > 1:
-                # (a) Interaction deferral: reader pods behind any attempting
-                # writer re-run strictly (module docstring).
-                pairs = _conflict_pairs(pf, schema)
-                # before[i, j] ⟺ i precedes j in chunk order.  A reader
-                # behind an attempting writer defers even when its own pick
-                # failed (-1): the writer's commit may make it feasible
-                # (e.g. required pod affinity to the writer's group).
-                before = jnp.triu(jnp.ones((c, c), jnp.bool_), k=1)
-                defer = (pairs & before & att[:, None]).any(axis=0) & pf["valid"]
-                att = att & ~defer
-                # (b) Exact cumulative resource fit at each picked node in
-                # chunk order (fitsRequest semantics over the chunk prefix).
-                samei = (
-                    (picks[:, None] == picks[None, :])
-                    & att[:, None]
-                    & att[None, :]
-                    & jnp.triu(jnp.ones((c, c), jnp.bool_))  # i ≤ j, incl. self
-                )
-                # i64 dot_general has no TPU lowering; masked-sum instead.
-                cum_req = jnp.where(
-                    samei[:, :, None], pf["req"][:, None, :], jnp.int64(0)
-                ).sum(axis=0)  # (C, R)
-                cum_cnt = samei.sum(axis=0).astype(jnp.int32)  # (C,)
-                rows = jnp.where(att, picks, 0)
-                free = (state.alloc - state.req)[rows]  # (C, R)
-                # Per-resource escape mirrors noderesources.filter_fn: a
-                # resource the pod does not request is never checked (the
-                # node may legitimately be over-committed on it).
-                ok = ((pf["req"] == 0) | (cum_req <= free)).all(axis=-1) & (
-                    state.num_pods[rows] + cum_cnt <= state.allowed_pods[rows]
-                )
-                overflow = att & ~ok
-                # Per-node CSI attach limits interact only on the SAME node:
-                # a later chunk-mate whose limit-scoped claims land where an
-                # earlier mate's did defers (distinct volumes still consume
-                # one shared per-driver budget; cross-node claims don't).
-                if "vol_csi_lim" in pf:
-                    lim = pf["vol_csi_lim"]  # (C,) carries a limited-driver claim
-                    prev_same = samei & ~jnp.eye(c, dtype=jnp.bool_)
-                    lim_clash = (
-                        prev_same & lim[:, None] & lim[None, :]
-                    ).any(axis=0)
-                    overflow = overflow | (att & lim_clash)
-                defer = defer | overflow
-                att = att & ~overflow
-            state, dom = _commit_chunk(state, dom, pf, picks, att)
-            out_picks = jnp.where(defer, -2, jnp.where(pf["valid"], picks, -1))
+                with jax.named_scope("pass/conflict"):
+                    # (a) Interaction deferral: reader pods behind any attempting
+                    # writer re-run strictly (module docstring).
+                    pairs = _conflict_pairs(pf, schema)
+                    # before[i, j] ⟺ i precedes j in chunk order.  A reader
+                    # behind an attempting writer defers even when its own pick
+                    # failed (-1): the writer's commit may make it feasible
+                    # (e.g. required pod affinity to the writer's group).
+                    before = jnp.triu(jnp.ones((c, c), jnp.bool_), k=1)
+                    defer = (pairs & before & att[:, None]).any(axis=0) & pf["valid"]
+                    att = att & ~defer
+                    # (b) Exact cumulative resource fit at each picked node in
+                    # chunk order (fitsRequest semantics over the chunk prefix).
+                    samei = (
+                        (picks[:, None] == picks[None, :])
+                        & att[:, None]
+                        & att[None, :]
+                        & jnp.triu(jnp.ones((c, c), jnp.bool_))  # i ≤ j, incl. self
+                    )
+                    # i64 dot_general has no TPU lowering; masked-sum instead.
+                    cum_req = jnp.where(
+                        samei[:, :, None], pf["req"][:, None, :], jnp.int64(0)
+                    ).sum(axis=0)  # (C, R)
+                    cum_cnt = samei.sum(axis=0).astype(jnp.int32)  # (C,)
+                    rows = jnp.where(att, picks, 0)
+                    free = (state.alloc - state.req)[rows]  # (C, R)
+                    # Per-resource escape mirrors noderesources.filter_fn: a
+                    # resource the pod does not request is never checked (the
+                    # node may legitimately be over-committed on it).
+                    ok = ((pf["req"] == 0) | (cum_req <= free)).all(axis=-1) & (
+                        state.num_pods[rows] + cum_cnt <= state.allowed_pods[rows]
+                    )
+                    overflow = att & ~ok
+                    # Per-node CSI attach limits interact only on the SAME node:
+                    # a later chunk-mate whose limit-scoped claims land where an
+                    # earlier mate's did defers (distinct volumes still consume
+                    # one shared per-driver budget; cross-node claims don't).
+                    if "vol_csi_lim" in pf:
+                        lim = pf["vol_csi_lim"]  # (C,) carries a limited-driver claim
+                        prev_same = samei & ~jnp.eye(c, dtype=jnp.bool_)
+                        lim_clash = (
+                            prev_same & lim[:, None] & lim[None, :]
+                        ).any(axis=0)
+                        overflow = overflow | (att & lim_clash)
+                    defer = defer | overflow
+                    att = att & ~overflow
+            with jax.named_scope("pass/commit"):
+                state, dom = _commit_chunk(state, dom, pf, picks, att)
+                out_picks = jnp.where(defer, -2, jnp.where(pf["valid"], picks, -1))
             return (state, dom.group_dom, dom.et_dom, start), PassResult(
                 picks=out_picks, scores=bests, feasible_counts=feas,
                 fail_masks=fails,
@@ -716,7 +727,8 @@ def build_pass(
                     carry2,
                 )
 
-            carry, out2 = lax.scan(step_tail, carry, (cbatch2, steps2))
+            with jax.named_scope("pass/tail"):
+                carry, out2 = lax.scan(step_tail, carry, (cbatch2, steps2))
             out2 = jax.tree_util.tree_map(
                 lambda x: x.reshape((k,) + x.shape[2:]), out2
             )

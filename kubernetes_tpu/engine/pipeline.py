@@ -47,7 +47,6 @@ lint family covers this file like the rest of ``engine/``).
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 
 from ..framework.events import NORMAL
@@ -124,9 +123,13 @@ class CommitTicket:
         return len(self.staged)
 
 
-def drain_commit(sched, ticket: CommitTicket) -> float:
-    """Journal + apply one staged commit group.  Returns the drain's
-    host seconds (the flight recorder's ``drain`` stage segment).
+def drain_commit(sched, ticket: CommitTicket) -> None:
+    """Journal + apply one staged commit group.  The caller's
+    `pipeline/drain` span is the flight recorder's ``drain`` stage
+    segment; inside it three spans say where the drain goes:
+    `drain/journal_append` (serialise + write every record, with the
+    serialisation's share as ``serialize_us``), `drain/journal_fsync` (the
+    group's one barrier) and `drain/apply`.
 
     Ordering contract (the WAL family's apply sites live here):
 
@@ -150,11 +153,10 @@ def drain_commit(sched, ticket: CommitTicket) -> float:
     a record, never reporting an unapplied bind as committed.
     """
     if ticket.drained:
-        return 0.0
+        return
     if not ticket.staged and not ticket.admission:
         ticket.drained = True
-        return 0.0
-    t0 = time.perf_counter()
+        return
     # The commit stage is fully staged, nothing journaled yet — the
     # stage-boundary crash window (at depth >= 2 a device pass for the
     # NEXT batch is typically in flight right now).
@@ -163,20 +165,27 @@ def drain_commit(sched, ticket: CommitTicket) -> float:
     if journal is not None and not ticket.barriered:
         need_admission = bool(ticket.admission) and not ticket.admission_journaled
         if ticket.journaled < len(ticket.staged) or need_admission:
+            # group() defers the fsync to its exit, where the journal
+            # times it as `drain/journal_fsync`: the two spans tile the
+            # group.
             with journal.group():
-                if need_admission:
-                    # The batch's fairness debits ride the SAME barrier
-                    # as its binds, ahead of them: a crash either loses
-                    # the whole group (restored pods re-pop through the
-                    # identical ledger) or recovers debits + binds
-                    # together — admission order replays bit-identical.
-                    sched._journal_append(
-                        "admission", debits=ticket.admission
-                    )
-                    ticket.admission_journaled = True
-                for sb in ticket.staged[ticket.journaled :]:
-                    sched._journal_bind(sb.qp.pod, sb.node_name)
-                    ticket.journaled += 1
+                with sched.span("drain/journal_append") as sp:
+                    sched._serialize_s = 0.0
+                    if need_admission:
+                        # The batch's fairness debits ride the SAME
+                        # barrier as its binds, ahead of them: a crash
+                        # either loses the whole group (restored pods
+                        # re-pop through the identical ledger) or recovers
+                        # debits + binds together — admission order
+                        # replays bit-identical.
+                        sched._journal_append(
+                            "admission", debits=ticket.admission
+                        )
+                        ticket.admission_journaled = True
+                    for sb in ticket.staged[ticket.journaled :]:
+                        sched._journal_bind(sb.qp.pod, sb.node_name)
+                        ticket.journaled += 1
+                    sp.set("serialize_us", int(sched._serialize_s * 1e6))
         else:
             # Every record is already written; only the group's fsync
             # raised on the last attempt.  Re-entering group() would see
@@ -194,6 +203,21 @@ def drain_commit(sched, ticket: CommitTicket) -> float:
         ticket.admission_applied = True
     # Apply in stage order — identical to the serial loop's inline
     # order, just batched behind the single barrier.
+    with sched.span("drain/apply"):
+        _apply_staged(sched, ticket)
+    ticket.drained = True
+    # Stage flight fields: deterministic drain counts on the current
+    # batch's flight record — the trace exporter sizes/labels the drain
+    # slice from these, never from wall seconds (which differ run to
+    # run).  A recovery drain outside a batch has no accumulator; the
+    # guard inside _flight_add keeps this a no-op there.
+    sched._flight_add("drained", ticket.applied)
+    if journal is not None:
+        sched._flight_add("group_fsyncs", 1)
+
+
+def _apply_staged(sched, ticket: CommitTicket) -> None:
+    """The apply loop of drain_commit: stage order, after the barrier."""
     m = sched.metrics
     now = ticket.now
     for sb in ticket.staged[ticket.applied :]:
@@ -228,16 +252,6 @@ def drain_commit(sched, ticket: CommitTicket) -> float:
             m.e2e_latency_samples.append(lat)
             m.registry.scheduling_sli.observe(lat)
         ticket.applied += 1
-    ticket.drained = True
-    # Stage flight fields: deterministic drain counts on the current
-    # batch's flight record — the trace exporter sizes/labels the drain
-    # slice from these, never from wall seconds (which differ run to
-    # run).  A recovery drain outside a batch has no accumulator; the
-    # guard inside _flight_add keeps this a no-op there.
-    sched._flight_add("drained", ticket.applied)
-    if journal is not None:
-        sched._flight_add("group_fsyncs", 1)
-    return time.perf_counter() - t0
 
 
 @dataclass
@@ -258,7 +272,6 @@ class Predispatch:
     schema: object
     nominator_token: tuple
     cycle0: int  # _cycle before the dispatch (rollback target)
-    t_dispatch: float = 0.0
 
 
 def nominator_token(sched) -> tuple:

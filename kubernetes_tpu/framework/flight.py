@@ -226,6 +226,16 @@ PHASE_ORDER = (
 # ``group_fsyncs`` (the pipeline drain's counts) ride along so a merged
 # fleet doc still carries the inputs framework/measured.py folds into
 # measured throughput rows and the trace exporter sizes stages from.
+# Top-level spans of a record (framework/tracing.SpanSink) and the phase
+# each stands for on the critical path, where a record carries real span
+# starts (``spans`` + ``t0_ns``) instead of phase sums alone.
+SPAN_PHASE = {
+    "batch/featurize": "featurize", "pass/inflight": "device",
+    "commit/stage": "commit", "commit/failed": "commit",
+    "pipeline/predispatch": "predispatch", "pipeline/drain": "drain",
+    "pipeline/snapshot": "snapshot",
+}
+
 _TIMELINE_FIELDS = (
     "event", "pods", "scheduled", "unschedulable", "deferred",
     "dispatch", "tenant", "op", "shard", "from", "to", "clock", "version",
@@ -347,6 +357,17 @@ def merge_fleet(
                 continue
             end = float(ts)
             start = end - wall
+            placed = None  # [(start, seconds, phase)] where the record has starts
+            if rec.get("spans") and rec.get("t0_ns"):
+                # Real starts: the batch began at t0_ns and each
+                # top-level span sits where the primitive measured it.
+                start = float(rec["t0_ns"]) * 1e-9
+                end = start + wall
+                placed = [
+                    (start + sp[1] * 1e-6, sp[2] * 1e-6, SPAN_PHASE[sp[0]])
+                    for sp in rec["spans"]
+                    if sp[3] < 0 and sp[0] in SPAN_PHASE and sp[2] > 0
+                ]
             comp_intervals.setdefault(name, []).append((start, end))
             cursor = start
             phases = rec.get("phases") or {}
@@ -359,10 +380,14 @@ def merge_fleet(
                 stats["phases"][phase] = (
                     stats["phases"].get(phase, 0.0) + dur
                 )
-                slices.append(
-                    (cursor, min(cursor + dur, end), name, phase, wall)
-                )
-                cursor += dur
+                if placed is None:
+                    # no starts on the record: lay the phases end to end
+                    slices.append(
+                        (cursor, min(cursor + dur, end), name, phase, wall)
+                    )
+                    cursor += dur
+            for s0, dur, phase in placed or ():
+                slices.append((max(s0, start), min(s0 + dur, end), name, phase, wall))
     # The deterministic spine: logical-clock order, lc-less records after
     # (grouped per component in ring order).
     timeline.sort(

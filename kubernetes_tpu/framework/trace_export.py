@@ -22,10 +22,15 @@ Two timebases:
   overlapping the batch's stage tiling, so PR 15's "commit hides under
   the next in-flight pass" story is visible as overlapping tracks, not
   a scalar coverage ratio.
-- ``wall``: honest wall attribution — batch slices span
-  ``[ts - wall_s, ts]`` and phases tile by their measured seconds (the
-  same cursor walk merge_fleet's critical path uses).  Not stable
-  across runs, by construction.
+- ``wall``: honest wall attribution.  A record that carries ``spans``
+  and ``t0_ns`` (the span primitive, framework/tracing.py) is drawn
+  from them: the batch starts at ``t0_ns`` and every span sits at its
+  measured start, nested by containment, with the pipeline stages and
+  the pass in flight (which may have been dispatched in the call
+  before) on the overlap track.  A record without them spans
+  ``[ts - wall_s, ts]`` and its phases tile by their measured seconds
+  (the cursor walk merge_fleet's critical path falls back to as well).
+  Not stable across runs, by construction.
 
 Stdlib-only: no JAX, no package-internal imports — profile_report-style
 consumers load this module by file path.
@@ -55,8 +60,10 @@ _PIPELINE_PHASES = ("predispatch", "drain")
 # across same-seed runs.
 _WALL_ARG_FIELDS = (
     "ts", "wall_s", "phases", "plugins", "journal", "overlap",
-    "trace_id", "span_id",
+    "trace_id", "span_id", "spans", "t0_ns", "queue_wait", "bid",
 )
+# Top-level spans drawn on the overlap track in the wall timebase.
+_OVERLAP_SPANS = ("pipeline/predispatch", "pipeline/drain", "pass/inflight")
 
 _TRACK_BATCH = 0
 _TRACK_STAGES = 1
@@ -213,8 +220,10 @@ def _emit_wall(comps, events) -> None:
             ts = rec.get("ts")
             if ts is None:
                 continue
-            wall = float(rec.get("wall_s") or 0.0)
-            t_start = float(ts) - wall
+            t_start = _wall_start(rec)
+            for sp in rec.get("spans") or ():
+                # a predispatched pass began before its batch's call did
+                t_start = min(t_start, t_start + sp[1] * 1e-6)
             t0 = t_start if t0 is None else min(t0, t_start)
     if t0 is None:
         # No wall data anywhere (a merged timeline) — logical layout is
@@ -228,7 +237,9 @@ def _emit_wall(comps, events) -> None:
             if ts is None:
                 continue
             at = (float(ts) - t0) * 1e6
-            args = {k: rec[k] for k in sorted(rec) if k != "phases"}
+            args = {
+                k: rec[k] for k in sorted(rec) if k not in ("phases", "spans")
+            }
             if rec.get("kind") == "marker":
                 events.append(
                     _event(
@@ -238,13 +249,28 @@ def _emit_wall(comps, events) -> None:
                 )
                 continue
             wall = float(rec.get("wall_s") or 0.0)
-            start = round(at - wall * 1e6, 3)
+            start = round((_wall_start(rec) - t0) * 1e6, 3)
             events.append(
                 _event(
                     "X", str(rec.get("op") or "batch"), pid, _TRACK_BATCH,
                     start, dur=round(wall * 1e6, 3), args=args,
                 )
             )
+            if rec.get("spans") and rec.get("t0_ns"):
+                # Real starts: every span where the primitive measured it.
+                for sp in rec["spans"]:
+                    sname, s_us, d_us, parent = sp[0], sp[1], sp[2], sp[3]
+                    overlap = parent < 0 and sname in _OVERLAP_SPANS
+                    events.append(
+                        _event(
+                            "X", sname, pid,
+                            _TRACK_PIPELINE if overlap else _TRACK_STAGES,
+                            round(start + s_us, 3), dur=max(d_us, 0),
+                            cat="pipeline" if overlap else "stage",
+                            args=sp[4] if len(sp) > 4 else None,
+                        )
+                    )
+                continue
             phases = rec.get("phases") or {}
             tiled, pipe = _phase_tiling(rec)
             cursor = start
@@ -270,6 +296,15 @@ def _emit_wall(comps, events) -> None:
                     )
                 )
                 pcursor += dur
+
+
+def _wall_start(rec: dict) -> float:
+    """A batch's start in wall seconds: ``t0_ns`` where the record has
+    it, else its millisecond close stamp less its wall time."""
+    t0_ns = rec.get("t0_ns")
+    if t0_ns:
+        return float(t0_ns) * 1e-9
+    return float(rec["ts"]) - float(rec.get("wall_s") or 0.0)
 
 
 def trace_document(doc, timebase: str = "logical", limit: int = 0) -> dict:

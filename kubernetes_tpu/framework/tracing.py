@@ -16,6 +16,14 @@ threshold — extended two ways for the two-process split:
   trace_id/parent_span_id), so a server-side batch span logged here
   carries the HOST's trace id and the two processes' logs join on it.
 
+* **One span primitive for the served path** (``SpanSink.span``, used
+  as ``with sched.span("drain/apply"):``): every layer boundary of the
+  batch loop is timed ONCE, and the one interval feeds the open batch's
+  flight record (``spans`` and today's ``phases``), the
+  ``scheduler_phase_duration_seconds`` histogram, the ``ScheduleBatch``
+  step log and — as a ``jax.profiler.TraceAnnotation`` — the profiler's
+  trace, on the device ops' clock, whenever a session is open.
+
 For deep device-side visibility the CLI's ``bench --profile-dir`` wraps
 the run in ``jax.profiler.trace`` (SURVEY §5: "add JAX profiler traces on
 the sidecar")."""
@@ -25,6 +33,11 @@ from __future__ import annotations
 import logging
 import os
 import time
+from threading import get_ident
+
+from jax.profiler import TraceAnnotation
+
+from .metrics import Histogram, _labels_key
 
 logger = logging.getLogger("kubernetes_tpu")
 
@@ -83,6 +96,11 @@ class Trace:
 
     def step(self, msg: str) -> None:
         self._steps.append((msg, time.perf_counter()))
+
+    def step_at(self, msg: str, ts: float) -> None:
+        """A step whose ``perf_counter`` reading the caller already took
+        (a span's end: the boundary is timed once)."""
+        self._steps.append((msg, ts))
 
     def nest(self, name: str, **fields) -> "Trace":
         """Open a child span (utiltrace.Nest): same trace id, own span id.
@@ -226,3 +244,218 @@ def stitch_spans(spans: list[dict]) -> list[dict]:
         else:
             roots.append(span)
     return roots
+
+
+# -- the span primitive -------------------------------------------------------
+#
+# Span names are a contract (PERF.md lists them; perfbench readers and
+# perfbench/spans.py match on them).  In the profiler's trace every span
+# is the event ``sched/<name>`` of the /host:CPU plane with the stat
+# ``batch`` (the flight record's ``bid``) plus the call site's keywords.
+
+
+class Span:
+    """One timed interval.  ``t0``/``t1`` are its ``perf_counter``
+    readings and ``dur_s`` their difference, for call sites that feed a
+    derived number (a boundary shared with a phase that spans calls)."""
+
+    __slots__ = ("_sink", "name", "_phase", "_label", "_kw", "_ann", "_rec",
+                 "_idx", "stats", "t0", "t1", "dur_s")
+
+    def __init__(self, sink: "SpanSink", name: str, phase, label, kw: dict):
+        self._sink = sink
+        self.name = name
+        self._phase = phase
+        # a span that feeds a phase reaches the histogram in the phase's
+        # batch sum (observed when the record is written), not on its own
+        self._label = label if label is not None else ("" if phase else name)
+        self._kw = kw
+        self.stats: dict | None = None
+        self.t1 = self.dur_s = 0.0
+
+    def set(self, key: str, value) -> None:
+        """A number known only at the end (an accumulated sub-time): kept
+        as the fifth element of the record's span entry."""
+        if self.stats is None:
+            self.stats = {}
+        self.stats[key] = value
+
+    def __enter__(self) -> "Span":
+        sink = self._sink
+        rec = sink._rec
+        if rec is not None and sink._owner == get_ident():
+            spans = rec["spans"]
+            self._idx = len(spans)
+            spans.append(None)  # list order is start order
+            sink._stack.append(self._idx)
+            self._rec = rec
+        else:
+            self._rec = None
+        self._ann = TraceAnnotation("sched/" + self.name, batch=sink.bid, **self._kw)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        t1 = self.t1 = time.perf_counter()
+        self._ann.__exit__(et, ev, tb)
+        dur = self.dur_s = t1 - self.t0
+        sink = self._sink
+        rec = self._rec
+        if rec is not None:
+            stack = sink._stack
+            while stack and stack.pop() != self._idx:
+                pass
+            entry = (
+                self.name,
+                int((self.t0 - sink._t0) * 1e6),
+                int(dur * 1e6),
+                stack[-1] if stack else -1,
+            )
+            rec["spans"][self._idx] = (
+                entry if self.stats is None else entry + (self.stats,)
+            )
+            if self._phase is not None:
+                ph = rec["phases"]
+                ph[self._phase] = ph.get(self._phase, 0.0) + dur
+            tr = sink.trace
+            if tr is not None:
+                tr.step_at(self.name, t1)
+        if self._label and sink._hist is not None:
+            sink.cell(self._label).observe(dur)
+        return False
+
+
+class SpanSink:
+    """Where a scheduler's spans land.  ``open`` starts a batch (the
+    accumulator the flight record is built from gains ``spans``, ``t0_ns``
+    and ``bid``); until ``close`` every span entered on the opening thread
+    is appended to it.  Spans entered with no batch open, or on another
+    thread (a handler waiting for the dispatch lock), only observe the
+    histogram and annotate the profiler's trace; such a thread reads
+    ``_rec`` and ``_owner`` unlocked, which can only tell it "not yours",
+    and observes only where its span ends holding the dispatch lock.
+    Always on: with no profiler session an annotation costs a check."""
+
+    __slots__ = ("_hist", "_cells", "_rec", "_stack", "_owner", "_t0",
+                 "bid", "trace")
+
+    def __init__(self, hist=None):
+        self._hist = hist  # HistogramFamily keyed by phase=
+        self._cells: dict = {}
+        self._rec: dict | None = None
+        self._stack: list[int] = []
+        self._owner = 0
+        self._t0 = 0.0
+        self.bid = 0
+        self.trace: Trace | None = None
+
+    def cell(self, label: str):
+        """The histogram cell of one phase label (created on first use)."""
+        c = self._cells.get(label)
+        if c is None:
+            c = self._cells[label] = self._hist.cells.setdefault(
+                _labels_key({"phase": label}), Histogram()
+            )
+        return c
+
+    def span(self, name: str, phase: str | None = None,
+             label: str | None = None, **kw) -> Span:
+        """``phase``: the flight record's phase key this span's seconds
+        add to.  ``label``: the histogram's ``phase=`` value where it is
+        not the span's name (``hint_decode`` keeps the one it has); ``""``
+        for no observation: an interval the histogram already holds
+        under a phase's label, or one that ends on a thread outside the
+        dispatch lock."""
+        return Span(self, name, phase, label, kw)
+
+    def open(self, acc: dict) -> None:
+        self.bid += 1
+        self._owner = get_ident()
+        self._stack.clear()
+        acc["spans"] = []
+        acc["bid"] = self.bid
+        acc["t0_ns"] = time.time_ns()
+        self._rec = acc
+        self._t0 = time.perf_counter()
+
+    def add(self, name: str, t0: float, t1: float) -> None:
+        """An interval between two boundaries spans already timed (a pass
+        in flight across calls): record entry only, top level.  ``t0`` may
+        lie before the batch's start, so the start may be negative."""
+        rec = self._rec
+        if rec is not None:
+            rec["spans"].append(
+                (name, int((t0 - self._t0) * 1e6), int((t1 - t0) * 1e6), -1)
+            )
+
+    def close(self) -> float:
+        """Ends the batch; returns its wall seconds."""
+        wall = time.perf_counter() - self._t0
+        rec, self._rec = self._rec, None
+        self.trace = None
+        if rec is not None and self._stack:
+            # a span still open (an exception unwinding past the batch)
+            # must not leave a hole in the list; indexes stay as they are
+            rec["spans"] = [s or ("?", 0, -1, -1) for s in rec["spans"]]
+            self._stack.clear()
+        return wall
+
+
+# For code that may run without a scheduler (a bare Journal): annotates the
+# profiler's trace and nothing else.
+NULL_SINK = SpanSink()
+
+
+# -- process counters ---------------------------------------------------------
+#
+# Two things a serving process does behind the batch loop's back, counted
+# where they happen: XLA programs built or loaded (every program of the
+# process, not only the pass variants the scheduler holds), and the
+# collector's pauses.  Totals are the process's; a scheduler's registry
+# exports them at scrape time.
+
+# Fires around compile_or_get_cached: a load from the persistent cache
+# counts like a build, with the seconds it took.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class ProcessCounters:
+    def __init__(self) -> None:
+        self.compiles = 0  # programs handed to the backend: built or loaded
+        self.compile_s = 0.0
+        self.gc_collections = [0, 0, 0]
+        self.gc_pause_s = 0.0
+        self.gc_hooked = False
+        self._compile_hooked = False
+        self._gc_t0 = 0.0
+
+    def _on_duration(self, event: str, secs: float, **_kw) -> None:
+        if event == COMPILE_EVENT:
+            self.compiles += 1
+            self.compile_s += secs
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        elif self._gc_t0:
+            self.gc_pause_s += time.perf_counter() - self._gc_t0
+            self.gc_collections[min(int(info.get("generation", 0)), 2)] += 1
+            self._gc_t0 = 0.0
+
+    def hook_compiles(self) -> None:
+        if not self._compile_hooked:
+            import jax.monitoring
+
+            jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+            self._compile_hooked = True
+
+    def hook_gc(self) -> None:
+        if not self.gc_hooked:
+            import gc
+
+            gc.callbacks.append(self._on_gc)
+            self.gc_hooked = True
+
+
+PROCESS = ProcessCounters()

@@ -66,6 +66,7 @@ import time
 import zlib
 
 from .framework.metrics import Histogram, exponential_buckets
+from .framework.tracing import NULL_SINK
 
 _HDR = struct.Struct(">II")  # payload length, crc32(payload)
 MAX_RECORD = 64 << 20
@@ -117,6 +118,9 @@ class Journal:
         # scheduler's own mutation surface (those calls would otherwise
         # re-journal every replayed decision).
         self.muted = False
+        # Where spans go: the scheduler's sink once attached
+        # (attach_journal); alone, the profiler's trace only.
+        self.spans = NULL_SINK
         # Observability (exported as scheduler_journal_* by the
         # scheduler's collector once attached).
         self.appends = 0
@@ -306,11 +310,7 @@ class Journal:
         # a SIGKILL here must recover to the same bindings with NONE of
         # the group applied.
         _crash("mid-group-fsync")
-        if self.fsync_enabled:
-            tf = time.perf_counter()
-            os.fsync(self._f.fileno())
-            self.fsync_s += time.perf_counter() - tf
-            self.fsyncs += 1
+        self._barrier_fsync()
         self.group_commits += 1
         # Durable but not yet applied — the post-append analog at group
         # scope: recovery replays the whole group.
@@ -323,12 +323,18 @@ class Journal:
         fsync raised — re-entering ``group()`` would see zero pending
         appends and skip the fsync, silently acknowledging undurable
         records."""
-        if self.fsync_enabled:
-            tf = time.perf_counter()
-            os.fsync(self._f.fileno())
-            self.fsync_s += time.perf_counter() - tf
-            self.fsyncs += 1
+        self._barrier_fsync()
         self.group_commits += 1
+
+    def _barrier_fsync(self) -> None:
+        """A group's one fsync, as the `drain/journal_fsync` span (which
+        is also what ``fsync_s`` accumulates, and so what the histogram
+        holds as ``journal_fsync``)."""
+        if self.fsync_enabled:
+            with self.spans.span("drain/journal_fsync", label="") as sp:
+                os.fsync(self._f.fileno())
+            self.fsync_s += sp.dur_s
+            self.fsyncs += 1
 
     def snapshot(self, state: dict) -> None:
         """Checkpoint the full scheduler state and truncate the log at the
@@ -340,7 +346,14 @@ class Journal:
         self._check_fence()
         _crash("pre-snapshot")
         doc = {"epoch": self.epoch, "seq": self.seq, "state": state}
-        blob = json.dumps(doc, separators=(",", ":")).encode()
+        with self.spans.span("snapshot/encode"):
+            blob = json.dumps(doc, separators=(",", ":")).encode()
+        with self.spans.span("snapshot/write", bytes=len(blob)):
+            self._write_snapshot(blob)
+
+    def _write_snapshot(self, blob: bytes) -> None:
+        """Temp file + fsync + replace + directory fsync, then truncate
+        the log at the barrier."""
         tmp = self.snap_path + ".tmp"
         c = CRASH
         with open(tmp, "wb") as f:

@@ -165,6 +165,10 @@ class QueuedPodInfo:
     # Requeue-verdict class this pod was filed under when it entered the
     # unschedulable pool (set by _unsched_insert, read by _unsched_remove).
     unsched_class: tuple | None = None
+    # When the server first held the pod, where that was before the queue
+    # did (a hint frame's arrival, on the queue's clock; 0 = the queue
+    # add): what a flight record's queue_wait counts from.
+    held_at: float = 0.0
 
 
 class SchedulingQueue:
@@ -386,7 +390,18 @@ class SchedulingQueue:
 
     # -- add / pop -----------------------------------------------------------
 
-    def add(self, pod: t.Pod) -> None:
+    def now(self) -> float:
+        """The queue's clock: what ``held_at`` is stamped on."""
+        return self._clock()
+
+    def held_for(self, infos: list[QueuedPodInfo]) -> tuple[float, float]:
+        """(sum, max) over just-popped ``infos`` of the seconds since the
+        server first held each pod: one subtraction a pod."""
+        now = self._clock()
+        waits = [now - (qp.held_at or qp.initial_attempt_timestamp) for qp in infos]
+        return sum(waits), max(waits)
+
+    def add(self, pod: t.Pod, held_at: float = 0.0) -> None:
         now = self._clock()
         if pod.uid in self._quarantine:
             # Informer re-deliveries must not resurrect a poison pod into
@@ -401,6 +416,8 @@ class SchedulingQueue:
             if self.tenant_note is not None:
                 self.tenant_note("admitted", pod)
         qp.pod = pod
+        if held_at and not qp.held_at:
+            qp.held_at = held_at
         # PreEnqueue: SchedulingGates holds gated pods out of every queue
         # (plugins/schedulinggates/scheduling_gates.go).
         if (

@@ -28,7 +28,7 @@ from .framework.events import NORMAL, WARNING, EventBroadcaster
 from .framework.flight import FlightRecorder
 from .framework.metrics import MetricsRegistry, TenantMetrics, pod_tenant
 from .framework.status import Diagnosis
-from .framework.tracing import Trace
+from .framework.tracing import PROCESS, SpanSink, Trace
 from .intern import InternTable
 from .ops.common import registered_subset
 from .preemption import PreemptionEvaluator
@@ -522,6 +522,12 @@ class TPUScheduler:
             "scheduler_phase_duration_seconds",
             "Per-batch scheduling phase duration, by phase.",
         )
+        # The one span source of the served path (framework/tracing.py):
+        # flight record, this histogram, the ScheduleBatch step log and
+        # the profiler's trace all read the same interval.
+        self.spans = SpanSink(self._phase_hist)
+        self.span = self.spans.span
+        self._serialize_s = 0.0  # serialize.to_dict seconds of the open drain
         # The tpulint-clean companion of the upstream-parity
         # plugin_execution_duration_seconds exposition: same sampled
         # observations, scheduler_-prefixed family.
@@ -678,9 +684,36 @@ class TPUScheduler:
             "scheduler_device_memory_bytes",
             "Device allocator stats when the backend reports them.",
         )
+        # Process counters (framework/tracing.PROCESS): every XLA program
+        # of the process, and the collector's pauses once `serve` hooked
+        # gc.callbacks.
+        PROCESS.hook_compiles()
+        jax_compiles = reg.counter(
+            "scheduler_jax_compiles_total",
+            "XLA programs handed to the backend by this process, built "
+            "or loaded from the persistent cache.",
+        )
+        jax_compile_s = reg.counter(
+            "scheduler_jax_compile_seconds_total",
+            "Seconds spent building or loading those programs.",
+        )
+        gc_collections = reg.counter(
+            "scheduler_gc_collections_total",
+            "Python collector runs in the serving process, by generation.",
+        )
+        gc_pause = reg.counter(
+            "scheduler_gc_pause_seconds_total",
+            "Seconds the serving process spent inside collector runs.",
+        )
 
         def collect(_reg) -> None:
             m = self.metrics
+            jax_compiles.set(PROCESS.compiles)
+            jax_compile_s.set(PROCESS.compile_s)
+            if PROCESS.gc_hooked:
+                for gen, n in enumerate(PROCESS.gc_collections):
+                    gc_collections.set(n, generation=str(gen))
+                gc_pause.set(PROCESS.gc_pause_s)
             # The reference's partitioning label set {scheduled,
             # unschedulable, error} (metrics.go:138): the cells sum to the
             # attempt total, so sum(rate(...)) dashboards stay honest.
@@ -740,6 +773,7 @@ class TPUScheduler:
         mutation surface, which would otherwise re-journal every record."""
         self.journal = journal
         self.queue.journal = journal
+        journal.spans = self.spans
         if snapshot_every_batches:
             self.snapshot_every_batches = snapshot_every_batches
         reg = self.metrics.registry
@@ -809,11 +843,10 @@ class TPUScheduler:
         if self.journal is not None:
             from .api import serialize
 
-            data = {
-                "uid": pod.uid,
-                "node": node_name,
-                "pod": serialize.to_dict(pod),
-            }
+            t0 = time.perf_counter()
+            pod_d = serialize.to_dict(pod)
+            self._serialize_s += time.perf_counter() - t0
+            data = {"uid": pod.uid, "node": node_name, "pod": pod_d}
             # Decision provenance rides the WAL: the device tie-break
             # step makes a journal-mode explain's selectHost trace exact
             # without the in-memory ring (replay ignores the field).
@@ -844,7 +877,10 @@ class TPUScheduler:
             return False
         from . import journal as journal_mod
 
-        j.snapshot(journal_mod.scheduler_state(self))
+        with self.span("pipeline/snapshot", phase="snapshot"):
+            with self.span("snapshot/collect"):
+                state = journal_mod.scheduler_state(self)
+            j.snapshot(state)
         self._last_snapshot_batch = self.metrics.batches
         return True
 
@@ -946,14 +982,6 @@ class TPUScheduler:
         if acc is not None:
             acc[key] = acc.get(key, 0) + n
 
-    def _flight_phase(self, key: str, secs: float) -> None:
-        """Accumulate one tiled phase segment (drain/predispatch — the
-        pipeline stages recorded outside _complete_batch's tiling)."""
-        acc = self._flight_acc
-        if acc is not None and secs > 0:
-            ph = acc["phases"]
-            ph[key] = ph.get(key, 0.0) + secs
-
     # -- software pipeline (ISSUE 15, engine/pipeline.py) ---------------------
 
     def _pipeline_active(self) -> bool:
@@ -974,16 +1002,20 @@ class TPUScheduler:
         loop on queue length must also drain these."""
         return self._prefetched is not None or self._predispatched is not None
 
-    def _drain_pending(self, overlapped: bool) -> float:
+    def _drain_pending(self, overlapped: bool) -> None:
         """Drain the current staged commit group (group fsync + applies,
-        engine/pipeline.drain_commit).  Returns the drain's host seconds;
-        records the `drain` flight phase and the placement counter."""
+        engine/pipeline.drain_commit) under the `pipeline/drain` span (the
+        `drain` flight phase), and count where it ran."""
         ticket = self._pending_ticket
         if ticket is None or ticket.drained:
-            return 0.0
+            return
         from .engine.pipeline import drain_commit
 
-        drain_s = drain_commit(self, ticket)
+        if ticket.staged:
+            with self.span("pipeline/drain", phase="drain"):
+                drain_commit(self, ticket)
+        else:
+            drain_commit(self, ticket)
         # Fully drained: release the scheduler's reference so an idle
         # process does not pin the last batch's pods/outcomes until the
         # next batch overwrites the slot.  (A mid-drain exception leaves
@@ -991,13 +1023,11 @@ class TPUScheduler:
         # drain resumes it.)
         self._pending_ticket = None
         if ticket.staged:
-            self._flight_phase("drain", drain_s)
             self._pipeline_drain_counter.inc(
                 kind="overlapped" if overlapped else "inline"
             )
-        return drain_s
 
-    def _predispatch_next(self, tr) -> bool:
+    def _predispatch_next(self) -> bool:
         """Dispatch the prefetched batch k+1 NOW (before batch k's drain)
         so the drain's fsync + applies run under the in-flight device
         pass.  The pass is picked up — or invalidated and re-dispatched —
@@ -1018,11 +1048,14 @@ class TPUScheduler:
         infos, work = pre
         if work["version"] != self.builder.feature_version():
             return False  # stale featurization: let the serial path redo it
-        from .engine.pipeline import Predispatch, nominator_token
-
         self._prefetched = None
         cycle0 = self._cycle
-        t_pd = time.perf_counter()
+        with self.span("pipeline/predispatch", phase="predispatch"):
+            return self._predispatch(infos, work, cycle0)
+
+    def _predispatch(self, infos, work: dict, cycle0: int) -> bool:
+        from .engine.pipeline import Predispatch, nominator_token
+
         try:
             # _dispatch_batch may permute its local infos (the packer);
             # keep OUR list in original pop order for re-dispatch.  The
@@ -1048,11 +1081,7 @@ class TPUScheduler:
             schema=self.builder.schema,
             nominator_token=nominator_token(self),
             cycle0=cycle0,
-            t_dispatch=t_pd,
         )
-        self._flight_phase("predispatch", time.perf_counter() - t_pd)
-        if tr is not None:
-            tr.step("predispatched next batch")
         return True
 
     def _observe_plugin(self, plugin: str, point: str, secs: float) -> None:
@@ -1066,16 +1095,15 @@ class TPUScheduler:
             key = f"{plugin}/{point}"
             acc["plugins"][key] = acc["plugins"].get(key, 0.0) + secs
 
-    def _record_flight(self, acc: dict, t0: float, snap_s: float, jbase) -> None:
+    def _record_flight(self, acc: dict, wall: float, jbase) -> None:
         """Finalize one per-batch flight record: close the phase tiling
         (featurize/device/commit/snapshot + the explicit `other` residual
-        — pop, expiry sweeps, loop overhead), attach the journal's
-        append/fsync slice deltas, and observe every phase into
-        scheduler_phase_duration_seconds."""
+        — pop, expiry sweeps, loop overhead), attach the spans, the
+        queue wait and the journal's append/fsync slice deltas, and
+        observe every phase's batch sum into
+        scheduler_phase_duration_seconds (a span that feeds no phase
+        observed its own name as it ended)."""
         phases = acc["phases"]
-        if snap_s > 0:
-            phases["snapshot"] = phases.get("snapshot", 0.0) + snap_s
-        wall = time.perf_counter() - t0
         # Per-stage serial sum BEFORE the residual: with the pipeline on,
         # a predispatched batch's device window started in the PREVIOUS
         # call, so the stage sum can exceed this call's wall — the excess
@@ -1092,7 +1120,19 @@ class TPUScheduler:
             "dispatch": acc["dispatches"],
             "wall_s": round(wall, 6),
             "phases": {k: round(v, 6) for k, v in phases.items()},
+            # [name, start_us from t0_ns, dur_us, parent index] in start
+            # order (a fifth element holds accumulated sub-times)
+            "spans": acc["spans"],
+            "t0_ns": acc["t0_ns"],
+            "bid": acc["bid"],
         }
+        qw = acc.get("queue_wait")
+        if qw is not None:
+            rec["queue_wait"] = {
+                "pods": qw[0],
+                "sum_ms": round(qw[1] * 1e3, 3),
+                "max_ms": round(qw[2] * 1e3, 3),
+            }
         if self.pipeline_depth >= 2:
             serial_total = serial_s + phases["other"]
             rec["overlap"] = {
@@ -1452,9 +1492,11 @@ class TPUScheduler:
         self.node_lifecycle.forget_node(name)
         self.pod_gc.forget_node(name)
 
-    def add_pod(self, pod: t.Pod) -> None:
+    def add_pod(self, pod: t.Pod, held_at: float = 0.0) -> None:
         """Unassigned pods enter the queue; assigned pods enter the cache
-        (eventhandlers.go:126 addPodToSchedulingQueue / :203 addPodToCache)."""
+        (eventhandlers.go:126 addPodToSchedulingQueue / :203 addPodToCache).
+        ``held_at``: when the server first held the pod, where that was
+        before this call (a hint frame's arrival, on the queue's clock)."""
         if not pod.spec.node_name and self._profile_for(pod) is None:
             return  # another scheduler's pod (responsibleForPod)
         if pod.spec.node_name:
@@ -1489,7 +1531,7 @@ class TPUScheduler:
                 # commit already happened — re-queueing would double-apply
                 # its resource delta on the next drain.
                 return
-            self.queue.add(pod)
+            self.queue.add(pod, held_at)
 
     def update_pod(self, pod: t.Pod) -> None:
         """Pod informer update (eventhandlers.go:136 updatePodInScheduling-
@@ -3108,7 +3150,6 @@ class TPUScheduler:
         per profile (pods group by .spec.scheduler_name).  Binds completed
         between batches by informer-driven notify_prebind are prepended to
         the returned outcomes."""
-        t0 = time.perf_counter()
         j = self.journal
         jbase = (
             (j.appends, j.fsyncs, j.append_latency.total, j.fsync_s)
@@ -3119,7 +3160,7 @@ class TPUScheduler:
             "phases": {}, "plugins": {}, "pods": 0,
             "scheduled": 0, "unschedulable": 0, "dispatches": [],
         }
-        snap_s = 0.0
+        self.spans.open(acc)
         try:
             out = self._schedule_batch_inner()
             if self._prebind_outcomes:
@@ -3132,16 +3173,16 @@ class TPUScheduler:
             self._drain_pending(overlapped=False)
             # Checkpoint at the quiescent point between batches (assume/
             # forget deltas settled); the cadence gate inside keeps this
-            # free when journaling is off or the log hasn't grown.
-            t_snap = time.perf_counter()
+            # free when journaling is off or the log hasn't grown, and
+            # the `pipeline/snapshot` span opens behind it.
             self.maybe_snapshot()
-            snap_s = time.perf_counter() - t_snap
         finally:
             self._flight_acc = None
+            wall = self.spans.close()
             # One record per batch that actually dispatched (empty polls
             # and the per-pod extender path stay off the ring).
             if acc["pods"]:
-                self._record_flight(acc, t0, snap_s, jbase)
+                self._record_flight(acc, wall, jbase)
         return out
 
     def _schedule_batch_inner(self) -> list[ScheduleOutcome]:
@@ -3195,7 +3236,7 @@ class TPUScheduler:
             infos, work = pre
             self._mark_inflight(infos)
         else:
-            infos = self.queue.pop_batch(self.batch_size)
+            infos = self._pop_batch()
             work = None
         if not infos:
             return []
@@ -3216,6 +3257,7 @@ class TPUScheduler:
             pods=len(infos),
         ) as tr:
             self.last_batch_span = tr
+            self.spans.trace = tr
             if self.extenders:
                 # Extender chain: per-pod eval-only path (see extender.py).
                 out: list[ScheduleOutcome] = []
@@ -3287,13 +3329,10 @@ class TPUScheduler:
                 self._pd_consec_invalid = min(
                     self._pd_consec_invalid + 4, 16
                 )
-                with tr.nest("DevicePassDispatch"):
-                    ctx = self._dispatch_batch(infos, self.profile, None)
-                tr.step("re-dispatched invalidated predispatch")
+                tr.step("re-dispatching invalidated predispatch")
+                ctx = self._dispatch_batch(infos, self.profile, None)
         else:
-            with tr.nest("DevicePassDispatch") as _sp:
-                ctx = self._dispatch_batch(infos, self.profile, work)
-            tr.step("dispatched device pass")
+            ctx = self._dispatch_batch(infos, self.profile, work)
         # Overlap victim packing + transfer with the in-flight device pass
         # when recent batches needed preemption (the dispatch is async; the
         # ~O(nodes) packing walk rides inside the pass's device time).
@@ -3327,7 +3366,7 @@ class TPUScheduler:
         if self._prefetch_enabled and not ctx["active"] & {
             "VolumeBinding", "DynamicResources"
         }:
-            nxt = self.queue.pop_batch(self.batch_size)
+            nxt = self._pop_batch()
             if nxt:
                 # Prefetched gang members still count as "coming" for
                 # the WaitOnPermit quorum (gang_pending) until their
@@ -3335,14 +3374,11 @@ class TPUScheduler:
                 for qp in nxt:
                     if qp.pod.spec.pod_group:
                         self.queue._track_gang_member(qp)
-                self._prefetched = (
-                    nxt, self._featurize_batch(nxt, self.profile)
-                )
-                tr.step("prefetched next batch")
-        with tr.nest("CompleteBatch"):
-            out = self._complete_batch(
-                ctx, defer_drain=self._pipeline_active()
-            )
+                with self.span("batch/prefetch") as sp:
+                    nxt_work = self._featurize_batch(nxt, self.profile)
+                nxt_work["feat_s"] = sp.dur_s
+                self._prefetched = (nxt, nxt_work)
+        out = self._complete_batch(ctx, defer_drain=self._pipeline_active())
         # Pipeline depth >= 2: dispatch batch k+1 BEFORE draining batch
         # k's staged commit group, so the group fsync and the apply loop
         # run while the device crunches the next pass.  With no next
@@ -3355,17 +3391,33 @@ class TPUScheduler:
             and not ticket.drained
             and self._pipeline_active()
         ):
-            predispatched = self._predispatch_next(tr)
+            predispatched = self._predispatch_next()
         self._drain_pending(overlapped=predispatched)
-        tr.step("completed (bind/permit/postfilter)")
         return out
+
+    def _pop_batch(self) -> list[QueuedPodInfo]:
+        """Pop the next batch, and add to the open record's queue_wait how
+        long its pods waited since the server first held them (a hint
+        frame's arrival, else the queue add).  A prefetched batch is
+        popped in the call before the one that completes it, so a record
+        counts the pods popped during its call."""
+        with self.span("batch/pop"):
+            infos = self.queue.pop_batch(self.batch_size)
+            acc = self._flight_acc
+            if infos and acc is not None:
+                total, longest = self.queue.held_for(infos)
+                qw = acc.setdefault("queue_wait", [0, 0.0, 0.0])
+                qw[0] += len(infos)
+                qw[1] += total
+                qw[2] = max(qw[2], longest)
+        return infos
 
     def _featurize_batch(self, infos: list[QueuedPodInfo], profile: Profile) -> dict:
         """Host featurization for one batch — separable from dispatch so the
         driver can overlap featurize(k+1) with device(k).  Featurization may
         grow vocab/schema (forcing a state rebuild at dispatch).  Always
-        pads to the full batch size: one batch shape → one XLA program."""
-        t0 = time.perf_counter()
+        pads to the full batch size: one batch shape → one XLA program.
+        The caller's span times it and sets ``feat_s``."""
         # ~10% of batches record per-plugin featurize durations
         # (plugin_execution_duration_seconds, metrics.go:256).
         sample = (
@@ -3380,7 +3432,6 @@ class TPUScheduler:
                 self._observe_plugin(op_name, "Featurize", secs)
         return {
             "batch": batch, "deltas": deltas, "active": active,
-            "feat_s": time.perf_counter() - t0,
             "version": self.builder.feature_version(),
         }
 
@@ -3453,20 +3504,36 @@ class TPUScheduler:
     ) -> dict:
         """Flush state and dispatch the device pass (async).  A prefetched
         ``work`` is dropped when anything featurization reads changed since
-        (catalog binds, vocab growth from another profile's batch)."""
-        t_f0 = time.perf_counter()  # flight tiling: featurize segment start
-        if self.fault_injector is not None:
-            # Injected engine faults fire HERE — before featurization and
-            # any state mutation — so the recovery path retries against
-            # clean state, exactly like an exception thrown by the real
-            # featurize/dispatch code below would.
-            self.fault_injector.on_engine_dispatch([qp.pod for qp in infos])
-        if work is not None and work["version"] != self.builder.feature_version():
-            work = None  # stale prefetch
-        if work is None:
-            work = self._featurize_batch(infos, profile)
-        self._inject_nomrows(work, infos)
-        t1 = time.perf_counter()
+        (catalog binds, vocab growth from another profile's batch).
+        Two spans tile it: `batch/featurize` (the `featurize` phase of the
+        record that completes this pass) and `pass/dispatch`, whose start
+        is the `device` phase's."""
+        with self.span("batch/featurize", label="") as sp_f:
+            if self.fault_injector is not None:
+                # Injected engine faults fire HERE — before featurization
+                # and any state mutation — so the recovery path retries
+                # against clean state, exactly like an exception thrown by
+                # the real featurize/dispatch code below would.
+                self.fault_injector.on_engine_dispatch([qp.pod for qp in infos])
+            if work is not None and work["version"] != self.builder.feature_version():
+                work = None  # stale prefetch
+            fresh = work is None
+            if fresh:
+                work = self._featurize_batch(infos, profile)
+            self._inject_nomrows(work, infos)
+        if fresh:
+            work["feat_s"] = sp_f.dur_s
+        with self.span("pass/dispatch", pods=len(infos)) as sp_d:
+            ctx = self._dispatch_pass(infos, profile, work)
+        ctx["t1"] = sp_d.t0
+        ctx["feat_phase_s"] = sp_f.dur_s
+        return ctx
+
+    def _dispatch_pass(
+        self, infos: list[QueuedPodInfo], profile: Profile, work: dict
+    ) -> dict:
+        """From the featurized rows to the jitted call returning (async):
+        invariants, state flush, packing, one device_put, the call."""
         # Batch invariants (interned term → topo slot) may grow TK/DV: build
         # them after featurization, before the state flush.
         inv = self._full_inv()
@@ -3508,7 +3575,7 @@ class TPUScheduler:
                 self._dispatch_counter.inc(kind="pinned")
                 return dict(
                     work, infos=infos, profile=profile, inv=inv, inv_d=inv_d,
-                    new_state=new_state, result=result, t1=t1, t_f0=t_f0,
+                    new_state=new_state, result=result,
                     schema=self.builder.schema, chunk=self.chunk_size,
                     pinned=True, nom_pinned=nom_pinned,
                 )
@@ -3527,32 +3594,32 @@ class TPUScheduler:
             # device parallelism exactly when affinity workloads needed it
             # most (and re-walked every pod per halving iteration on this
             # hot path).
-            t_pack0 = time.perf_counter()
-            npods = len(infos)
-            plan = pack_batch(work["batch"], npods, chunk)
-            chunk = plan.width
-            if plan.perm is not None:
-                perm = plan.perm
-                infos = [infos[j] for j in perm]
-                work["deltas"] = [work["deltas"][j] for j in perm]
-                full_perm = np.arange(self.batch_size, dtype=np.int64)
-                full_perm[:npods] = perm
-                work["batch"] = {
-                    key2: np.asarray(arr)[full_perm]
-                    for key2, arr in work["batch"].items()
-                }
-                # Tie-break seeds ride the pod: row r re-draws the seed of
-                # its ORIGINAL dispatch position, so the packed scan picks
-                # exactly what the sequential scan would have picked.
-                soff = np.arange(self.batch_size, dtype=np.int32)
-                soff[:npods] = perm
-                work["batch"]["step_offset"] = soff
-                self.metrics.packed_batches += 1
-                self._flight_add("packed", 1)
-            self.metrics.pack_collisions += plan.collisions
-            self.metrics.pack_width = plan.width
-            self.metrics.pack_classes = plan.n_classes
-            pack_s = time.perf_counter() - t_pack0
+            with self.span("batch/pack", label="") as sp_p:
+                npods = len(infos)
+                plan = pack_batch(work["batch"], npods, chunk)
+                chunk = plan.width
+                if plan.perm is not None:
+                    perm = plan.perm
+                    infos = [infos[j] for j in perm]
+                    work["deltas"] = [work["deltas"][j] for j in perm]
+                    full_perm = np.arange(self.batch_size, dtype=np.int64)
+                    full_perm[:npods] = perm
+                    work["batch"] = {
+                        key2: np.asarray(arr)[full_perm]
+                        for key2, arr in work["batch"].items()
+                    }
+                    # Tie-break seeds ride the pod: row r re-draws the seed
+                    # of its ORIGINAL dispatch position, so the packed scan
+                    # picks exactly what the sequential scan would have.
+                    soff = np.arange(self.batch_size, dtype=np.int32)
+                    soff[:npods] = perm
+                    work["batch"]["step_offset"] = soff
+                    self.metrics.packed_batches += 1
+                    self._flight_add("packed", 1)
+                self.metrics.pack_collisions += plan.collisions
+                self.metrics.pack_width = plan.width
+                self.metrics.pack_classes = plan.n_classes
+            pack_s = sp_p.dur_s
         if "step_offset" not in work["batch"]:
             # Identity offsets: ONE compiled program shape whether or not
             # this batch was reordered.
@@ -3617,8 +3684,8 @@ class TPUScheduler:
         self._dispatch_counter.inc(kind="batch")
         return dict(
             work, infos=infos, profile=profile, inv=inv, inv_d=inv_d,
-            batch_d=batch_d, new_state=new_state, result=result, t1=t1,
-            t_f0=t_f0, schema=self.builder.schema, chunk=chunk,
+            batch_d=batch_d, new_state=new_state, result=result,
+            schema=self.builder.schema, chunk=chunk,
             cycle0=cycle0, pack_s=pack_s, dom_out=dom_out,
         )
 
@@ -3789,13 +3856,15 @@ class TPUScheduler:
              sp_picks, sp_vmask) = device_fetch(
                 (result.picks, result.scores, result.feasible_counts,
                  result.fail_masks, result.processed,
-                 spec["out"].picks, spec["out"].vic_mask)
+                 spec["out"].picks, spec["out"].vic_mask),
+                span=self.span,
             )
             ctx["spec_res"] = (sp_picks, sp_vmask)
         else:
             picks, scores, feas, fails, processed = device_fetch(
                 (result.picks, result.scores, result.feasible_counts,
-                 result.fail_masks, result.processed)
+                 result.fail_masks, result.processed),
+                span=self.span,
             )
         if self._truncated:
             # Advance the rotating start by this batch's processedNodes sum
@@ -3908,528 +3977,528 @@ class TPUScheduler:
             # the churn-workload magnet); once earlier commits are visible
             # they place cleanly in one pass instead of one scan step each.
             all_deferred = list(deferred)
-            if ctx["chunk"] > 1 and len(deferred) > self.tail_size:
-                deferred = run_tail(deferred, ctx["chunk"], self.batch_size)
-            # Round 2 — strict sequential-equivalent finisher (chunk=1
-            # never defers, so this always terminates).
-            if deferred:
-                run_tail(deferred, 1, self.tail_size)
+            with self.span("pass/tail", pods=len(deferred)):
+                if ctx["chunk"] > 1 and len(deferred) > self.tail_size:
+                    deferred = run_tail(deferred, ctx["chunk"], self.batch_size)
+                # Round 2 — strict sequential-equivalent finisher (chunk=1
+                # never defers, so this always terminates).
+                if deferred:
+                    run_tail(deferred, 1, self.tail_size)
             tail_placed = any(picks[i] >= 0 for i in all_deferred)
-        t2 = time.perf_counter()
-        self._last_batch_meta = (
-            {
-                k: (v.shape, np.asarray(v).dtype)
-                for k, v in batch.items()
-                if k != "uniform_all"  # scalar flag, not a feature row
-            },
-            active,
-        )
-        self.builder.absorb_device_state(new_state)
-        # Carry the scan-maintained domain tables into the next batch —
-        # valid only under the exact (schema, mutation_epoch) they were
-        # stashed at; any host mutation in between forces a device-side
-        # rebuild.  A batch whose prefetch grew the schema mid-flight
-        # drops the carry (its arrays are shaped for the old buckets).
-        if ctx.get("pinned"):
-            pass  # carry already dropped at dispatch
-        elif ctx["schema"] == self.builder.schema and "dom_out" in ctx:
-            self._dom_carry = ctx["dom_out"]
-            self._dom_token = (
-                self.builder.schema, self.builder.mutation_epoch
+        # The commit stage starts where the pass ends: this span's start
+        # closes the `device` phase (dispatch to fetched, tails included).
+        with self.span("commit/stage", phase="commit") as cs:
+            t2 = cs.t0
+            self._last_batch_meta = (
+                {
+                    k: (v.shape, np.asarray(v).dtype)
+                    for k, v in batch.items()
+                    if k != "uniform_all"  # scalar flag, not a feature row
+                },
+                active,
             )
-        else:
-            self._dom_carry = None
-
-        outcomes: list[ScheduleOutcome] = []
-        now = time.monotonic()
-        # The batch's staged commit group (engine/pipeline.CommitTicket):
-        # binds that pass Permit + Reserve stage here and journal + apply
-        # together under ONE group fsync — at the serial point below
-        # (depth 1, or any batch with failures), or deferred under the
-        # next batch's in-flight device pass (_batch_traced_inner).
-        from .engine.pipeline import CommitTicket
-
-        ticket = CommitTicket(now=now)
-        if self.queue.admission is not None:
-            # This batch's weighted-fair debits (pop order), captured by
-            # the batch's OWN uids — at depth 2 the prefetch has already
-            # popped batch k+1, whose intents must ride k+1's ticket.
-            # Failed pods' debits stay in: an admission attempt costs
-            # credit whether or not the bind lands.
-            ticket.admission = self.queue.admission.take_intents(
-                [qp.pod.uid for qp in infos]
-            )
-        self._pending_ticket = ticket
-        m = self.metrics
-        m.batches += 1
-        m.featurize_time_s += ctx["feat_s"]
-        m.device_time_s += t2 - t1
-        m.registry.observe_point("Featurize", ctx["feat_s"])
-        m.registry.observe_point("DevicePass", t2 - t1)
-        m.registry.attempt_duration.observe(t2 - t1 + ctx["feat_s"])
-        failed: list[tuple[int, QueuedPodInfo, ScheduleOutcome]] = []
-        nom_pinned = ctx.get("nom_pinned")
-        # Phase 1 — assume every pick (cache.go:361 AssumePod; the device
-        # already committed the deltas in-scan).
-        placed: list[tuple[int, QueuedPodInfo, str]] = []
-        for i, qp in enumerate(infos):
-            m.schedule_attempts += 1
-            row = int(picks[i])
-            if row < 0 and row != -3 and nom_pinned is not None and nom_pinned[i]:
-                # The nominated node alone failed: fall back to the FULL
-                # node list next batch (schedule_one.go:547 does so in the
-                # same cycle) — NOT the failure path, whose PostFilter
-                # would preempt again on top of a live nomination.
-                qp.nom_pin_failed = True
-                self.queue.reactivate(qp)
-                continue
-            if row >= 0:
-                node_name = self.cache.node_name_at_row(row)
-                assert node_name is not None, f"pick={row} maps to no node"
-                self.cache.assume_pod(qp.pod, node_name, device_already=True, delta=deltas[i])
-                # A placed pod's nomination is spent (nominator.go deletes
-                # on assume).
-                if self.nominator:
-                    self.nominator.pop(qp.pod.uid, None)
-                qp.pod.status.nominated_node_name = ""
-                placed.append((i, qp, node_name))
-                if self.journal is not None or self.provenance is not None:
-                    self._tie_pending[qp.pod.uid] = self._tie_step_of(
-                        i, ctx, batch
-                    )
-                if self.provenance is not None:
-                    self._provenance_capture(
-                        qp.pod.uid, node_name, row, i, ctx, batch,
-                        scores, feas, fails, profile,
-                    )
-            elif row == -3:
-                continue  # already requeued (schema grew mid-flight)
+            self.builder.absorb_device_state(new_state)
+            # Carry the scan-maintained domain tables into the next batch —
+            # valid only under the exact (schema, mutation_epoch) they were
+            # stashed at; any host mutation in between forces a device-side
+            # rebuild.  A batch whose prefetch grew the schema mid-flight
+            # drops the carry (its arrays are shaped for the old buckets).
+            if ctx.get("pinned"):
+                pass  # carry already dropped at dispatch
+            elif ctx["schema"] == self.builder.schema and "dom_out" in ctx:
+                self._dom_carry = ctx["dom_out"]
+                self._dom_token = (
+                    self.builder.schema, self.builder.mutation_epoch
+                )
             else:
-                failed.append((i, qp, None))
+                self._dom_carry = None
 
-        # Phase 2 — Permit (RunPermitPlugins, runtime/framework.go:1443;
-        # reference extension-point order: Permit precedes PreBind, so a
-        # cancelled group never durably binds volumes).  Each registered
-        # PermitPlugin judges the batch's placed pods and returns
-        # group-level allow/wait/reject; the loop owns only the generic
-        # mechanics (waiting room, rollback, timeouts).
-        rollback: set[str] = set()
-        wait: set[str] = set()
-        admitted: set[str] = set()
-        owner: dict[str, object] = {}
-        if placed or self.permit_waiting:
-            placed_view = [(qp, node) for _i, qp, node in placed]
-            decisions = [
-                (plugin, plugin.judge_batch(placed_view, self))
-                for plugin in self.permit_plugins
-            ]
-            # Most-restrictive-wins across plugins (RunPermitPlugins stops
-            # at the first reject; any wait holds the pod): reject > wait >
-            # admit, with the group owned by its most restrictive decider.
-            for plugin, dec in decisions:
-                for g in dec.reject:
-                    rollback.add(g)
-                    owner[g] = plugin
-            for plugin, dec in decisions:
-                for g in dec.wait - rollback:
-                    wait.add(g)
-                    owner.setdefault(g, plugin)
-            for plugin, dec in decisions:
-                for g in dec.admit - rollback - wait:
-                    admitted.add(g)
-                    owner.setdefault(g, plugin)
+            outcomes: list[ScheduleOutcome] = []
+            now = time.monotonic()
+            # The batch's staged commit group (engine/pipeline.CommitTicket):
+            # binds that pass Permit + Reserve stage here and journal + apply
+            # together under ONE group fsync — at the serial point below
+            # (depth 1, or any batch with failures), or deferred under the
+            # next batch's in-flight device pass (_batch_traced_inner).
+            from .engine.pipeline import CommitTicket
 
-        # Waiters of rejected groups roll back with their group; waiters of
-        # admitted groups join this batch's finalize list.
-        entries: list[tuple[QueuedPodInfo, str, int, int]] = [
-            (qp, node, int(scores[i]), int(feas[i])) for i, qp, node in placed
-        ]
-        for g in rollback:
-            self.permit_wait_since.pop(g, None)
-            pl = owner.get(g) or self.permit_wait_owner.get(g)
-            self.permit_wait_owner.pop(g, None)
-            for qp, _node, _s, feasn in self.permit_waiting.pop(g, ()):
-                self.cache.forget_pod(qp.pod.uid)
-                outcomes.append(ScheduleOutcome(qp.pod, None, 0, feasn))
-                pl.on_rollback(qp, self)
-        for g in admitted:
-            self.permit_wait_since.pop(g, None)
-            self.permit_wait_owner.pop(g, None)
-            entries.extend(self.permit_waiting.pop(g, ()))
-        for g in wait:
-            # One GangWaiting per group per batch (the coscheduling
-            # plugin's Permit-wait narration); the ring aggregates repeats.
-            self.recorder.event(
-                f"podgroup/{g}", NORMAL, "GangWaiting",
-                f"gang {g} waiting on Permit for quorum",
-            )
-
-        # Phase 3 — Reserve + PreBind + bind: each registered ReservePlugin
-        # reserves host-side state on the chosen node (VolumeBinding PreBind
-        # volume_binding.go:521, DRA claim allocation), unwinding in reverse
-        # on failure (RunReservePluginsUnreserve).  A pod that lost a
-        # same-batch race is forgotten and retried — the assume/forget
-        # protocol (cache.go:404 ForgetPod).  If the loser belongs to a
-        # permit group, the whole group rolls back with it — including
-        # reverting peers' reservations — so a gang never lands partially
-        # bound below minMember (ADVICE r1).
-        finalized_by_group: dict[str, list] = {}
-        race_rollback: set[str] = set()  # transient (PV race): retry on timer
-        prebind_parked: set[str] = set()  # pods gone to the PreBind wait room
-        prebind_s = 0.0
-        # Per-plugin sampled Reserve durations: ONE gate per batch (the
-        # reference samples per scheduling attempt, schedule_one.go:104).
-        sample_rp = bool(entries) and m.registry.sample_plugins("reserve")
-        for qp, node_name, score, feasn in entries:
-            g, gpl = self._permit_group(qp.pod)
-            if g in rollback:
-                self.cache.forget_pod(qp.pod.uid)
-                outcomes.append(ScheduleOutcome(qp.pod, None, 0, feasn))
-                # Plugin rollback (not add_unschedulable): an ex-waiter's
-                # queue._info entry was dropped by done() when it entered the
-                # waiting room and must be restored with the original qp.
-                gpl.on_rollback(qp, self)
-                continue
-            if g in wait:
-                # WaitOnPermit: off-queue, still assumed, until quorum or
-                # the owning plugin's timeout (expire_waiting_gangs).
-                self.queue.done(qp.pod.uid)
-                self.permit_waiting.setdefault(g, []).append(
-                    (qp, node_name, score, feasn)
+            ticket = CommitTicket(now=now)
+            if self.queue.admission is not None:
+                # This batch's weighted-fair debits (pop order), captured by
+                # the batch's OWN uids — at depth 2 the prefetch has already
+                # popped batch k+1, whose intents must ride k+1's ticket.
+                # Failed pods' debits stay in: an admission attempt costs
+                # credit whether or not the bind lands.
+                ticket.admission = self.queue.admission.take_intents(
+                    [qp.pod.uid for qp in infos]
                 )
-                self.permit_wait_since.setdefault(g, now)
-                self.permit_wait_owner[g] = owner.get(g, gpl)
-                continue
-            undos: list = []  # [(plugin, undo)] in reserve order
-            reserve_failed = False
-            relevant = [
-                rp for rp in self._reserve_for(qp.pod) if rp.relevant(qp.pod, self)
-            ]
-            t_pb = time.perf_counter() if relevant else 0.0
-            for rp in relevant:
-                t_rp = time.perf_counter() if sample_rp else 0.0
-                u = rp.reserve(qp.pod, node_name, self)
-                if sample_rp:
-                    self._observe_plugin(
-                        getattr(rp, "name", type(rp).__name__), "Reserve",
-                        time.perf_counter() - t_rp,
-                    )
-                if u is None:
-                    for rp2, u2 in reversed(undos):
-                        rp2.unreserve(u2, self)
-                    reserve_failed = True
-                    break
-                undos.append((rp, u))
-            if relevant:
-                prebind_s += time.perf_counter() - t_pb
-            if reserve_failed:
-                # Reserve lost a same-batch race (PV or claim allocation).
-                self.cache.forget_pod(qp.pod.uid)
-                outcomes.append(ScheduleOutcome(qp.pod, None, 0, feasn))
-                if g:
-                    # The whole group retries together, with peers'
-                    # reservations reverted.
-                    rollback.add(g)
-                    race_rollback.add(g)
-                    gpl.on_rollback(qp, self)
-                    # Same-batch mates are still STAGED (their journal
-                    # records unwritten, gang credit uncounted): unstage
-                    # — nothing on the log or in spec to unwind.
-                    for qp2, out2, undos2 in finalized_by_group.pop(g, ()):
-                        for rp2, u2 in reversed(undos2):
-                            rp2.unreserve(u2, self)
-                        ticket.unstage(qp2.pod.uid)
-                        self.cache.forget_pod(qp2.pod.uid)
-                        out2.node_name, out2.score = None, 0
-                        gpl.on_rollback(qp2, self)
-                    # Same-batch mates already parked in the PreBind wait
-                    # room revert with the group too.
-                    for uid2 in [
-                        u for u, e in self.prebind_waiting.items()
-                        if e["g"] == g
-                    ]:
-                        e = self.prebind_waiting.pop(uid2)
-                        prebind_parked.discard(uid2)
-                        for rp2, u2 in reversed(e["undos"]):
-                            rp2.unreserve(u2, self)
-                        self.cache.forget_pod(uid2)
-                        outcomes.append(
-                            ScheduleOutcome(e["qp"].pod, None, 0, e["feasn"])
+            self._pending_ticket = ticket
+            m = self.metrics
+            m.batches += 1
+            m.featurize_time_s += ctx["feat_s"]
+            m.device_time_s += t2 - t1
+            m.registry.observe_point("Featurize", ctx["feat_s"])
+            m.registry.observe_point("DevicePass", t2 - t1)
+            m.registry.attempt_duration.observe(t2 - t1 + ctx["feat_s"])
+            failed: list[tuple[int, QueuedPodInfo, ScheduleOutcome]] = []
+            nom_pinned = ctx.get("nom_pinned")
+            # Phase 1 — assume every pick (cache.go:361 AssumePod; the device
+            # already committed the deltas in-scan).
+            placed: list[tuple[int, QueuedPodInfo, str]] = []
+            for i, qp in enumerate(infos):
+                m.schedule_attempts += 1
+                row = int(picks[i])
+                if row < 0 and row != -3 and nom_pinned is not None and nom_pinned[i]:
+                    # The nominated node alone failed: fall back to the FULL
+                    # node list next batch (schedule_one.go:547 does so in the
+                    # same cycle) — NOT the failure path, whose PostFilter
+                    # would preempt again on top of a live nomination.
+                    qp.nom_pin_failed = True
+                    self.queue.reactivate(qp)
+                    continue
+                if row >= 0:
+                    node_name = self.cache.node_name_at_row(row)
+                    assert node_name is not None, f"pick={row} maps to no node"
+                    self.cache.assume_pod(qp.pod, node_name, device_already=True, delta=deltas[i])
+                    # A placed pod's nomination is spent (nominator.go deletes
+                    # on assume).
+                    if self.nominator:
+                        self.nominator.pop(qp.pod.uid, None)
+                    qp.pod.status.nominated_node_name = ""
+                    placed.append((i, qp, node_name))
+                    if self.journal is not None or self.provenance is not None:
+                        self._tie_pending[qp.pod.uid] = self._tie_step_of(
+                            i, ctx, batch
                         )
-                        gpl.on_rollback(e["qp"], self)
+                    if self.provenance is not None:
+                        self._provenance_capture(
+                            qp.pod.uid, node_name, row, i, ctx, batch,
+                            scores, feas, fails, profile,
+                        )
+                elif row == -3:
+                    continue  # already requeued (schema grew mid-flight)
                 else:
-                    self.queue.add_backoff(qp)
-                continue
-            pending = set()
-            for rp, u in undos:
-                hook = getattr(rp, "prebind_pending", None)
-                if hook is not None:
-                    pending.update(hook(qp.pod, u, self))
-            if pending:
-                # PreBind wait (RunPreBindPlugins inside the detached
-                # bindingCycle, volume_binding.go:521): the pod stays
-                # ASSUMED off-queue until every key resolves
-                # (notify_prebind) or the bind timeout unreserves it —
-                # the batch itself never blocks.
-                self.queue.done(qp.pod.uid)
-                self.prebind_waiting[qp.pod.uid] = {
-                    "qp": qp, "node": node_name, "score": score,
-                    "feasn": feasn, "undos": undos, "keys": pending,
-                    "g": g, "gpl": gpl, "since": time.monotonic(),
-                    "mates": [],
-                }
-                prebind_parked.add(qp.pod.uid)
-                continue
-            # Write-ahead at GROUP scope (engine/pipeline.drain_commit):
-            # the bind STAGES here; its journal record and its apply
-            # (spec mutation + finish_binding + queue/gang bookkeeping)
-            # both happen at the drain, where the whole group's records
-            # go durable under ONE fsync before any of them applies —
-            # the crash analog of etcd acknowledging a batched txn
-            # before the scheduler trusts any write in it.
-            outcome = ScheduleOutcome(qp.pod, node_name, score, feasn)
-            outcomes.append(outcome)
-            ticket.stage(qp, node_name, outcome)
-            if g:
-                finalized_by_group.setdefault(g, []).append(
-                    (qp, outcome, undos)
+                    failed.append((i, qp, None))
+
+            # Phase 2 — Permit (RunPermitPlugins, runtime/framework.go:1443;
+            # reference extension-point order: Permit precedes PreBind, so a
+            # cancelled group never durably binds volumes).  Each registered
+            # PermitPlugin judges the batch's placed pods and returns
+            # group-level allow/wait/reject; the loop owns only the generic
+            # mechanics (waiting room, rollback, timeouts).
+            rollback: set[str] = set()
+            wait: set[str] = set()
+            admitted: set[str] = set()
+            owner: dict[str, object] = {}
+            if placed or self.permit_waiting:
+                placed_view = [(qp, node) for _i, qp, node in placed]
+                decisions = [
+                    (plugin, plugin.judge_batch(placed_view, self))
+                    for plugin in self.permit_plugins
+                ]
+                # Most-restrictive-wins across plugins (RunPermitPlugins stops
+                # at the first reject; any wait holds the pod): reject > wait >
+                # admit, with the group owned by its most restrictive decider.
+                for plugin, dec in decisions:
+                    for g in dec.reject:
+                        rollback.add(g)
+                        owner[g] = plugin
+                for plugin, dec in decisions:
+                    for g in dec.wait - rollback:
+                        wait.add(g)
+                        owner.setdefault(g, plugin)
+                for plugin, dec in decisions:
+                    for g in dec.admit - rollback - wait:
+                        admitted.add(g)
+                        owner.setdefault(g, plugin)
+
+            # Waiters of rejected groups roll back with their group; waiters of
+            # admitted groups join this batch's finalize list.
+            entries: list[tuple[QueuedPodInfo, str, int, int]] = [
+                (qp, node, int(scores[i]), int(feas[i])) for i, qp, node in placed
+            ]
+            for g in rollback:
+                self.permit_wait_since.pop(g, None)
+                pl = owner.get(g) or self.permit_wait_owner.get(g)
+                self.permit_wait_owner.pop(g, None)
+                for qp, _node, _s, feasn in self.permit_waiting.pop(g, ()):
+                    self.cache.forget_pod(qp.pod.uid)
+                    outcomes.append(ScheduleOutcome(qp.pod, None, 0, feasn))
+                    pl.on_rollback(qp, self)
+            for g in admitted:
+                self.permit_wait_since.pop(g, None)
+                self.permit_wait_owner.pop(g, None)
+                entries.extend(self.permit_waiting.pop(g, ()))
+            for g in wait:
+                # One GangWaiting per group per batch (the coscheduling
+                # plugin's Permit-wait narration); the ring aggregates repeats.
+                self.recorder.event(
+                    f"podgroup/{g}", NORMAL, "GangWaiting",
+                    f"gang {g} waiting on Permit for quorum",
                 )
-        # A parked gang member pins its batch-mates' records so a PreBind
-        # timeout can roll the whole gang back (the repo's gang contract is
-        # all-or-nothing; mates bound this batch revert like a lost PV race).
-        for uid in prebind_parked:
-            entry = self.prebind_waiting.get(uid)
-            if entry is not None and entry["g"]:
-                entry["mates"] = list(finalized_by_group.get(entry["g"], ()))
-        # A group rolled back by a transient PV race re-admits behind backoff
-        # right away — no cluster event will ever fire in a quiet cluster,
-        # and the race loser's next attempt resolves against the updated
-        # volume catalog.
-        for g in race_rollback:
-            self.queue.readmit_gang(g)
-        # Plugins see their groups that are now waiting (e.g. coscheduling
-        # re-attempts queue admission: waiter credit grew).
-        for plugin in self.permit_plugins:
-            plugin_waits = {g for g in wait if owner.get(g) is plugin}
-            if plugin_waits:
-                plugin.post_batch(plugin_waits, self)
-        if prebind_s:
-            m.registry.observe_point("PreBind", prebind_s)
+
+            # Phase 3 — Reserve + PreBind + bind: each registered ReservePlugin
+            # reserves host-side state on the chosen node (VolumeBinding PreBind
+            # volume_binding.go:521, DRA claim allocation), unwinding in reverse
+            # on failure (RunReservePluginsUnreserve).  A pod that lost a
+            # same-batch race is forgotten and retried — the assume/forget
+            # protocol (cache.go:404 ForgetPod).  If the loser belongs to a
+            # permit group, the whole group rolls back with it — including
+            # reverting peers' reservations — so a gang never lands partially
+            # bound below minMember (ADVICE r1).
+            finalized_by_group: dict[str, list] = {}
+            race_rollback: set[str] = set()  # transient (PV race): retry on timer
+            prebind_parked: set[str] = set()  # pods gone to the PreBind wait room
+            prebind_s = 0.0
+            # Per-plugin sampled Reserve durations: ONE gate per batch (the
+            # reference samples per scheduling attempt, schedule_one.go:104).
+            sample_rp = bool(entries) and m.registry.sample_plugins("reserve")
+            for qp, node_name, score, feasn in entries:
+                g, gpl = self._permit_group(qp.pod)
+                if g in rollback:
+                    self.cache.forget_pod(qp.pod.uid)
+                    outcomes.append(ScheduleOutcome(qp.pod, None, 0, feasn))
+                    # Plugin rollback (not add_unschedulable): an ex-waiter's
+                    # queue._info entry was dropped by done() when it entered the
+                    # waiting room and must be restored with the original qp.
+                    gpl.on_rollback(qp, self)
+                    continue
+                if g in wait:
+                    # WaitOnPermit: off-queue, still assumed, until quorum or
+                    # the owning plugin's timeout (expire_waiting_gangs).
+                    self.queue.done(qp.pod.uid)
+                    self.permit_waiting.setdefault(g, []).append(
+                        (qp, node_name, score, feasn)
+                    )
+                    self.permit_wait_since.setdefault(g, now)
+                    self.permit_wait_owner[g] = owner.get(g, gpl)
+                    continue
+                undos: list = []  # [(plugin, undo)] in reserve order
+                reserve_failed = False
+                relevant = [
+                    rp for rp in self._reserve_for(qp.pod) if rp.relevant(qp.pod, self)
+                ]
+                t_pb = time.perf_counter() if relevant else 0.0
+                for rp in relevant:
+                    t_rp = time.perf_counter() if sample_rp else 0.0
+                    u = rp.reserve(qp.pod, node_name, self)
+                    if sample_rp:
+                        self._observe_plugin(
+                            getattr(rp, "name", type(rp).__name__), "Reserve",
+                            time.perf_counter() - t_rp,
+                        )
+                    if u is None:
+                        for rp2, u2 in reversed(undos):
+                            rp2.unreserve(u2, self)
+                        reserve_failed = True
+                        break
+                    undos.append((rp, u))
+                if relevant:
+                    prebind_s += time.perf_counter() - t_pb
+                if reserve_failed:
+                    # Reserve lost a same-batch race (PV or claim allocation).
+                    self.cache.forget_pod(qp.pod.uid)
+                    outcomes.append(ScheduleOutcome(qp.pod, None, 0, feasn))
+                    if g:
+                        # The whole group retries together, with peers'
+                        # reservations reverted.
+                        rollback.add(g)
+                        race_rollback.add(g)
+                        gpl.on_rollback(qp, self)
+                        # Same-batch mates are still STAGED (their journal
+                        # records unwritten, gang credit uncounted): unstage
+                        # — nothing on the log or in spec to unwind.
+                        for qp2, out2, undos2 in finalized_by_group.pop(g, ()):
+                            for rp2, u2 in reversed(undos2):
+                                rp2.unreserve(u2, self)
+                            ticket.unstage(qp2.pod.uid)
+                            self.cache.forget_pod(qp2.pod.uid)
+                            out2.node_name, out2.score = None, 0
+                            gpl.on_rollback(qp2, self)
+                        # Same-batch mates already parked in the PreBind wait
+                        # room revert with the group too.
+                        for uid2 in [
+                            u for u, e in self.prebind_waiting.items()
+                            if e["g"] == g
+                        ]:
+                            e = self.prebind_waiting.pop(uid2)
+                            prebind_parked.discard(uid2)
+                            for rp2, u2 in reversed(e["undos"]):
+                                rp2.unreserve(u2, self)
+                            self.cache.forget_pod(uid2)
+                            outcomes.append(
+                                ScheduleOutcome(e["qp"].pod, None, 0, e["feasn"])
+                            )
+                            gpl.on_rollback(e["qp"], self)
+                    else:
+                        self.queue.add_backoff(qp)
+                    continue
+                pending = set()
+                for rp, u in undos:
+                    hook = getattr(rp, "prebind_pending", None)
+                    if hook is not None:
+                        pending.update(hook(qp.pod, u, self))
+                if pending:
+                    # PreBind wait (RunPreBindPlugins inside the detached
+                    # bindingCycle, volume_binding.go:521): the pod stays
+                    # ASSUMED off-queue until every key resolves
+                    # (notify_prebind) or the bind timeout unreserves it —
+                    # the batch itself never blocks.
+                    self.queue.done(qp.pod.uid)
+                    self.prebind_waiting[qp.pod.uid] = {
+                        "qp": qp, "node": node_name, "score": score,
+                        "feasn": feasn, "undos": undos, "keys": pending,
+                        "g": g, "gpl": gpl, "since": time.monotonic(),
+                        "mates": [],
+                    }
+                    prebind_parked.add(qp.pod.uid)
+                    continue
+                # Write-ahead at GROUP scope (engine/pipeline.drain_commit):
+                # the bind STAGES here; its journal record and its apply
+                # (spec mutation + finish_binding + queue/gang bookkeeping)
+                # both happen at the drain, where the whole group's records
+                # go durable under ONE fsync before any of them applies —
+                # the crash analog of etcd acknowledging a batched txn
+                # before the scheduler trusts any write in it.
+                outcome = ScheduleOutcome(qp.pod, node_name, score, feasn)
+                outcomes.append(outcome)
+                ticket.stage(qp, node_name, outcome)
+                if g:
+                    finalized_by_group.setdefault(g, []).append(
+                        (qp, outcome, undos)
+                    )
+            # A parked gang member pins its batch-mates' records so a PreBind
+            # timeout can roll the whole gang back (the repo's gang contract is
+            # all-or-nothing; mates bound this batch revert like a lost PV race).
+            for uid in prebind_parked:
+                entry = self.prebind_waiting.get(uid)
+                if entry is not None and entry["g"]:
+                    entry["mates"] = list(finalized_by_group.get(entry["g"], ()))
+            # A group rolled back by a transient PV race re-admits behind backoff
+            # right away — no cluster event will ever fire in a quiet cluster,
+            # and the race loser's next attempt resolves against the updated
+            # volume catalog.
+            for g in race_rollback:
+                self.queue.readmit_gang(g)
+            # Plugins see their groups that are now waiting (e.g. coscheduling
+            # re-attempts queue admission: waiter credit grew).
+            for plugin in self.permit_plugins:
+                plugin_waits = {g for g in wait if owner.get(g) is plugin}
+                if plugin_waits:
+                    plugin.post_batch(plugin_waits, self)
+            if prebind_s:
+                m.registry.observe_point("PreBind", prebind_s)
         # Drain the staged commit group at the SERIAL point — unless the
         # pipeline defers it under the next dispatch.  Any batch with
         # failures drains here regardless: PostFilter's victim deletes
         # journal with their own fsyncs, and the WAL's replay order must
         # keep this batch's bind records AHEAD of them (delete-then-bind
         # replay would resurrect a preempted pod).
-        drain_inline_s = 0.0
         if not defer_drain or failed:
-            drain_inline_s = self._drain_pending(overlapped=False)
-        # Metrics after rollbacks settled (success = outcome kept its
-        # node).  Staged successes are accounted by the drain (inline
-        # above at depth 1, under the next device pass at depth >= 2).
-        for outcome in outcomes:
-            if outcome.node_name:
-                if ticket.holds(outcome.pod.uid):
-                    continue  # success accounting rides the drain
-                # Not staged: an inline preemptor commit
-                # (_commit_preempted journals + applies directly) —
-                # its success accounting happens here.
-                if m.scheduled == 0:
-                    m.first_scheduled_ts = now
-                m.scheduled += 1
-                m.last_scheduled_ts = now
-                self._note_bound(outcome.pod, outcome.node_name)
-                self.recorder.event(
-                    outcome.pod.uid, NORMAL, "Scheduled",
-                    f"Successfully assigned {outcome.pod.uid} to "
-                    f"{outcome.node_name}",
-                )
-            else:
+            self._drain_pending(overlapped=False)
+        with self.span("commit/failed", phase="commit", failed=len(failed)):
+            # Metrics after rollbacks settled (success = outcome kept its
+            # node).  Staged successes are accounted by the drain (inline
+            # above at depth 1, under the next device pass at depth >= 2).
+            for outcome in outcomes:
+                if outcome.node_name:
+                    if ticket.holds(outcome.pod.uid):
+                        continue  # success accounting rides the drain
+                    # Not staged: an inline preemptor commit
+                    # (_commit_preempted journals + applies directly) —
+                    # its success accounting happens here.
+                    if m.scheduled == 0:
+                        m.first_scheduled_ts = now
+                    m.scheduled += 1
+                    m.last_scheduled_ts = now
+                    self._note_bound(outcome.pod, outcome.node_name)
+                    self.recorder.event(
+                        outcome.pod.uid, NORMAL, "Scheduled",
+                        f"Successfully assigned {outcome.pod.uid} to "
+                        f"{outcome.node_name}",
+                    )
+                else:
+                    m.unschedulable += 1
+                    # Rollback/race failures carry no device diagnosis; the
+                    # engine-rejected failures get theirs (with the plugin
+                    # set) in the diagnosis loop below.
+                    self.recorder.event(
+                        outcome.pod.uid, WARNING, "FailedScheduling",
+                        f"0/{self.cache.node_count()} nodes available "
+                        "(batch rollback or lost race)",
+                        **self._trace_extra(),
+                    )
+            # Diagnosis from the device's per-op fail bitmask (bit order =
+            # filter_op_names): which plugins rejected nodes this cycle.  A
+            # uniform failing batch (5k no-fit pods, the Unschedulable shape)
+            # produces ONE distinct mask — build each mask's plugin set once.
+            bit_names = filter_op_names(profile, active)
+            mask_sets: dict[int, set] = {}
+            failed2 = []
+            for i, qp, _ in failed:
+                mask = int(fails[i])
+                plugins = mask_sets.get(mask)
+                if plugins is None:
+                    plugins = {
+                        name for b, name in enumerate(bit_names) if mask & (1 << b)
+                    }
+                    mask_sets[mask] = plugins
+                diag = Diagnosis(unschedulable_plugins=plugins)
+                outcome = ScheduleOutcome(qp.pod, None, 0, int(feas[i]), diagnosis=diag)
                 m.unschedulable += 1
-                # Rollback/race failures carry no device diagnosis; the
-                # engine-rejected failures get theirs (with the plugin
-                # set) in the diagnosis loop below.
+                for name in sorted(plugins):
+                    self._unsched_reasons.inc(plugin=name)
+                # FailedScheduling with the diagnosis plugin set (the fitError
+                # message shape: "0/N nodes are available: ...").
                 self.recorder.event(
-                    outcome.pod.uid, WARNING, "FailedScheduling",
-                    f"0/{self.cache.node_count()} nodes available "
-                    "(batch rollback or lost race)",
+                    qp.pod.uid, WARNING, "FailedScheduling",
+                    f"0/{self.cache.node_count()} nodes available: rejected by "
+                    + (", ".join(sorted(plugins)) if plugins else "no feasible nodes"),
+                    plugins=sorted(plugins),
                     **self._trace_extra(),
                 )
-        # Diagnosis from the device's per-op fail bitmask (bit order =
-        # filter_op_names): which plugins rejected nodes this cycle.  A
-        # uniform failing batch (5k no-fit pods, the Unschedulable shape)
-        # produces ONE distinct mask — build each mask's plugin set once.
-        bit_names = filter_op_names(profile, active)
-        mask_sets: dict[int, set] = {}
-        failed2 = []
-        for i, qp, _ in failed:
-            mask = int(fails[i])
-            plugins = mask_sets.get(mask)
-            if plugins is None:
-                plugins = {
-                    name for b, name in enumerate(bit_names) if mask & (1 << b)
-                }
-                mask_sets[mask] = plugins
-            diag = Diagnosis(unschedulable_plugins=plugins)
-            outcome = ScheduleOutcome(qp.pod, None, 0, int(feas[i]), diagnosis=diag)
-            m.unschedulable += 1
-            for name in sorted(plugins):
-                self._unsched_reasons.inc(plugin=name)
-            # FailedScheduling with the diagnosis plugin set (the fitError
-            # message shape: "0/N nodes are available: ...").
-            self.recorder.event(
-                qp.pod.uid, WARNING, "FailedScheduling",
-                f"0/{self.cache.node_count()} nodes available: rejected by "
-                + (", ".join(sorted(plugins)) if plugins else "no feasible nodes"),
-                plugins=sorted(plugins),
-                **self._trace_extra(),
-            )
-            outcomes.append(outcome)
-            failed2.append((i, qp, outcome))
-        failed = failed2
+                outcomes.append(outcome)
+                failed2.append((i, qp, outcome))
+            failed = failed2
 
-        # PostFilter: one batched preemption pass for every failure
-        # (schedule_one.go:196 RunPostFilterPlugins → DefaultPreemption).
-        results = [None] * len(failed)
-        ran_postfilter = False
-        t_post = time.perf_counter()
-        # (Preemption also sits out a schema-grown batch: its pass would mix
-        # old-shape feature rows with rebuilt state; failures just requeue.)
-        spec_applied = False
-        if (
-            failed
-            and self.preemption is not None
-            and "DefaultPreemption" in profile.post_filter
-            and not schema_grew
-        ):
-            ran_postfilter = True
-            if spec is not None and "spec_res" in ctx:
-                # The dry-run already ran, chained on the scan's verdicts;
-                # interpret its results for the pods that FINALLY failed
-                # (tail placements simply never apply theirs).
-                by_index = self.preemption.collect_speculative(
-                    spec, ctx["spec_res"],
-                    {i: qp.pod for i, qp, _ in failed},
-                )
-                results = [by_index.get(i) for i, _qp, _ in failed]
-                spec_applied = True
-            else:
-                rows = {
-                    key: [np.asarray(arr)[i] for i, _, _ in failed]
-                    for key, arr in batch.items()
-                    if key not in ("valid", "pin_row", "uniform_all")
-                }
-                results = self.preemption.preempt_batch(
-                    [qp.pod for _, qp, _ in failed], rows, active,
-                    ctx["inv_d"], profile=profile,
-                    prepacked=ctx.get("prepacked"),
-                )
-        if self.preemption is not None:
-            # Prepack victim tensors next batch only while failures recur.
-            self.preemption.expect_failures = bool(failed)
-        any_victims = False
-        # A SPECULATIVE result's dry-run predates the strict tail.  Inline
-        # commit needs its verdict still valid against post-tail truth:
-        # resources re-check via _fits_now always; hard filters that read
-        # MUTABLE node state (affinity/spread/ports/volumes/DRA) cannot be
-        # re-checked host-side, so when the tail actually placed something
-        # AND such an op is active, speculative results take the
-        # nominate-and-retry path (which re-validates on device).
-        spec_inline_ok = not spec_applied or not tail_placed or not (
-            active & DYNAMIC_HARD_OPS
-        )
-        for (i, qp, outcome), res in zip(failed, results):
-            if res is not None:
-                if self.provenance is not None:
-                    # pickOneNode rationale BEFORE the commit path's
-                    # victim deletes debit the PDB budgets the key reads.
-                    self.provenance.note_preemption(
-                        qp.pod.uid,
-                        {
-                            "node": res.node_name,
-                            "victims": [v.uid for v in res.victims],
-                            "key": self._preempt_key(res.victims),
-                        },
+            # PostFilter: one batched preemption pass for every failure
+            # (schedule_one.go:196 RunPostFilterPlugins → DefaultPreemption).
+            results = [None] * len(failed)
+            ran_postfilter = False
+            t_post = time.perf_counter()
+            # (Preemption also sits out a schema-grown batch: its pass would mix
+            # old-shape feature rows with rebuilt state; failures just requeue.)
+            spec_applied = False
+            if (
+                failed
+                and self.preemption is not None
+                and "DefaultPreemption" in profile.post_filter
+                and not schema_grew
+            ):
+                ran_postfilter = True
+                if spec is not None and "spec_res" in ctx:
+                    # The dry-run already ran, chained on the scan's verdicts;
+                    # interpret its results for the pods that FINALLY failed
+                    # (tail placements simply never apply theirs).
+                    by_index = self.preemption.collect_speculative(
+                        spec, ctx["spec_res"],
+                        {i: qp.pod for i, qp, _ in failed},
                     )
-                if (
-                    self.inline_preempt_commit
-                    and self._can_commit_inline(qp)
-                    and (
-                        not spec_applied
-                        or (
-                            spec_inline_ok
-                            and self._fits_now(res.node_name, deltas[i])
-                        )
-                    )
-                ):
-                    self._commit_preempted(qp, outcome, res, deltas[i], now)
+                    results = [by_index.get(i) for i, _qp, _ in failed]
+                    spec_applied = True
                 else:
-                    # The fit overlay protects the freed node from same/
-                    # next-batch stealers, and the retry's fast path takes
-                    # it (nominator.go AddNominatedPod).
-                    self._record_preemption(qp, outcome, res, deltas[i])
-                any_victims = any_victims or bool(res.victims)
-            elif self.preemption is not None and schema_grew:
-                # Preemption sat this batch out (its compiled pass cannot
-                # mix old-shape feature rows with the rebuilt state) — the
-                # failure must RETRY next batch rather than park: in a
-                # quiet cluster no event would ever wake it, while the
-                # reference would have run PostFilter on this very cycle.
-                self.queue.reactivate(qp)
-            else:
-                # Precise requeue hints: wait only on events the plugins that
-                # actually rejected nodes care about (isPodWorthRequeuing,
-                # scheduling_queue.go:406).  Empty diagnosis (e.g. zero valid
-                # nodes) falls back to the whole filter set.
-                plugins = outcome.diagnosis.unschedulable_plugins if outcome.diagnosis else set()
-                qp.delta = deltas[i]  # the object-aware hints read req
-                self.queue.add_unschedulable(
-                    qp, plugins or set(profile.filters)
-                )
-        if any_victims:
-            # One batched POD_DELETE for every victim this pass, carrying
-            # the affected nodes' post-eviction free capacity (minus the
-            # preemptors' nominated claims) so the fit hint wakes only pods
-            # the freed space could actually seat — without this, every
-            # victim deletion re-activates the whole unschedulable pool
-            # (the preemption-async churn VERDICT r2 weak-1 named).
-            freed_rows = {
-                self.cache.nodes[res.node_name].row
-                for res in results
-                if res is not None and res.victims
-                and res.node_name in self.cache.nodes
-            }
-            self.queue.on_event(Event.POD_DELETE, self._free_ctx(freed_rows))
-        if ran_postfilter:
-            m.registry.observe_point("PostFilter", time.perf_counter() - t_post)
-        if (
-            self.consistency_check_every
-            and m.batches % self.consistency_check_every == 0
-        ):
-            # Quiescent point: host assume/forget deltas all applied.
-            self.check_consistency()
+                    rows = {
+                        key: [np.asarray(arr)[i] for i, _, _ in failed]
+                        for key, arr in batch.items()
+                        if key not in ("valid", "pin_row", "uniform_all")
+                    }
+                    results = self.preemption.preempt_batch(
+                        [qp.pod for _, qp, _ in failed], rows, active,
+                        ctx["inv_d"], profile=profile,
+                        prepacked=ctx.get("prepacked"),
+                    )
+            if self.preemption is not None:
+                # Prepack victim tensors next batch only while failures recur.
+                self.preemption.expect_failures = bool(failed)
+            any_victims = False
+            # A SPECULATIVE result's dry-run predates the strict tail.  Inline
+            # commit needs its verdict still valid against post-tail truth:
+            # resources re-check via _fits_now always; hard filters that read
+            # MUTABLE node state (affinity/spread/ports/volumes/DRA) cannot be
+            # re-checked host-side, so when the tail actually placed something
+            # AND such an op is active, speculative results take the
+            # nominate-and-retry path (which re-validates on device).
+            spec_inline_ok = not spec_applied or not tail_placed or not (
+                active & DYNAMIC_HARD_OPS
+            )
+            for (i, qp, outcome), res in zip(failed, results):
+                if res is not None:
+                    if self.provenance is not None:
+                        # pickOneNode rationale BEFORE the commit path's
+                        # victim deletes debit the PDB budgets the key reads.
+                        self.provenance.note_preemption(
+                            qp.pod.uid,
+                            {
+                                "node": res.node_name,
+                                "victims": [v.uid for v in res.victims],
+                                "key": self._preempt_key(res.victims),
+                            },
+                        )
+                    if (
+                        self.inline_preempt_commit
+                        and self._can_commit_inline(qp)
+                        and (
+                            not spec_applied
+                            or (
+                                spec_inline_ok
+                                and self._fits_now(res.node_name, deltas[i])
+                            )
+                        )
+                    ):
+                        self._commit_preempted(qp, outcome, res, deltas[i], now)
+                    else:
+                        # The fit overlay protects the freed node from same/
+                        # next-batch stealers, and the retry's fast path takes
+                        # it (nominator.go AddNominatedPod).
+                        self._record_preemption(qp, outcome, res, deltas[i])
+                    any_victims = any_victims or bool(res.victims)
+                elif self.preemption is not None and schema_grew:
+                    # Preemption sat this batch out (its compiled pass cannot
+                    # mix old-shape feature rows with the rebuilt state) — the
+                    # failure must RETRY next batch rather than park: in a
+                    # quiet cluster no event would ever wake it, while the
+                    # reference would have run PostFilter on this very cycle.
+                    self.queue.reactivate(qp)
+                else:
+                    # Precise requeue hints: wait only on events the plugins that
+                    # actually rejected nodes care about (isPodWorthRequeuing,
+                    # scheduling_queue.go:406).  Empty diagnosis (e.g. zero valid
+                    # nodes) falls back to the whole filter set.
+                    plugins = outcome.diagnosis.unschedulable_plugins if outcome.diagnosis else set()
+                    qp.delta = deltas[i]  # the object-aware hints read req
+                    self.queue.add_unschedulable(
+                        qp, plugins or set(profile.filters)
+                    )
+            if any_victims:
+                # One batched POD_DELETE for every victim this pass, carrying
+                # the affected nodes' post-eviction free capacity (minus the
+                # preemptors' nominated claims) so the fit hint wakes only pods
+                # the freed space could actually seat — without this, every
+                # victim deletion re-activates the whole unschedulable pool
+                # (the preemption-async churn VERDICT r2 weak-1 named).
+                freed_rows = {
+                    self.cache.nodes[res.node_name].row
+                    for res in results
+                    if res is not None and res.victims
+                    and res.node_name in self.cache.nodes
+                }
+                self.queue.on_event(Event.POD_DELETE, self._free_ctx(freed_rows))
+            if ran_postfilter:
+                m.registry.observe_point("PostFilter", time.perf_counter() - t_post)
+            if (
+                self.consistency_check_every
+                and m.batches % self.consistency_check_every == 0
+            ):
+                # Quiescent point: host assume/forget deltas all applied.
+                self.check_consistency()
         acc = self._flight_acc
         if acc is not None:
-            # Flight tiling for this dispatch→complete unit: the three
-            # segments share boundary timestamps, so they sum to the
-            # unit's wall time exactly (multi-profile batches accumulate
-            # one unit per group; `other` in _record_flight absorbs the
-            # gaps between units).
-            t_flight_end = time.perf_counter()
+            # Flight tiling for this dispatch→complete unit.  The spans
+            # above fed `commit` (and the drain its own phase) as they
+            # ended; the dispatch side's spans may have run in the
+            # PREVIOUS call (a predispatched pass), so their seconds ride
+            # the ctx to the record that completes the pass: `featurize`
+            # is batch/featurize, `packing` batch/pack, and `device` runs
+            # from pass/dispatch's start to commit/stage's, less the
+            # packer (multi-profile batches accumulate one unit per group;
+            # `other` in _record_flight absorbs the gaps between spans).
             ph = acc["phases"]
             pack_s = ctx.get("pack_s", 0.0)
-            ph["featurize"] = ph.get("featurize", 0.0) + (t1 - ctx["t_f0"])
-            # The packer runs between t1 and dispatch: carve its slice out
-            # of the device segment so the tiling still sums to wall time.
+            ph["featurize"] = ph.get("featurize", 0.0) + ctx["feat_phase_s"]
             if pack_s > 0.0:
                 ph["packing"] = ph.get("packing", 0.0) + pack_s
             ph["device"] = ph.get("device", 0.0) + (t2 - t1 - pack_s)
-            # An inline drain ran inside the commit window and recorded
-            # its own `drain` segment — carve it out so the tiling still
-            # sums to wall time.
-            ph["commit"] = ph.get("commit", 0.0) + max(
-                t_flight_end - t2 - drain_inline_s, 0.0
-            )
+            self.spans.add("pass/inflight", t1, t2)
             acc["pods"] += len(infos)
             acc["scheduled"] += sum(1 for o in outcomes if o.node_name)
             acc["unschedulable"] += sum(

@@ -174,6 +174,7 @@ class SidecarServer:
 
         sched = self.scheduler
         front = self.frontend
+        span = sched.span
         # The scheduler is a sequential state machine; connections are
         # threaded but dispatch is serialized (concurrency belongs to the
         # host side).
@@ -248,19 +249,28 @@ class SidecarServer:
                         return
                     out = pb.Envelope(seq=env.seq)
                     responded = False
+                    # The wire's three boundaries: waiting for the
+                    # dispatch lock (the span ends holding it), the
+                    # dispatch, the response's write (outside the lock:
+                    # an annotation only, no histogram).
+                    with span("wire/lock_wait"):
+                        lock.acquire()
                     try:
-                        with lock:
+                        with span("wire/dispatch", kind=env.WhichOneof("msg") or ""):
                             responded = _dispatch(
                                 sched, env, out, front, self.request,
                                 health_extra,
                             )
                     except Exception as exc:  # surface, don't kill the server
                         out.response.error = f"{type(exc).__name__}: {exc}"
+                    finally:
+                        lock.release()
                     if responded:
                         subscribed = True
                         continue
                     try:
-                        write_frame(self.request, out)
+                        with span("wire/write", label=""):
+                            write_frame(self.request, out)
                     except OSError:  # peer (or close()) severed mid-dispatch
                         return
 

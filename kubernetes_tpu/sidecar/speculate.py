@@ -68,7 +68,6 @@ Consistency contract:
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from dataclasses import dataclass, field
 
@@ -161,12 +160,16 @@ class SpeculativeFrontend:
         # non-device host cost in the push-consumer path (~1.3s, fully
         # exposed on the FIRST miss, before any device pass it could
         # hide under was in flight).
-        self.raw_blobs: list[bytes] = []
-        self._blob_cursor: tuple[str, int] | None = None
+        # Each blob, the cursor into the one being parsed and every pool
+        # entry carry when the server first held them (the frame's
+        # arrival, on the queue's clock): handed to the queue at admission,
+        # where a flight record's queue_wait counts from.
+        self.raw_blobs: list[tuple[float, bytes]] = []
+        self._blob_cursor: tuple[str, int, float] | None = None
         # Hint uids whose pool entry is still a raw dict, in arrival
         # order — the build queue _on_dispatched drains.
         self._unbuilt: deque[str] = deque()
-        self.hints: dict[str, t.Pod] = {}
+        self.hints: dict[str, tuple[t.Pod | dict, float]] = {}
         self.cached: dict[str, ScheduleOutcome] = {}
         self.deps: dict[str, DepSet] = {}
         # uid → node of decisions handed to the host over the WIRE, awaiting
@@ -333,7 +336,7 @@ class SpeculativeFrontend:
     def add_hint_blob(self, raw: bytes) -> None:
         """A coalesced PendingPods frame, deferred whole: parsed by
         _parse_blobs under a device pass (or on first demand)."""
-        self.raw_blobs.append(raw)
+        self.raw_blobs.append((self.sched.queue.now(), raw))
 
     def _parse_blobs(self, need: int | None = None) -> None:
         """Parse deferred blobs into the hint pool — up to ``need`` NEW
@@ -356,15 +359,19 @@ class SpeculativeFrontend:
             return
         if not self.raw_blobs and self._blob_cursor is None:
             return
+        with self.sched.span("hints/decode", label="hint_decode", kind="parse"):
+            self._parse_blobs_timed(need)
+
+    def _parse_blobs_timed(self, need: int | None) -> None:
         import json
 
-        t0 = time.perf_counter()
         decoder = json.JSONDecoder()
         added = 0
         try:
             while self.raw_blobs or self._blob_cursor is not None:
                 if self._blob_cursor is None:
-                    text = self.raw_blobs.pop(0).decode("utf-8")
+                    held, raw = self.raw_blobs.pop(0)
+                    text = raw.decode("utf-8")
                     pos = 0
                     while pos < len(text) and text[pos] in " \t\n\r":
                         pos += 1
@@ -374,8 +381,8 @@ class SpeculativeFrontend:
                         raise ValueError(
                             "PendingPods frame is not a JSON array"
                         )
-                    self._blob_cursor = (text, pos + 1)
-                text, pos = self._blob_cursor
+                    self._blob_cursor = (text, pos + 1, held)
+                text, pos, held = self._blob_cursor
                 while True:
                     while pos < len(text) and text[pos] in " \t\n\r,":
                         pos += 1
@@ -384,11 +391,11 @@ class SpeculativeFrontend:
                         break
                     data, pos = decoder.raw_decode(text, pos)
                     uid = self._uid_of(data)
-                    if uid not in self.hints and self._add_hint(uid, data):
+                    if uid not in self.hints and self._add_hint(uid, data, held):
                         self._unbuilt.append(uid)
                         added += 1
                         if need is not None and added >= need:
-                            self._blob_cursor = (text, pos)
+                            self._blob_cursor = (text, pos, held)
                             return
         except ValueError:
             # A malformed blob cannot be resumed (framing inside the
@@ -396,31 +403,22 @@ class SpeculativeFrontend:
             # where the old whole-array parse would have.
             self._blob_cursor = None
             raise
-        finally:
-            self._observe_decode(time.perf_counter() - t0)
-
-    def _observe_decode(self, secs: float) -> None:
-        """Attribute hint deserialization to the phase split
-        (scheduler_phase_duration_seconds{phase="hint_decode"}) — the
-        evidence surface for the push-consumer host-cost work."""
-        hist = getattr(self.sched, "_phase_hist", None)
-        if hist is not None:
-            hist.observe(secs, phase="hint_decode")
 
     def _build_hints(self, budget: int) -> None:
         """Convert up to ``budget`` raw-dict pool entries into built
         t.Pod objects (the expensive half of deserialization), oldest
         first."""
         unbuilt = self._unbuilt
+        if budget <= 0 or not unbuilt:
+            return
         hints = self.hints
-        t0 = time.perf_counter()
-        while budget > 0 and unbuilt:
-            uid = unbuilt.popleft()
-            obj = hints.get(uid)
-            if isinstance(obj, dict):
-                hints[uid] = self._hint_pod(obj)
-                budget -= 1
-        self._observe_decode(time.perf_counter() - t0)
+        with self.sched.span("hints/decode", label="hint_decode", kind="build"):
+            while budget > 0 and unbuilt:
+                uid = unbuilt.popleft()
+                obj, held = hints.get(uid, (None, 0.0))
+                if isinstance(obj, dict):
+                    hints[uid] = (self._hint_pod(obj), held)
+                    budget -= 1
 
     def _on_dispatched(self) -> None:
         """scheduler.post_dispatch_hook: a device pass is in flight; do
@@ -430,7 +428,7 @@ class SpeculativeFrontend:
         self._build_hints(self.sched.batch_size * 2)
         self._admit_hints(self.sched.batch_size)
 
-    def _add_hint(self, uid: str, obj) -> bool:
+    def _add_hint(self, uid: str, obj, held: float = 0.0) -> bool:
         if uid in self.cached or uid in self.delivered:
             return False
         if uid in self.sched.cache.pods:
@@ -444,7 +442,7 @@ class SpeculativeFrontend:
             # queue.done() would strand a stale active entry.  Its
             # outcome is already on the way; drop the duplicate hint.
             return False
-        self.hints[uid] = obj
+        self.hints[uid] = (obj, held or self.sched.queue.now())
         return True
 
     @staticmethod
@@ -779,7 +777,7 @@ class SpeculativeFrontend:
                 # unschedulable pool; re-adding via the hint path pops it
                 # back to active for the recompute.
                 pass
-            self.hints[uid] = out.pod
+            self.hints[uid] = (out.pod, 0.0)  # waits anew, from its re-admission
 
     # -- the request path ---------------------------------------------------
 
@@ -800,6 +798,10 @@ class SpeculativeFrontend:
     def _admit_hints(self, budget: int) -> None:
         if budget <= 0:
             return
+        with self.sched.span("hints/admit"):
+            self._admit_hints_timed(budget)
+
+    def _admit_hints_timed(self, budget: int) -> None:
         if len(self.hints) < budget:
             # Top up from the deferred blobs — only as many pods as this
             # admission can use (the incremental-parse contract).
@@ -813,9 +815,9 @@ class SpeculativeFrontend:
         # Admit in QueueSort order (priority desc, arrival order) — the
         # host activeQ's comparator, so speculation follows its pop order.
         order = sorted(
-            self.hints.items(), key=lambda kv: -self._hint_priority(kv[1])
+            self.hints.items(), key=lambda kv: -self._hint_priority(kv[1][0])
         )[:budget]
-        for uid, obj in order:
+        for uid, (obj, held) in order:
             self.hints.pop(uid, None)
             if (
                 uid in self.sched.cache.pods
@@ -828,31 +830,37 @@ class SpeculativeFrontend:
                 # in via a plain informer add too).  Re-admitting would
                 # double-commit it.
                 continue
-            self.sched.add_pod(self._hint_pod(obj))
+            self.sched.add_pod(self._hint_pod(obj), held_at=held)
 
     def _run_batch(self, requested: t.Pod) -> None:
-        self.hints.pop(requested.uid, None)
+        _, held = self.hints.pop(requested.uid, (None, 0.0))
         if requested.uid not in self._prefetched_uids():
-            self.sched.add_pod(requested)
+            self.sched.add_pod(requested, held_at=held)
         self._admit_hints(self.lookahead)
         # The requested pod may sort below admitted hints or behind
         # event-woken stragglers; keep draining batches until its outcome
         # lands (it is in the active queue, so successive pops reach it).
+        span = self.sched.span
         for _ in range(64):
             outs = self.sched.schedule_batch()
-            fresh = []
-            for o in outs:
-                self.cached[o.pod.uid] = o
-                self.deps[o.pod.uid] = _deps_of(o.pod, o)
-                if o.pod.uid != requested.uid:
-                    self.stats.speculated += 1
-                    fresh.append(o)  # the requested pod rides the response
-                if o.nominated_node and not o.node_name:
-                    # Park the nominee until its verdict is delivered (see
-                    # module docstring) — the queue re-add in
-                    # _record_preemption would re-batch it uselessly.
-                    self.sched.queue.delete(o.pod.uid)
-            self._push_decisions(fresh)
+            # What follows a batch before the next can start: most of
+            # what a trace shows between batches.
+            with span("spec/publish", pods=len(outs)):
+                fresh = []
+                with span("spec/cache_outcomes"):
+                    for o in outs:
+                        self.cached[o.pod.uid] = o
+                        self.deps[o.pod.uid] = _deps_of(o.pod, o)
+                        if o.pod.uid != requested.uid:
+                            self.stats.speculated += 1
+                            fresh.append(o)  # the requested pod rides the response
+                        if o.nominated_node and not o.node_name:
+                            # Park the nominee until its verdict is delivered
+                            # (see module docstring) — the queue re-add in
+                            # _record_preemption would re-batch it uselessly.
+                            self.sched.queue.delete(o.pod.uid)
+                with span("spec/push_decisions", pods=len(fresh)):
+                    self._push_decisions(fresh)
             if requested.uid in self.cached:
                 return
             if (

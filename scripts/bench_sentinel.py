@@ -2,15 +2,12 @@
 """Declarative bench/SLO regression sentinel (ISSUE 16 tentpole c).
 
 ONE declarative guard table evaluated over a bench.py payload and the
-committed SOAK_*/OBS_TAX artifacts:
+committed SOAK_* artifacts:
 
   journal_fsyncs     group commit must stay group commit (a per-append
                      fsync regression is ~3 orders of magnitude)
   overlap_coverage   the pipeline's overlap must stay engaged
   slo_p99            decision latency vs the recorded budget
-  obs_tax            the observability A/B gate (<= 2%)
-  explain_tax        the armed explain readout's share of the ON leg
-                     (decision provenance, same 2% gate)
   fair_steady_p99    fairness isolation: the steady tenant's p99 under a
                      capped burst vs its recorded solo-baseline tolerance
   fair_starvation    starvation-SLO violations in the fairness soak (= 0)
@@ -20,7 +17,9 @@ committed SOAK_*/OBS_TAX artifacts:
 
 There is no throughput-ratio row: the table guards structure and
 recorded gates, and speed is judged from chip runs of the benchmark
-(ROADMAP S0), not against a committed CPU-box number.
+(ROADMAP S0), not against a committed CPU-box number.  For the same
+reason there is no observability-tax row: what the instrumentation costs
+is read on the chip, the same seed traced and untraced (PERF.md).
 
 Each guard has a WARN boundary (reported) and a HARD floor (exit 1: a
 real regression).  ``bench.py`` embeds the same evaluation as a
@@ -83,25 +82,6 @@ GUARDS = (
         "warn": 1.0,   # x budget
         "hard": 4.0,   # x budget
         "why": "decision latency p99 vs the recorded SLO budget",
-    },
-    {
-        "name": "obs_tax",
-        "source": {"family": "OBS_TAX_r*.json", "path": ("tax",)},
-        "op": "max",
-        "warn": 0.015,
-        "hard": 0.02,
-        "why": "the observability A/B gate: attribution + exporter "
-        "surfaces must cost <= 2% throughput",
-    },
-    {
-        "name": "explain_tax",
-        "source": {"family": "OBS_TAX_r*.json", "path": ("explain_tax",)},
-        "op": "max",
-        "warn": 0.015,
-        "hard": 0.02,
-        "why": "decision provenance: a warm armed explain_pod readout "
-        "(the recurring cost; the one-time pass compile rides the "
-        "headline tax) must stay under the observability gate",
     },
     {
         "name": "fair_steady_p99",
@@ -275,8 +255,8 @@ def _eval_guard(guard: dict, payload: dict | None, root: str) -> dict:
         "status": "pass",
     }
     # The value under test: from the payload, or from a committed
-    # artifact family (obs_tax, the fairness soak — the payload never
-    # carries them).
+    # artifact family (the fairness and production soaks — the payload
+    # never carries them).
     denom = None
     if "live" in guard:
         stats = _lint_stats(root)

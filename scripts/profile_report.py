@@ -140,6 +140,27 @@ def report(doc: dict) -> str:
                 else "pipeline overlap: no stage records"
             )
 
+        # The span tree (framework/tracing.SpanSink): totals by name over
+        # every batch that carries one, then the slowest batch's own tree
+        # with each span at its measured start.
+        spanned = [b for b in batches if b.get("spans")]
+        if spanned:
+            out.append("\n" + span_summary(spanned))
+            slowest = max(spanned, key=lambda b: b.get("wall_s", 0.0))
+            out.append(
+                f"\nspan tree of the slowest batch (seq {slowest.get('seq', '?')}, "
+                f"{slowest.get('pods', 0)} pods, {_fmt_s(slowest.get('wall_s', 0.0))}):"
+            )
+            out.extend(span_tree(slowest["spans"]))
+            waits = [b["queue_wait"] for b in batches if b.get("queue_wait")]
+            n = sum(w["pods"] for w in waits)
+            if n:
+                out.append(
+                    f"queue wait (pop minus first held): mean "
+                    f"{sum(w['sum_ms'] for w in waits) / n:.3f} ms over {n} pods, "
+                    f"max {max(w['max_ms'] for w in waits):.3f} ms"
+                )
+
         # Sampled per-plugin durations.
         plugins: dict[str, float] = {}
         for b in batches:
@@ -178,6 +199,42 @@ def report(doc: dict) -> str:
         out.append("\n--- host ring ---")
         out.append(report(host))
     return "\n".join(out)
+
+
+def span_summary(batches: list[dict]) -> str:
+    """Seconds by span name over the batches' span lists, with the
+    accumulated sub-times (a span's fifth element) beside them."""
+    totals: dict[str, list] = {}
+    for b in batches:
+        for sp in b["spans"]:
+            t = totals.setdefault(sp[0], [0, 0.0, {}])
+            t[0] += 1
+            t[1] += sp[2] * 1e-6
+            for k, v in (sp[4] if len(sp) > 4 else {}).items():
+                t[2][k] = t[2].get(k, 0) + v
+    pods = sum(b.get("pods", 0) for b in batches) or 1
+    rows = [
+        (name, n, _fmt_s(secs), f"{secs / pods * 1e6:.1f}",
+         " ".join(f"{k}={v}" for k, v in sorted(extra.items())))
+        for name, (n, secs, extra) in sorted(totals.items(), key=lambda kv: -kv[1][1])
+    ]
+    return _table(rows, ("span", "count", "total", "us/pod", "accumulated"))
+
+
+def span_tree(spans: list) -> list[str]:
+    """One record's spans as an indented tree, in start order."""
+    depth: list[int] = []
+    lines = []
+    for sp in spans:
+        parent = sp[3]
+        d = depth[parent] + 1 if 0 <= parent < len(depth) else 0
+        depth.append(d)
+        extra = " ".join(f"{k}={v}" for k, v in sorted((sp[4] if len(sp) > 4 else {}).items()))
+        lines.append(
+            f"  {'  ' * d}{sp[0]}  @{sp[1] / 1e3:.3f}ms  {sp[2] / 1e3:.3f}ms"
+            + (f"  {extra}" if extra else "")
+        )
+    return lines
 
 
 def _decision_latency_split(doc: dict) -> str:

@@ -384,7 +384,9 @@ def test_run_workload_reports_phase_attribution_coverage():
     assert r["scheduled"] == 96
     pa = r["phase_attribution"]
     assert pa["phases"]["device"] > 0
-    assert pa["coverage"] >= 0.95
+    # the tiling, and not the spans inside it a second time
+    assert 0.95 <= pa["coverage"] <= 1.001
+    assert not [k for k in pa["phases"] if "/" in k]
 
 
 def test_live_registry_families_are_all_cataloged(tmp_path):

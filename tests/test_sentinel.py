@@ -1,5 +1,5 @@
 """The declarative bench/SLO regression sentinel (ISSUE 16 tentpole c):
-one guard table over a bench.py payload + the committed OBS_TAX/SOAK
+one guard table over a bench.py payload + the committed SOAK
 artifacts — pass / warn / hard-floor semantics, missing-artifact
 handling, the bench.py ``sentinel`` payload block, and the tier-1
 ``--check`` gate."""
@@ -57,7 +57,7 @@ def test_committed_trajectory_passes_every_guard():
     assert block["missing"] == []
     assert {g["name"] for g in block["guards"]} == {
         "journal_fsyncs", "overlap_coverage",
-        "slo_p99", "obs_tax", "explain_tax", "fair_steady_p99",
+        "slo_p99", "fair_steady_p99",
         "fair_starvation",
         "prod_service_p99", "prod_recovery_p99", "prod_promotion_max",
         "lint_findings", "lint_suppressions",
@@ -106,7 +106,7 @@ def test_missing_artifacts_report_as_missing_not_failure(tmp_path):
     block = sentinel.evaluate(synthetic_payload(), root=str(tmp_path))
     assert block["ok"]
     assert set(block["missing"]) >= {
-        "obs_tax", "fair_steady_p99", "fair_starvation",
+        "prod_service_p99", "fair_steady_p99", "fair_starvation",
     }
 
 
@@ -116,7 +116,8 @@ def test_missing_payload_fields_report_as_missing():
     assert statuses["overlap_coverage"] == "missing"
     assert statuses["journal_fsyncs"] == "missing"
     assert statuses["slo_p99"] == "missing"
-    assert statuses["obs_tax"] == "pass"  # artifact-sourced, payload-free
+    # artifact-sourced, payload-free
+    assert statuses["fair_starvation"] == "pass"
     assert block["ok"]  # missing is loud, not fatal
 
 
@@ -167,7 +168,7 @@ def test_check_gate_passes_on_the_committed_trajectory():
     that read none (artifacts + the live tree) are evaluated."""
     proc = run_cli("--check")
     assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "obs_tax" in proc.stdout and "lint_findings" in proc.stdout
+    assert "fair_starvation" in proc.stdout and "lint_findings" in proc.stdout
     assert "journal_fsyncs" not in proc.stdout
 
 
