@@ -81,13 +81,15 @@ class CommitTicket:
     now: float = 0.0
     drained: bool = False
     # Drain progress: staged[:journaled] have records WRITTEN to the
-    # log, barriered means the group's fsync RETURNED (written is not
-    # durable), staged[:applied] are live.  A drain interrupted by an
-    # exception (deposed-writer fence, fsync OSError) leaves drained
-    # False with these markers on the completed prefix, so the recovery
-    # drain resumes exactly what remains — never re-journaling, never
-    # silently abandoning the group, and never applying ahead of a
-    # barrier that has not actually returned.
+    # log (bytes in the file: the group is written whole or not at all,
+    # so this is 0 or len(staged)), barriered means the group's fsync
+    # RETURNED (written is not durable), staged[:applied] are live.  A
+    # drain interrupted by an exception (deposed-writer fence, write or
+    # fsync OSError) leaves drained False with these markers saying
+    # what is in the file, so the recovery drain resumes exactly what
+    # remains — never re-journaling, never silently abandoning the
+    # group, and never applying ahead of a barrier that has not
+    # actually returned.
     journaled: int = 0
     barriered: bool = False
     applied: int = 0
@@ -127,15 +129,18 @@ def drain_commit(sched, ticket: CommitTicket) -> None:
     """Journal + apply one staged commit group.  The caller's
     `pipeline/drain` span is the flight recorder's ``drain`` stage
     segment; inside it three spans say where the drain goes:
-    `drain/journal_append` (serialise + write every record, with the
-    serialisation's share as ``serialize_us``), `drain/journal_fsync` (the
-    group's one barrier) and `drain/apply`.
+    `drain/journal_append` (serialise and encode every record into the
+    group's buffer, with the serialisation's share as ``serialize_us``),
+    `drain/journal_fsync` (the group's one barrier) and `drain/apply`;
+    the group's one write + flush runs between the first two and is the
+    flight record's ``journal.append_s``.
 
     Ordering contract (the WAL family's apply sites live here):
 
     1. every staged bind's record is appended inside ONE
-       ``journal.group()`` — written and flushed, fsync deferred;
-    2. the group barrier returns — all records durable in one fsync;
+       ``journal.group()`` — encoded and buffered, no syscall a record;
+    2. the group exits — one fence check, one write, one flush, one
+       fsync: all records in the file and durable together;
     3. only then does any bind apply (spec mutation, finish_binding,
        queue bookkeeping, events/metrics), in stage order.
 
@@ -145,12 +150,17 @@ def drain_commit(sched, ticket: CommitTicket) -> None:
     windows (stage-boundary / mid-group-fsync / post-group-fsync /
     torn-group-tail).
 
-    An in-process EXCEPTION mid-drain (epoch fence, fsync error) leaves
-    ``drained`` False with the ticket's journaled/applied counters
-    marking the completed prefix: the group's `__exit__` has already
-    made that prefix durable, and a retry (the recovery path's
-    ``_drain_pending``) resumes from the counters — never re-journaling
-    a record, never reporting an unapplied bind as committed.
+    The group is all-or-nothing in the file, and the ticket's counters
+    say so.  After any in-process EXCEPTION out of the group (a record
+    that will not serialise, the epoch fence, a write or fsync error)
+    ``ticket.journaled`` and ``ticket.admission_journaled`` count only
+    records whose bytes are in the file, no ``seq`` appears twice in
+    the file, and no bind is applied whose record is not durable: an
+    exception before the write discards the buffer and rewinds ``seq``
+    (the counters stay where they were), and the one case that leaves
+    the whole group written — its fsync raised — advances them, so the
+    retry (the recovery path's ``_drain_pending``) re-runs the barrier
+    instead of the group.  ``drained`` stays False throughout.
     """
     if ticket.drained:
         return
@@ -165,32 +175,38 @@ def drain_commit(sched, ticket: CommitTicket) -> None:
     if journal is not None and not ticket.barriered:
         need_admission = bool(ticket.admission) and not ticket.admission_journaled
         if ticket.journaled < len(ticket.staged) or need_admission:
-            # group() defers the fsync to its exit, where the journal
-            # times it as `drain/journal_fsync`: the two spans tile the
-            # group.
-            with journal.group():
-                with sched.span("drain/journal_append") as sp:
-                    sched._serialize_s = 0.0
-                    if need_admission:
-                        # The batch's fairness debits ride the SAME
-                        # barrier as its binds, ahead of them: a crash
-                        # either loses the whole group (restored pods
-                        # re-pop through the identical ledger) or recovers
-                        # debits + binds together — admission order
-                        # replays bit-identical.
-                        sched._journal_append(
-                            "admission", debits=ticket.admission
-                        )
-                        ticket.admission_journaled = True
-                    for sb in ticket.staged[ticket.journaled :]:
-                        sched._journal_bind(sb.qp.pod, sb.node_name)
-                        ticket.journaled += 1
-                    sp.set("serialize_us", int(sched._serialize_s * 1e6))
+            # group() writes and fsyncs at its exit, where the journal
+            # times the barrier as `drain/journal_fsync`.
+            written = journal.appends
+            try:
+                with journal.group():
+                    with sched.span("drain/journal_append") as sp:
+                        sched._serialize_s = 0.0
+                        if need_admission:
+                            # The batch's fairness debits ride the SAME
+                            # barrier as its binds, ahead of them: a crash
+                            # either loses the whole group (restored pods
+                            # re-pop through the identical ledger) or
+                            # recovers debits + binds together — admission
+                            # order replays bit-identical.
+                            sched._journal_append(
+                                "admission", debits=ticket.admission
+                            )
+                        for sb in ticket.staged[ticket.journaled :]:
+                            sched._journal_bind(sb.qp.pod, sb.node_name)
+                        sp.set("serialize_us", int(sched._serialize_s * 1e6))
+            finally:
+                # `appends` moves only when the group's one write has
+                # returned: count the group then, whole, even if the
+                # fsync after it raised.
+                if journal.appends != written:
+                    ticket.admission_journaled |= need_admission
+                    ticket.journaled = len(ticket.staged)
         else:
             # Every record is already written; only the group's fsync
             # raised on the last attempt.  Re-entering group() would see
-            # zero pending appends and skip the fsync — re-run the
-            # barrier explicitly instead.
+            # nothing buffered and skip the fsync — re-run the barrier
+            # explicitly instead.
             journal.barrier()
         ticket.barriered = True
     # Group fsync returned: every record in the group is durable.

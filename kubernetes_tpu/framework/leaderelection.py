@@ -38,7 +38,9 @@ import time
 
 def read_epoch(path: str) -> int:
     """The epoch recorded in a lease file (0 when absent/unreadable) —
-    the journal's fence source: cheap enough to consult per append."""
+    the journal's fence source.  An open, a read, a close and a JSON
+    parse by path (235 µs a call on the TPU host's filesystem, PERF.md):
+    the journal consults it once a commit group, never once a record."""
     try:
         with open(path, "rb") as f:
             raw = f.read()

@@ -15,17 +15,22 @@ double-bind on restart.  This module is the etcd stand-in:
   decision it described was never applied, so dropping it is exactly
   the etcd semantics of an unacknowledged write.
 
-- Group commit (ISSUE 15): ``with journal.group():`` batches the
-  appends of one commit stage into ONE fsync at group exit — the
-  classic WAL group-commit optimization (one durability barrier per
-  batch instead of one per binding).  Journal-before-apply is
-  preserved STRICTLY: callers stage their applies and run them only
-  after ``group()`` returns, so no decision in the group is applied
-  until the group's single fsync has returned.  A crash inside the
-  group leaves a clean prefix (possibly with a torn tail the open-time
+- Group commit (ISSUE 15, ISSUE 26): ``with journal.group():`` turns
+  the appends of one commit stage into ONE fence check, ONE buffer, ONE
+  ``write``, ONE ``flush`` and ONE fsync at group exit — the classic
+  WAL group-commit optimization.  Inside the block ``append`` only
+  encodes the record and buffers its bytes; no syscall runs a record.
+  Journal-before-apply is preserved STRICTLY: callers stage their
+  applies and run them only after ``group()`` returns, so no decision
+  in the group is applied until the group's single fsync has returned.
+  The group is all-or-nothing in the file: an exception out of the
+  block, a fenced writer or a failed ``write`` leaves the log and
+  ``seq`` as they were at group entry.  A crash inside the group's one
+  write leaves a clean prefix (possibly with a torn tail the open-time
   repair truncates); none of the group's decisions were applied, so
   recovery replays exactly the acknowledged prefix — unacknowledged
-  appends were never made live.
+  appends were never made live.  An append outside any group is a
+  group of one through the same code.
 
 - Epoch fencing: every record is stamped with the holder's lease epoch
   (framework/leaderelection.py FileLease.epoch).  Appends check the
@@ -94,8 +99,9 @@ class Journal:
 
     ``epoch`` is the holder's fencing token (FileLease.epoch); ``fence``
     is an optional zero-arg callable returning the CURRENT authoritative
-    epoch (leaderelection.read_epoch over the lease file) consulted on
-    every append.  ``fsync`` False trades durability of the last few
+    epoch (leaderelection.read_epoch over the lease file) consulted once
+    a group: at entry and again before the group's write (an append
+    outside a group is a group of one).  ``fsync`` False trades durability of the last few
     records for append latency (the fsync knob README documents); the
     snapshot path always fsyncs — it is the recovery floor."""
 
@@ -123,16 +129,18 @@ class Journal:
         self.spans = NULL_SINK
         # Observability (exported as scheduler_journal_* by the
         # scheduler's collector once attached).
-        self.appends = 0
+        self.appends = 0  # records whose bytes are in the file
+        self.writes = 0  # `write` calls on the log: one a group
+        self.fence_checks = 0  # at most two a group
         self.fsyncs = 0
         self.fsync_s = 0.0  # cumulative append-path fsync seconds
-        self.fenced = 0  # appends rejected by the epoch fence
-        # Group commit (ISSUE 15): appends made inside a `with
-        # journal.group():` block defer their fsync to ONE barrier at
-        # group exit.  _group_depth nests (an inner group rides the
-        # outermost barrier); _group_pending counts records awaiting it.
+        self.fenced = 0  # groups rejected by the epoch fence
+        # Group commit (ISSUE 15, ISSUE 26): appends made inside a `with
+        # journal.group():` block are encoded into _group_buf and reach
+        # the file in ONE write at the outermost exit, under ONE fsync.
+        # _group_depth nests (an inner group rides the outermost write).
         self._group_depth = 0
-        self._group_pending = 0
+        self._group_buf: list[bytes] = []
         self.group_commits = 0  # barriers that fsync'd >= 1 record
         self.group_appends = 0  # appends whose fsync was deferred
         self.last_group_size = 0
@@ -142,6 +150,8 @@ class Journal:
         self.replayed = 0  # records applied by the last replay()
         self.replay_fenced = 0  # records dropped stale by the last replay()
         self.torn_bytes = 0  # trailing bytes dropped by open-time repair
+        # One observation a write (write + flush of a whole group), so
+        # `total` is the seconds spent putting bytes into the file.
         self.append_latency = Histogram(
             buckets=exponential_buckets(1e-6, 2, 24)
         )
@@ -198,11 +208,11 @@ class Journal:
         # Self-fencing tripwire: if the log's size is not where this
         # writer left it, another holder has written (or truncated at a
         # snapshot barrier) — adopt the file's epoch high-water mark
-        # before judging our own.
-        try:
-            size = os.path.getsize(self.wal_path)
-        except OSError:
-            size = 0
+        # before judging our own.  The log is opened once, appended to
+        # and truncated in place, never replaced, so the open file's
+        # size is the path's size without the path walk.
+        self.fence_checks += 1
+        size = os.fstat(self._f.fileno()).st_size
         if size != self._expected_size:
             for _off, rec in self._scan():
                 self._max_epoch = max(self._max_epoch, rec["e"])
@@ -218,94 +228,145 @@ class Journal:
             )
 
     def append(self, rtype: str, data: dict) -> int | None:
-        """Durably record one decision BEFORE it is applied.  Returns the
-        record's seq, or None while muted (recovery replay).  Raises
-        StaleEpochError when this writer has been deposed."""
+        """Record one decision BEFORE it is applied.  Returns the
+        record's seq, or None while muted (recovery replay).
+
+        Inside ``group()`` the record is encoded and buffered — no
+        syscall — and is in the file, durable, only once the outermost
+        group has exited; the caller must not apply the decision before
+        that.  Outside a group it is a group of one: fence check, write,
+        flush and fsync before this returns.  Raises StaleEpochError
+        when this writer has been deposed."""
         if self.muted:
             return None
-        self._check_fence()
-        _crash("pre-append")
-        self.seq += 1
+        seq = self.seq + 1
         payload = json.dumps(
-            {"e": self.epoch, "q": self.seq, "t": rtype, "d": data},
+            {"e": self.epoch, "q": seq, "t": rtype, "d": data},
             separators=(",", ":"),
         ).encode()
-        buf = _HDR.pack(len(payload), zlib.crc32(payload)) + payload
-        c = CRASH
-        if c is not None and c.should_fire("torn-append"):
-            # Crash mid-write: leave half the record's bytes on disk (the
-            # torn-tail shape open-time repair must absorb), make them
-            # durable so recovery actually sees them, then die.
-            self._f.write(buf[: _HDR.size + max(1, len(payload) // 2)])
-            self._f.flush()
-            os.fsync(self._f.fileno())
-            c.fire()
-        if (
-            self._group_depth
-            and c is not None
-            and c.should_fire("torn-group-tail")
-        ):
-            # Crash mid-write INSIDE a group: earlier group records are
-            # complete (written, unfsynced), this one is torn — the
-            # torn-group-tail shape.  None of them were applied (applies
-            # wait for the group fsync), so recovery's prefix replay +
-            # idempotent re-run must converge on identical bindings.
-            self._f.write(buf[: _HDR.size + max(1, len(payload) // 2)])
-            self._f.flush()
-            os.fsync(self._f.fileno())
-            c.fire()
-        t0 = time.perf_counter()
-        self._f.write(buf)
-        self._f.flush()
-        if self._group_depth:
-            # Group commit: durability deferred to the group's single
-            # fsync barrier (group_commit) — the caller must not apply
-            # this decision until that barrier returns.
-            self._group_pending += 1
-            self.group_appends += 1
-        elif self.fsync_enabled:
-            tf = time.perf_counter()
-            os.fsync(self._f.fileno())
-            self.fsync_s += time.perf_counter() - tf
-            self.fsyncs += 1
-        self.append_latency.observe(time.perf_counter() - t0)
-        self.appends += 1
-        self._max_epoch = max(self._max_epoch, self.epoch)
-        self._expected_size = self._f.tell()
-        _crash("post-append")
-        return self.seq
+        self._group_buf.append(
+            _HDR.pack(len(payload), zlib.crc32(payload)) + payload
+        )
+        self.seq = seq
+        if not self._group_depth:
+            self._write_group(grouped=False)
+            if self.fsync_enabled:
+                tf = time.perf_counter()
+                os.fsync(self._f.fileno())
+                self.fsync_s += time.perf_counter() - tf
+                self.fsyncs += 1
+        return seq
 
-    # -- group commit (ISSUE 15) -------------------------------------------
+    def _write_group(self, grouped: bool) -> int:
+        """Put the buffered records into the file: one fence check, one
+        ``write``, one ``flush``.  All or nothing — a fenced writer or a
+        failed write leaves the file and ``seq`` as they were before the
+        first of these records was appended.  Returns the record count
+        (not yet durable: the caller runs the fsync)."""
+        buf, self._group_buf = self._group_buf, []
+        try:
+            self._check_fence()
+            c = CRASH
+            if c is not None:
+                self._crash_points(c, buf, grouped)
+            blob = b"".join(buf)
+            t0 = time.perf_counter()
+            try:
+                self._f.write(blob)
+                self._f.flush()
+            except BaseException:
+                self._discard_partial_write()
+                raise
+            self.append_latency.observe(time.perf_counter() - t0)
+        except BaseException:
+            self.seq -= len(buf)
+            raise
+        self.writes += 1
+        self.appends += len(buf)
+        self._expected_size += len(blob)
+        self._max_epoch = max(self._max_epoch, self.epoch)
+        return len(buf)
+
+    def _crash_points(self, c, buf: list[bytes], grouped: bool) -> None:
+        """The per-record crash windows of the group's one write.  Each
+        point is consulted once a record in record order (the kill
+        matrix arms "the Nth append"), and a firing point dies with the
+        file as a record-at-a-time writer would have left it: every
+        earlier record whole, the armed one absent (pre-append), half
+        there (torn-append; torn-group-tail inside a group) or whole
+        (post-append).  None of the group was applied — applies wait
+        for the group fsync — so recovery's prefix replay + idempotent
+        re-run must converge on identical bindings."""
+        for i, rec in enumerate(buf):
+            if c.should_fire("pre-append"):
+                self._die_with(c, buf[:i])
+            if c.should_fire("torn-append") or (
+                grouped and c.should_fire("torn-group-tail")
+            ):
+                half = _HDR.size + max(1, (len(rec) - _HDR.size) // 2)
+                self._die_with(c, buf[:i] + [rec[:half]])
+            if c.should_fire("post-append"):
+                self._die_with(c, buf[: i + 1])
+
+    def _die_with(self, c, chunks: list[bytes]) -> None:
+        # Make the bytes durable so recovery actually sees them, then die.
+        self._f.write(b"".join(chunks))
+        self._f.flush()
+        os.fsync(self._f.fileno())
+        c.fire()
+
+    def _discard_partial_write(self) -> None:
+        """A ``write``/``flush`` of the group raised: cut the file back
+        to where the group began.  The file object may still hold bytes
+        it could not write, so it is closed (that flush may fail again)
+        and the log reopened — same inode, the path is never replaced."""
+        try:
+            self._f.close()
+        except OSError:
+            pass
+        with open(self.wal_path, "r+b") as f:
+            f.truncate(self._expected_size)
+            os.fsync(f.fileno())
+        self._f = open(self.wal_path, "ab")
+
+    # -- group commit (ISSUE 15, ISSUE 26) ---------------------------------
 
     def group(self) -> "_JournalGroup":
-        """One fsync barrier for every append made inside the block::
+        """One fence check, one write and one fsync barrier for every
+        append made inside the block::
 
             with journal.group():
                 for decision in batch:
-                    journal.append(...)   # written, fsync deferred
-            # barrier returned: the whole group is durable — apply now.
+                    journal.append(...)   # encoded and buffered
+            # returned: the whole group is in the file and durable — apply now.
 
-        Nested groups ride the outermost barrier.  With fsync disabled
-        the barrier is a no-op (same durability trade the fsync knob
+        All or nothing in the file: if the block raises, the writer is
+        fenced or the write fails, none of the group's records is
+        written and ``seq`` is back where it was at entry.  Nested
+        groups ride the outermost write.  With fsync disabled the
+        barrier is a no-op (same durability trade the fsync knob
         already documents); muted journals skip everything.
         """
         return _JournalGroup(self)
 
     def _group_begin(self) -> None:
+        if not self._group_depth and not self.muted:
+            # A deposed holder stops here, before anything is buffered.
+            self._check_fence()
         self._group_depth += 1
 
     def _group_commit(self) -> None:
-        """Leave the group; at the outermost exit, fsync ONCE for every
-        record appended inside.  Applies staged on this group must run
-        only after this returns — journal-before-apply at group scope."""
+        """Leave the group; at the outermost exit, write the buffered
+        records ONCE and fsync ONCE.  Applies staged on this group must
+        run only after this returns — journal-before-apply at group
+        scope."""
         self._group_depth -= 1
-        if self._group_depth > 0:
+        if self._group_depth > 0 or not self._group_buf:
             return
-        pending, self._group_pending = self._group_pending, 0
-        if not pending:
-            return
-        self.last_group_size = pending
-        self.max_group_size = max(self.max_group_size, pending)
+        n = self._write_group(grouped=True)
+        self.group_appends += n
+        self.last_group_size = n
+        self.max_group_size = max(self.max_group_size, n)
         # The group's records are written (flushed) but not yet durable;
         # a SIGKILL here must recover to the same bindings with NONE of
         # the group applied.
@@ -316,12 +377,21 @@ class Journal:
         # scope: recovery replays the whole group.
         _crash("post-group-fsync")
 
+    def _group_abort(self) -> None:
+        """Leave a group whose block raised; at the outermost exit, drop
+        what it buffered — nothing of it reached the file — and give its
+        seqs back."""
+        self._group_depth -= 1
+        if self._group_depth == 0 and self._group_buf:
+            self.seq -= len(self._group_buf)
+            self._group_buf = []
+
     def barrier(self) -> None:
         """Re-run a durability barrier: fsync everything written so far
         (fsync is file-wide and idempotent).  The drain-resume path uses
-        it when a group's records were ALL appended but the group's own
-        fsync raised — re-entering ``group()`` would see zero pending
-        appends and skip the fsync, silently acknowledging undurable
+        it when a group's records were ALL written but the group's own
+        fsync raised — re-entering ``group()`` would see nothing
+        buffered and skip the fsync, silently acknowledging undurable
         records."""
         self._barrier_fsync()
         self.group_commits += 1
@@ -475,6 +545,8 @@ class Journal:
             "seq": self.seq,
             "snapshot_seq": self.snapshot_seq,
             "appends": self.appends,
+            "writes": self.writes,
+            "fence_checks": self.fence_checks,
             "fsyncs": self.fsyncs,
             "fsync_s": round(self.fsync_s, 6),
             "fenced": self.fenced,
@@ -488,6 +560,7 @@ class Journal:
             "replay_fenced": self.replay_fenced,
             "torn_bytes": self.torn_bytes,
             "wal_bytes": wal_bytes,
+            # p99 over writes (one write + flush a group), not records.
             "append_p99_us": round(
                 self.append_latency.quantile(0.99) * 1e6, 3
             ),
@@ -502,10 +575,12 @@ class Journal:
 
 class _JournalGroup:
     """Context manager for one group-commit barrier (Journal.group).
-    Exceptions still commit the records already appended — a half-staged
-    batch's durable prefix is acknowledged state the recovery replay
-    must see (dropping it would forget fsync-pending decisions whose
-    bytes may already be on disk)."""
+    All or nothing in the file: a clean exit writes the buffered
+    records once and fsyncs once; an exception out of the block writes
+    none of them and rewinds ``seq``, so whoever resumes the batch
+    journals every record exactly once.  (Records counted as written
+    while still in the buffer would be acknowledged state that no
+    recovery replay could see.)"""
 
     def __init__(self, journal: Journal):
         self._j = journal
@@ -515,7 +590,10 @@ class _JournalGroup:
         return self._j
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        self._j._group_commit()
+        if exc_type is None:
+            self._j._group_commit()
+        else:
+            self._j._group_abort()
 
 
 # -- scheduler state <-> snapshot documents --------------------------------

@@ -781,6 +781,15 @@ class TPUScheduler:
             "scheduler_journal_appends_total",
             "Decisions durably appended to the write-ahead journal.",
         )
+        writes = reg.counter(
+            "scheduler_journal_writes_total",
+            "write calls on the journal file: one per commit group, so "
+            "appends / writes is the group size.",
+        )
+        fence_checks = reg.counter(
+            "scheduler_journal_fence_checks_total",
+            "Lease-epoch fence checks: at most two per commit group.",
+        )
         fsyncs = reg.counter(
             "scheduler_journal_fsync_total", "Journal fsync calls."
         )
@@ -817,6 +826,8 @@ class TPUScheduler:
             if j is None:
                 return
             appends.set(j.appends)
+            writes.set(j.writes)
+            fence_checks.set(j.fence_checks)
             fsyncs.set(j.fsyncs)
             fenced.set(j.fenced)
             group_commits.set(j.group_commits)
@@ -1165,6 +1176,9 @@ class TPUScheduler:
             fsync_s = j.fsync_s - jbase[3]
             rec["journal"] = {
                 "appends": j.appends - jbase[0],
+                # One write a commit group: appends / writes is its size.
+                "writes": j.writes - jbase[4],
+                "fence_checks": j.fence_checks - jbase[5],
                 "fsyncs": j.fsyncs - jbase[1],
                 "append_s": round(append_s, 6),
                 "fsync_s": round(fsync_s, 6),
@@ -3152,7 +3166,8 @@ class TPUScheduler:
         the returned outcomes."""
         j = self.journal
         jbase = (
-            (j.appends, j.fsyncs, j.append_latency.total, j.fsync_s)
+            (j.appends, j.fsyncs, j.append_latency.total, j.fsync_s,
+             j.writes, j.fence_checks)
             if j is not None
             else None
         )

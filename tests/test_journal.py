@@ -706,7 +706,7 @@ def test_group_commit_no_apply_before_group_fsync(tmp_path):
 
     def rec_commit():
         was_outermost = journal._group_depth == 1
-        had_pending = journal._group_pending > 0
+        had_pending = bool(journal._group_buf)
         orig_commit()
         if was_outermost and had_pending:
             events.append(("group-fsync",))
@@ -731,6 +731,225 @@ def test_group_commit_no_apply_before_group_fsync(tmp_path):
     # And the applies ran in stage order = the batch's outcome order.
     applied = [e[1] for e in events if e[0] == "apply"]
     assert applied == [o.pod.uid for o in out if o.node_name]
+
+
+# -- the group is written once, all or nothing (ISSUE 26) -------------------
+
+
+def wal_bytes(directory):
+    with open(os.path.join(str(directory), Journal.WAL), "rb") as f:
+        return f.read()
+
+
+def test_group_one_write_one_fence_call(tmp_path):
+    """A group costs one ``write`` on the log and two fence checks (at
+    entry, before anything is buffered, and before the write), whatever
+    its size: ``appends / writes`` reads the group size."""
+    calls = []
+
+    def fence():
+        calls.append(1)
+        return 1
+
+    j = Journal(str(tmp_path), epoch=1, fence=fence)
+    with j.group():
+        for i in range(5):
+            j.append("bind", {"uid": f"p{i}", "node": "n1"})
+        assert j.writes == 0 and j.appends == 0  # buffered, no syscall
+        assert wal_bytes(tmp_path) == b""
+    assert (j.writes, j.appends, j.fence_checks, len(calls)) == (1, 5, 2, 2)
+    stats = j.stats()
+    assert stats["writes"] == 1 and stats["fence_checks"] == 2
+    assert j.append_latency.n == 1  # one observation a write
+    # A larger group costs the same.
+    with j.group():
+        for i in range(50):
+            j.append("bind", {"uid": f"q{i}", "node": "n1"})
+    assert (j.writes, j.appends, j.fence_checks) == (2, 55, 4)
+
+
+def test_single_append_is_a_group_of_one(tmp_path, monkeypatch):
+    """Outside ``group()`` an append goes through the same write path as
+    a group of one: one fence check, one write, and the fsync has
+    returned before ``append`` does."""
+    import kubernetes_tpu.journal as journal_mod
+
+    j = Journal(str(tmp_path), epoch=1, fence=lambda: 1)
+    synced = []
+    real_fsync = os.fsync
+
+    def rec_fsync(fd):
+        real_fsync(fd)
+        synced.append(os.fstat(fd).st_size)
+
+    monkeypatch.setattr(journal_mod.os, "fsync", rec_fsync)
+    assert j.append("bind", {"uid": "a", "node": "n1"}) == 1
+    assert synced == [len(wal_bytes(tmp_path))] and synced[0] > 0
+    assert (j.writes, j.appends, j.fence_checks, j.fsyncs) == (1, 1, 1, 1)
+    assert j.group_commits == 0 and j.group_appends == 0
+
+
+def test_stale_epoch_at_group_entry_leaves_log_and_seq(tmp_path):
+    """A deposed holder is stopped at the group's entry, before anything
+    is buffered: the block never runs, the file and ``seq`` stay."""
+    j1 = Journal(str(tmp_path), epoch=1)
+    j1.append("bind", {"uid": "a", "node": "n1"})
+    j2 = Journal(str(tmp_path), epoch=2)
+    j2.append("bind", {"uid": "b", "node": "n2"})
+    before, seq = wal_bytes(tmp_path), j1.seq
+    ran = []
+    with pytest.raises(StaleEpochError):
+        with j1.group():
+            ran.append(1)
+            j1.append("bind", {"uid": "c", "node": "nX"})
+    assert not ran and j1.fenced == 1
+    assert wal_bytes(tmp_path) == before and j1.seq == seq
+    assert j1.writes == 1 and j1._group_depth == 0
+
+
+def test_stale_epoch_at_group_write_drops_the_group(tmp_path):
+    """A successor that arrives while the group is being buffered is
+    seen by the second fence check, before the group's first byte is
+    written: nothing of the group reaches the file."""
+    j1 = Journal(str(tmp_path), epoch=1)
+    seq = j1.seq
+    with pytest.raises(StaleEpochError):
+        with j1.group():
+            j1.append("bind", {"uid": "a", "node": "n1"})
+            Journal(str(tmp_path), epoch=2).append(
+                "bind", {"uid": "b", "node": "n2"}
+            )
+    assert j1.seq == seq and j1.writes == 0
+    _, recs, _ = Journal(str(tmp_path), epoch=3).replay()
+    assert [r["d"]["uid"] for r in recs] == ["b"]
+
+
+def test_exception_inside_group_writes_nothing_and_rewinds_seq(tmp_path):
+    """An exception out of the block leaves no partial group in the
+    file; the seqs it took are given back, so the retry's records carry
+    them and no seq appears twice."""
+    j = Journal(str(tmp_path), epoch=1)
+    j.append("bind", {"uid": "a", "node": "n1"})
+    before = wal_bytes(tmp_path)
+    with pytest.raises(TypeError):
+        with j.group():
+            j.append("bind", {"uid": "b", "node": "n1"})
+            j.append("bind", {"uid": "c", "node": object()})  # no JSON form
+    assert wal_bytes(tmp_path) == before
+    assert (j.seq, j.appends, j.writes, j.fsyncs) == (1, 1, 1, 1)
+    with j.group():
+        j.append("bind", {"uid": "b", "node": "n1"})
+        j.append("bind", {"uid": "c", "node": "n2"})
+    _, recs, _ = j.replay()
+    assert [(r["q"], r["d"]["uid"]) for r in recs] == [
+        (1, "a"), (2, "b"), (3, "c"),
+    ]
+
+
+class HalfWriteThenRaise:
+    """The log's file object, whose first ``write`` puts half of its
+    bytes into the file and raises (a full disk, mid-group)."""
+
+    def __init__(self, f):
+        self._f = f
+        self.raised = False
+
+    def write(self, data):
+        if self.raised:
+            return self._f.write(data)
+        self.raised = True
+        self._f.write(data[: len(data) // 2])
+        self._f.flush()
+        raise OSError("no space left on device")
+
+    def __getattr__(self, name):
+        return getattr(self._f, name)
+
+
+def test_failed_group_write_leaves_no_partial_group(tmp_path):
+    """An OSError from the group's one write: the file is cut back to
+    where the group began, ``seq`` rewinds, and the retry writes every
+    record exactly once."""
+    j = Journal(str(tmp_path), epoch=1)
+    j.append("bind", {"uid": "a", "node": "n1"})
+    before = wal_bytes(tmp_path)
+    j._f = HalfWriteThenRaise(j._f)
+    with pytest.raises(OSError):
+        with j.group():
+            for u in "bcd":
+                j.append("bind", {"uid": u, "node": "n1"})
+    assert wal_bytes(tmp_path) == before
+    assert (j.seq, j.appends, j.writes) == (1, 1, 1)
+    with j.group():
+        for u in "bcd":
+            j.append("bind", {"uid": u, "node": "n1"})
+    j2 = Journal(str(tmp_path), epoch=2)
+    assert j2.torn_bytes == 0
+    _, recs, _ = j2.replay()
+    assert [(r["q"], r["d"]["uid"]) for r in recs] == [
+        (1, "a"), (2, "b"), (3, "c"), (4, "d"),
+    ]
+
+
+def test_group_bytes_identical_to_per_record_appends(tmp_path):
+    """The record format did not move: a group's bytes are what the same
+    appends made one at a time produce — same framing, same seq order."""
+    datas = [
+        {"uid": f"p{i}", "node": f"n{i % 3}", "pod": serialize.to_dict(pod(f"p{i}"))}
+        for i in range(7)
+    ]
+    one = Journal(str(tmp_path / "one"), epoch=4)
+    seqs_one = [one.append("bind", d) for d in datas]
+    grouped = Journal(str(tmp_path / "grouped"), epoch=4)
+    with grouped.group():
+        seqs_grouped = [grouped.append("bind", d) for d in datas[:3]]
+        with grouped.group():
+            seqs_grouped += [grouped.append("bind", d) for d in datas[3:]]
+    assert seqs_grouped == seqs_one == list(range(1, 8))
+    assert wal_bytes(tmp_path / "grouped") == wal_bytes(tmp_path / "one")
+    assert (one.writes, grouped.writes) == (7, 1)
+
+
+_CRASH_CHILD = """
+import sys
+from kubernetes_tpu.faults import KillSwitch
+from kubernetes_tpu.journal import Journal
+KillSwitch(sys.argv[2], int(sys.argv[3])).arm()
+j = Journal(sys.argv[1], epoch=1)
+with j.group():
+    for i in range(5):
+        j.append("bind", {"uid": "p%d" % i, "node": "n1", "pad": "x" * 64})
+"""
+
+
+@pytest.mark.faults
+@pytest.mark.parametrize(
+    "point,nth,whole,torn",
+    [
+        ("pre-append", 3, 2, False),
+        ("torn-append", 3, 2, True),
+        ("torn-group-tail", 3, 2, True),
+        ("post-append", 3, 3, False),
+        ("mid-group-fsync", 1, 5, False),
+        ("post-group-fsync", 1, 5, False),
+    ],
+)
+def test_crash_point_shapes_inside_one_group_write(tmp_path, point, nth, whole, torn):
+    """Each crash point keeps its name, its per-record hit count and its
+    on-disk shape though the group is written once: a SIGKILL at the
+    Nth record leaves the N-1 before it whole and the Nth absent, torn
+    (open-time repair truncates it) or whole."""
+    rc = subprocess.run(
+        [sys.executable, "-c", _CRASH_CHILD, str(tmp_path), point, str(nth)],
+        cwd=REPO,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    ).returncode
+    assert rc == -9, f"child survived the SIGKILL point (rc={rc})"
+    j = Journal(str(tmp_path), epoch=2)
+    assert (j.torn_bytes > 0) == torn
+    _, recs, _ = j.replay()
+    assert [r["d"]["uid"] for r in recs] == [f"p{i}" for i in range(whole)]
+    assert [r["q"] for r in recs] == list(range(1, whole + 1))
 
 
 @pytest.mark.faults

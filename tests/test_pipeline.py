@@ -23,6 +23,7 @@ from gen_golden_transcripts import (  # noqa: E402
     session_schedulers,
     wait_for_backoffs,
 )
+from test_journal import HalfWriteThenRaise, wal_bytes  # noqa: E402
 
 # The two recorded golden sessions (basic = fit-only, default = the full
 # default plugin profile) — the same factories the wire-transcript
@@ -311,3 +312,122 @@ def test_failed_group_fsync_retries_barrier_before_apply(monkeypatch):
         assert journal.group_commits >= 1
         for o in bound:
             assert o.pod.spec.node_name == o.node_name
+
+
+# -- the drain's group is all-or-nothing in the file (ISSUE 26) -------------
+
+
+def _journaled_sched(td, pods=8, **journal_kw):
+    journal = Journal(td, epoch=1, **journal_kw)
+    s = TPUScheduler(batch_size=8, chunk_size=1, pipeline_depth=1,
+                     enable_preemption=False)
+    s.attach_journal(journal, snapshot_every_batches=100)
+    for i in range(4):
+        s.add_node(
+            make_node(f"g{i}")
+            .capacity({"cpu": "8", "memory": "16Gi", "pods": 32})
+            .obj()
+        )
+    for i in range(pods):
+        s.add_pod(make_pod(f"gp{i}").req({"cpu": "500m"}).obj())
+    return s, journal
+
+
+@pytest.mark.parametrize("fault", ["to_dict", "write"])
+def test_failed_group_leaves_no_partial_group_and_resumes_once(fault, monkeypatch):
+    """An exception inside the group's block (a pod that will not
+    serialise) or an OSError from the group's one write leaves NO record
+    of the group in the file and the ticket counting none; the resumed
+    drain journals each record exactly once and applies only after the
+    group's fsync."""
+    from kubernetes_tpu.api import serialize
+    from kubernetes_tpu.engine import pipeline
+
+    with tempfile.TemporaryDirectory() as td:
+        s, journal = _journaled_sched(td)
+        events = []
+        seen = {}
+        if fault == "to_dict":
+            real_to_dict = serialize.to_dict
+            state = {"calls": 0}
+
+            def poisoned(obj):
+                state["calls"] += 1
+                if state["calls"] == 3:
+                    raise ValueError("pod will not serialise")
+                return real_to_dict(obj)
+
+            monkeypatch.setattr(serialize, "to_dict", poisoned)
+        else:
+            journal._f = HalfWriteThenRaise(journal._f)
+        real_drain = pipeline.drain_commit
+
+        def rec_drain(sched, ticket):
+            try:
+                return real_drain(sched, ticket)
+            except (ValueError, OSError):
+                # What the failed attempt left behind, before the resume.
+                seen.update(
+                    wal=wal_bytes(td), seq=journal.seq, journaled=ticket.journaled,
+                    barriered=ticket.barriered, applied=ticket.applied,
+                )
+                raise
+
+        monkeypatch.setattr(pipeline, "drain_commit", rec_drain)
+        real_barrier = journal._barrier_fsync
+        journal._barrier_fsync = lambda: (real_barrier(), events.append("fsync"))
+        real_fb = s.cache.finish_binding
+        s.cache.finish_binding = lambda uid: (events.append("apply"), real_fb(uid))
+        out = s.schedule_all_pending()
+        bound = [o for o in out if o.node_name]
+        assert len(bound) == 8
+        # The failed attempt: nothing in the file, nothing counted, nothing live.
+        assert seen == {
+            "wal": b"", "seq": 0, "journaled": 0, "barriered": False, "applied": 0,
+        }
+        # The resume: one write of the whole group, fsync, then the applies.
+        assert events == ["fsync"] + ["apply"] * 8
+        assert journal.writes == 1 and journal.appends == 8
+        _snap, records, _ = Journal(td, epoch=2).replay()
+        assert [r["q"] for r in records] == list(range(1, 9))
+        assert sorted(r["d"]["uid"] for r in records) == sorted(
+            o.pod.uid for o in bound
+        )
+
+
+def test_stale_epoch_at_drain_entry_leaves_ticket_and_log():
+    """A deposed holder's drain raises StaleEpochError at the group's
+    entry: the file, ``seq`` and ``ticket.journaled`` stay as they were
+    and no bind is applied."""
+    from kubernetes_tpu.journal import StaleEpochError
+
+    with tempfile.TemporaryDirectory() as td:
+        s, journal = _journaled_sched(td)
+        Journal(td, epoch=2).append("bind", {"uid": "x", "node": "g0"})
+        before, seq = wal_bytes(td), journal.seq
+        with pytest.raises(StaleEpochError):
+            s.schedule_all_pending()
+        ticket = s._pending_ticket
+        assert ticket is not None and len(ticket) == 8
+        assert (ticket.journaled, ticket.barriered, ticket.applied) == (0, False, 0)
+        assert wal_bytes(td) == before and journal.seq == seq
+        assert journal.writes == 0 and journal.fenced >= 1
+        assert not bindings_of(s)
+
+
+def test_drain_counts_one_write_and_two_fence_checks_a_group():
+    """The mechanism engages on the served path: one batch's drain is
+    one write on the log whatever its pods, so ``appends / writes``
+    reads the group size, and the counters are exported."""
+    with tempfile.TemporaryDirectory() as td:
+        s, journal = _journaled_sched(td, fence=lambda: 1)
+        out = s.schedule_all_pending()
+        assert sum(1 for o in out if o.node_name) == 8
+        assert (journal.appends, journal.writes, journal.fence_checks) == (8, 1, 2)
+        assert journal.fsyncs == 1 and journal.group_commits == 1
+        text = s.metrics.registry.render_text()
+        assert "scheduler_journal_writes_total 1" in text
+        assert "scheduler_journal_fence_checks_total 2" in text
+        rec = [r for r in s.flight.records() if r.get("journal")][-1]
+        assert rec["journal"]["appends"] == 8 and rec["journal"]["fsyncs"] == 1
+        assert rec["journal"]["writes"] == 1 and rec["journal"]["fence_checks"] == 2
