@@ -72,6 +72,16 @@ def pods_needed(config: dict, mix: dict, seconds: float, n_open: int) -> dict:
     return plan
 
 
+def closed_before(records, stop_marks) -> list:
+    """The flight records closed (``ts``, the serving process's clock, to
+    the millisecond) before the profiler's stop began (``stop_marks``: the
+    same clock in nanoseconds just before and after it); all of them
+    where no profiler was stopped."""
+    if not stop_marks:
+        return list(records)
+    return [x for x in records if float(x["ts"]) <= stop_marks[0] * 1e-9]
+
+
 class Run:
     """Everything one run holds; ``close`` stops what it started."""
 
@@ -121,6 +131,7 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
             root, os.path.join(out, "serve.log"), env,
         )
         # Built while the server starts: every object this run will send.
+        t_build = time.monotonic()
         nodes = objects.Nodes(config, seed)
         offsets: list[float] = []
         warm_offsets: list[float] = []
@@ -143,7 +154,7 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
                 hint_frame(a, a + plan["backlog"])
                 for a in range(first_window, total, plan["backlog"])
             ]
-
+        built_s = time.monotonic() - t_build
         r.srv.wait_listening(sock, 900.0)
         listening_s = time.monotonic() - t_start
         r.conn = wire.Conn(sock)
@@ -185,18 +196,24 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
 
         trace_ctl = None
         on_boundary = None
+        marks: dict = {}
         if traced:
             trace_ctl = server.TraceControl(trace_sock)
-            # The traced slice is the window's last ``trace.seconds``: the
-            # profiler starts at the first batch boundary from there on and
-            # stops once the window has closed, because stopping it takes
-            # many seconds that must not fall inside a window.
+            # One rule for the traced slice.  It starts just before the
+            # first wire call that follows a hint frame (nothing of that
+            # batch is on the device yet) at or after the window's last
+            # ``trace.seconds`` of the mix: steady state.  It ends after
+            # the configuration's ``trace.seconds`` where the file has
+            # them (events a second follow the pass's shape, not the
+            # traffic), else the mix's, wherever a pass stands, or when
+            # the window has closed, whichever comes first.  The serving
+            # process ends it itself: this loop never waits on the stop.
             t_from = max(seconds - float(mix["trace"]["seconds"]), 0.0)
-            marks: dict = {}
+            slice_s = float((config.get("trace") or mix["trace"])["seconds"])
 
-            def on_boundary(elapsed: float) -> None:
-                if not trace_ctl.started and elapsed >= t_from:
-                    marks["start"] = trace_ctl.start()
+            def on_boundary(elapsed: float, first: bool) -> None:
+                if first and not trace_ctl.started and elapsed >= t_from:
+                    marks["start"] = trace_ctl.start(slice_s)
 
         scrape0 = prom(r.conn.metrics_text())
         flight0 = r.conn.flight(limit=1).get("recorded", 0)
@@ -214,13 +231,17 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
         wall_close = time.time()
         gc.unfreeze()
         take(w)
-        if trace_ctl is not None and trace_ctl.started and not trace_ctl.stopped:
+        if trace_ctl is not None and trace_ctl.started:
             marks["stop"] = trace_ctl.stop()
         entries1 = cache_entries(root)
         scrape1 = prom(r.conn.metrics_text())
         fl = r.conn.flight()
         records = [x for x in fl.get("records", []) if x.get("kind") == "batch" and x.get("seq", 0) > flight0]
         markers = [x for x in fl.get("records", []) if x.get("kind") == "marker" and x.get("seq", 0) > flight0]
+        # Stopping the profiler takes seconds of the serving process, and
+        # the slice's end may fall inside the window: a traced run's
+        # readers see the batches closed before the stop began.
+        before_stop = closed_before(records, marks.get("stop"))
         r.push.drain()
         measured = set(pods.uids[w.first: w.first + w.asked])
         r.conn.close()
@@ -257,12 +278,12 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
         verdict["info"]["recover_s"] = round(read_back["s"], 3)
         verdict["info"]["journal_bindings"] = None if recovered is None else len(recovered)
         return {
-            "window": w, "records": records, "markers": markers,
+            "window": w, "records": records, "records_before_stop": before_stop, "markers": markers,
             "scrape0": scrape0, "scrape1": scrape1, "device": device,
-            "setup_s": setup_s, "listening_s": listening_s, "nodes_s": nodes_s,
+            "setup_s": setup_s, "listening_s": listening_s, "nodes_s": nodes_s, "built_s": built_s,
             "cache_entries": {"at_start": entries_start, "window_open": entries0, "window_close": entries1},
             "verdict": verdict, "trace_dir": trace_dir if traced else None,
-            "trace_marks": marks if traced else None,
+            "trace_marks": marks if traced else None,  # the serving process's clock around start and stop
             "wall_open": wall_open, "wall_close": wall_close, "serve_rc": rc,
             "out": out, "plan": plan, "config": config, "mix": mix, "cell": cell,
             "seconds": seconds, "push": {"frames": r.push.frames, "invalidations": r.push.invalidations,

@@ -14,7 +14,10 @@ The window rules live here and nowhere else:
           backlogs, the same mix of full and short batches whatever its
           length.  The window's end is the time of the last answer, and
           the rate is every pod answered with a node over that whole
-          length: stalls included, no median of pieces.
+          length: stalls included, no median of pieces.  A run prebuilds
+          its pods; a system so fast that they are all answered before
+          ``seconds`` closes its window there, early, and the window says
+          so (``short``): the result line carries it, not a traceback.
   open    pods fall due on a schedule drawn before the window and are
           asked for in arrival order at their due time or when the loop
           is free, whichever is later.  The window closes when the last
@@ -30,8 +33,8 @@ from dataclasses import dataclass, field
 
 
 class ClusterFull(RuntimeError):
-    """The run ran out of prebuilt pods: the window would outgrow the
-    cluster's stated capacity."""
+    """The run has no prebuilt pods for a single backlog or for its
+    arrivals: the plan outgrew the cluster's stated capacity."""
 
 
 @dataclass
@@ -51,6 +54,7 @@ class Window:
     due_t: list = field(default_factory=list)  # open loop: clock each was due
     lag_s: list = field(default_factory=list)  # open loop: generator lateness
     miss_at: list = field(default_factory=list)  # (pod index, t_before, t_after)
+    short: str = ""  # closed loop: why the window closed before ``seconds``
 
     @property
     def seconds(self) -> float:
@@ -62,9 +66,11 @@ def closed_loop(conn, push, pods, hint_frames, first: int, backlog: int,
                 max_backlogs: int | None = None) -> Window:
     """Backlogs of ``backlog`` pods from ``pods[first:]`` until the window
     rule closes it.  ``hint_frames[b]`` is the prebuilt PendingPods frame
-    of the b-th backlog.  ``on_boundary(elapsed)`` runs at every batch
-    boundary before the wire call (the traced run starts and stops the
-    profiler there)."""
+    of the b-th backlog.  ``on_boundary(elapsed, first)`` runs at every
+    batch boundary, just before the wire call that starts a batch;
+    ``first`` says that the call is the first after its backlog's hint
+    frame, when nothing of the backlog is on the device yet (the traced
+    run starts its slice there)."""
     w = Window(first=first)
     uids, frames = pods.uids, pods.frames
     nodes, answer_t = w.nodes, w.answer_t
@@ -78,11 +84,11 @@ def closed_loop(conn, push, pods, hint_frames, first: int, backlog: int,
         if i > first and now - t_open >= seconds:
             break
         if i + backlog > len(uids) or b >= len(hint_frames):
-            raise ClusterFull(
-                f"window still open after {i - first} pods: no pods left"
-            )
-        if on_boundary is not None:
-            on_boundary(now - t_open)
+            if i == first:
+                raise ClusterFull(f"no pods prebuilt for one backlog of {backlog}")
+            w.short = (f"every prebuilt pod ({i - first}) was answered {now - t_open:.3f} s into a "
+                       f"window of {seconds} s: it closed there, early")
+            break
         t0 = clock()
         conn.call_raw(hint_frames[b])
         w.hint_s += clock() - t0
@@ -97,7 +103,7 @@ def closed_loop(conn, push, pods, hint_frames, first: int, backlog: int,
             if node is None:
                 now = clock()
                 if on_boundary is not None:
-                    on_boundary(now - t_open)
+                    on_boundary(now - t_open, k == i)
                     now = clock()
                 node = conn.schedule_raw(frames[k])
                 last = clock()
@@ -173,7 +179,8 @@ def open_loop(conn, push, pods, first: int, offsets, make_hint_frame,
         if node is None:
             now = clock()
             if on_boundary is not None:
-                on_boundary(now - t_open)
+                # every call of this loop follows its own hint flush
+                on_boundary(now - t_open, True)
                 now = clock()
             flush(now, True)
             now = clock()

@@ -8,6 +8,9 @@ import statistics
 from . import correct, loops, peaks, spec, trace
 
 
+GEN2 = 'scheduler_gc_collections_total{generation="2"}'  # full collections of the serving process
+
+
 def load_reader(home: str, name: str):
     return spec.load_file_module(os.path.join(home, "metrics", name + ".py"),
                                  "perfbench_metric_" + name.replace(".", "_"))
@@ -16,12 +19,16 @@ def load_reader(home: str, name: str):
 class Ctx:
     """What a metric's reader may read.  ``window`` is the client's side
     (loops.Window), ``records`` the flight recorder's per-batch records of
-    the window, ``before``/``after`` the metrics frame at its two ends,
-    ``trace`` the reduced profiler trace of a traced run (else None)."""
+    the window (of a traced run: those closed before the profiler's stop
+    began; ``window_records`` has them all, for a reader that divides a
+    counter of the whole window), ``before``/``after`` the metrics frame
+    at the window's two ends, ``trace`` the reduced profiler trace of a
+    traced run (else None)."""
 
     def __init__(self, raw: dict, tr: dict | None):
         self.window = raw["window"]
-        self.records = raw["records"]
+        self.window_records = raw["records"]
+        self.records = raw.get("records_before_stop", raw["records"])
         self.before = raw["scrape0"]
         self.after = raw["scrape1"]
         self.trace = tr
@@ -38,6 +45,9 @@ class Ctx:
     def pods(self) -> int:
         return sum(int(r.get("pods", 0)) for r in self.records)
 
+    def window_pods(self) -> int:
+        return sum(int(r.get("pods", 0)) for r in self.window_records)
+
     def phase_s(self, name: str) -> float:
         return sum(float(r.get("phases", {}).get(name, 0.0)) for r in self.records)
 
@@ -45,10 +55,10 @@ class Ctx:
 def build(bench: dict, raw: dict, traced: bool, rehearsal: bool, rate) -> tuple[dict, dict]:
     w = raw["window"]
     cell = raw["cell"]
-    tr = None
+    ctx = Ctx(raw, None)
     if traced:
-        tr = trace.reduce(raw["trace_dir"], raw["trace_marks"], raw["records"], rehearsal)
-    ctx = Ctx(raw, tr)
+        ctx.trace = trace.reduce(raw["trace_dir"], ctx.records, rehearsal)
+    tr = ctx.trace
     group = "per_layer" if traced else "end_to_end"
     metrics = {}
     for m in spec.metrics_for(bench, group, cell["name"]):
@@ -66,7 +76,11 @@ def build(bench: dict, raw: dict, traced: bool, rehearsal: bool, rate) -> tuple[
     compiled0 = raw["scrape0"].get("scheduler_jax_compiled_programs")
     compiled1 = after.get("scheduler_jax_compiled_programs")
     ce = raw["cache_entries"]
-    compiled_in_window = (ce["window_close"] - ce["window_open"]) + int((compiled1 or 0) - (compiled0 or 0))
+    # every program the serving process built or loaded inside the window
+    # (scheduler_jax_compiles_total; the pass variants the scheduler holds
+    # are among them), beside the files the cache gained
+    jax_compiles = max(int(ctx.delta("scheduler_jax_compiles_total")), int((compiled1 or 0) - (compiled0 or 0)))
+    compiled_in_window = (ce["window_close"] - ce["window_open"]) + jax_compiles
     failed = sum(1 for n in w.nodes if not n)
     result = {
         "correct": bool(correct.verdict(numbers)),
@@ -81,6 +95,8 @@ def build(bench: dict, raw: dict, traced: bool, rehearsal: bool, rate) -> tuple[
         result["rehearsal"] = "CPU rehearsal at toy sizes: not a chip run, no number here is a measurement"
     if rate is not None:
         result["study_rate_pods_per_s"] = rate
+    if w.short:
+        result["window_short"] = w.short
     result["compared"] = numbers
 
     recs = raw["records"]
@@ -90,6 +106,7 @@ def build(bench: dict, raw: dict, traced: bool, rehearsal: bool, rate) -> tuple[
         "pods_asked": w.asked, "pods_bound": w.bound, "local_hits": w.hits, "wire_misses": w.misses,
         "hint_frames": w.hint_frames, "wire_s": w.wire_s,
         "setup_s": raw["setup_s"], "listening_s": raw["listening_s"], "nodes_added_s": raw["nodes_s"],
+        "objects_built_s": raw.get("built_s"), "plan": raw.get("plan"), "window_short": w.short,
         "batches": len(recs),
         "batch_pods": [int(r.get("pods", 0)) for r in recs],
         "batch_wall_ms": [round(float(r.get("wall_s", 0.0)) * 1e3, 3) for r in recs],
@@ -104,10 +121,10 @@ def build(bench: dict, raw: dict, traced: bool, rehearsal: bool, rate) -> tuple[
         "checkpoints": int(ctx.delta("scheduler_journal_snapshots_total")),
         "checkpoint_s": sum(x for x in snaps if x > 0.001),
         "deferred_pods": int(ctx.delta("scheduler_deferred_pods_total")),
-        "server_gc_gen2_collections": "not measured (the program counts none; a line for the tracing issue)",
+        "server_gc_gen2_collections": int(ctx.delta(GEN2)) if GEN2 in after else "not measured",
         "client_gc": "frozen for the window",
         "markers": [m.get("event") for m in raw["markers"]],
-        "compiled_programs": [compiled0, compiled1],
+        "compiled_programs": [compiled0, compiled1], "jax_compiles_in_window": jax_compiles,
         "cache_entries": ce,
         "compiled_in_window": compiled_in_window,
         "os_cpu_count": os.cpu_count(),
@@ -134,8 +151,23 @@ def build(bench: dict, raw: dict, traced: bool, rehearsal: bool, rate) -> tuple[
         summary["generator_waited"] = len(w.lag_s)
     elif w.due_t:
         summary["generator_waited"] = 0
+    marks = raw.get("trace_marks") or {}
     if tr is not None:
-        summary["trace"] = {k: tr[k] for k in ("window_s", "busy_s", "ops", "modules", "planes", "xplane_bytes")}
+        summary["trace"] = {k: tr[k] for k in (
+            "window_s", "busy_s", "idle_s", "idle_named_share", "ops", "device_events", "host_events",
+            "span_events", "modules", "module_events", "pass_device_s", "pods_in_slice", "passes_in_slice",
+            "planes", "xplane_bytes")}
+    if "stop" in marks:
+        # what the stop cost, by the serving process's clock, and what a
+        # traced run's readers were not shown because of it
+        summary["trace_stop"] = {
+            "stop_s": (marks["stop"][1] - marks["stop"][0]) * 1e-9,
+            "start_s": (marks["start"][1] - marks["start"][0]) * 1e-9,
+            "started_at_s": round(marks["start"][0] * 1e-9 - raw["wall_open"], 3),
+            "stop_began_at_s": round(marks["stop"][0] * 1e-9 - raw["wall_open"], 3),
+            "records_left_out": len(ctx.window_records) - len(ctx.records),
+            "pods_left_out": ctx.window_pods() - ctx.pods(),
+        }
     return result, summary
 
 
@@ -153,6 +185,9 @@ def earlier_lines(summary: dict) -> list[str]:
                        " — NOT small beside the median latency" if lag > 0.1 * p50 else ""))
     elif summary.get("generator_waited") == 0:
         out.append("perfbench: the asking loop never had to wait for a due time: the system is behind its arrivals")
+    if summary["window_short"]:
+        out.append(f"perfbench: WARNING {summary['window_short']}: this run is not a measurement at the "
+                   "length asked for (traffic/<mix>.json: prebuild_pods_per_s; the cluster's room)")
     if summary["rehearsal"]:
         out.append("perfbench: CPU REHEARSAL at toy sizes: not a chip run")
     return out

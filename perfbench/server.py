@@ -114,7 +114,6 @@ class TraceControl:
     def __init__(self, path: str):
         self.path = path
         self.started = False
-        self.stopped = False
 
     def _ask(self, word: str) -> str:
         with socket.socket(socket.AF_UNIX, socket.SOCK_STREAM) as s:
@@ -123,17 +122,20 @@ class TraceControl:
             s.sendall(word.encode() + b"\n")
             return s.makefile().readline().strip()
 
-    def start(self) -> tuple[int, int]:
-        """Unix nanoseconds just before and just after the profiler started."""
-        reply = self._ask("start").split()
+    def start(self, seconds: float) -> tuple[int, int]:
+        """Starts the slice and arms its end ``seconds`` later, inside the
+        serving process.  Unix nanoseconds just before and just after."""
+        reply = self._ask(f"start {seconds!r}").split()
         if reply[:1] != ["ok"]:
             raise RuntimeError(f"profiler did not start: {reply}")
         self.started = True
         return int(reply[1]), int(reply[2])
 
     def stop(self) -> tuple[int, int]:
+        """Ends the slice now unless its own end has come, waits for the
+        stop to be done, and returns the serving process's clock just
+        before and just after it."""
         reply = self._ask("stop").split()
         if reply[:1] != ["ok"]:
             raise RuntimeError(f"profiler did not stop: {reply}")
-        self.stopped = True
         return int(reply[1]), int(reply[2])

@@ -18,9 +18,11 @@ and prints
 (c) ``drain_overlapped_share``: of the time inside ``pipeline/drain`` (and
     its ``drain/*`` children), the share during which a device op ran.
 
-Imported by nothing the harness runs.  The slice is the one the harness
-judged (``trace.window_s`` of the run's timeline.json, from 0) where that
-file is there, else from the first event to the last.  ``--rehearsal``
+The harness reduces the same xplane with the same functions
+(``perfbench/trace.py``: ``read_events``, ``innermost``); what is only
+here is the stage of each device op, read out of the programs' HLO.  The
+slice is the one the harness judged, between the launcher's two marks in
+the trace, else from the first event to the last.  ``--rehearsal``
 reads the CPU client's executor threads in place of a device plane, as
 ``trace.read_events`` does, to exercise the code: not a measurement.
 """
@@ -40,8 +42,6 @@ if ROOT not in sys.path:
 
 from perfbench import trace  # noqa: E402
 
-PREFIX = "sched/"
-NO_SPAN = "(no span)"
 NO_SCOPE = "(no scope)"
 # a plugin's scope is CamelCase; a lower-case word after pass/eval/ is a primitive
 STAGE = re.compile(r"pass/(?:eval(?:/[A-Z][A-Za-z0-9]+)?|conflict|commit|tail)")
@@ -156,9 +156,9 @@ def module_stages(xspace) -> dict[str, dict[str, str]]:
 
 
 def read_planes(path: str, rehearsal: bool = False) -> dict:
-    """{"spans": [(name, start_s, end_s, batch)], "ops": [(name, start_s,
-    dur_s, stage)], "host_events": n, "programs": {name: instructions
-    that carry a pass/* scope}} on the trace's clock.  A device op's stage
+    """``trace.read_events`` of the xplane, with each device op's stage
+    beside it ("ops": [(name, start_s, dur_s, stage)]) and "programs":
+    {name: instructions that carry a pass/* scope}.  A device op's stage
     is its instruction's in the program (``XLA Modules`` event) that
     encloses it."""
     import bisect
@@ -168,70 +168,18 @@ def read_planes(path: str, rehearsal: bool = False) -> dict:
     with open(path, "rb") as f:
         raw = f.read()
     stages = module_stages(memoryview(raw))
-    data = ProfileData.from_serialized_xspace(raw)
-    spans, ops, host_events = [], [], 0
-    planes = list(data.planes)
-    device = sorted((p for p in planes if p.name.startswith("/device:")
-                     and "CUSTOM" not in p.name.upper()), key=lambda p: p.name)
-    for plane in planes:
-        if plane.name != "/host:CPU":
-            continue
-        for line in plane.lines:
-            cpu_ops = rehearsal and not device and line.name.startswith("tf_XLAPjRtCpuClient")
-            for e in line.events:
-                host_events += 1
-                name = e.name
-                if name.startswith(PREFIX):
-                    stats = dict(e.stats)
-                    s = e.start_ns * 1e-9
-                    spans.append((name[len(PREFIX):].split("#", 1)[0], s,
-                                  s + e.duration_ns * 1e-9, stats.get("batch")))
-                elif cpu_ops and e.duration_ns > 0 and "::" not in name:
-                    ops.append((name, e.start_ns * 1e-9, e.duration_ns * 1e-9, stage_of(name)))
-    if device:
-        lines = {line.name: line for line in device[0].lines}
-        modules = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
-                         for e in lines["XLA Modules"].events) if "XLA Modules" in lines else []
-        starts = [m[0] for m in modules]
-        for e in lines["XLA Ops"].events if "XLA Ops" in lines else ():
-            name = trace.op_name(e.name)
-            k = bisect.bisect_right(starts, e.start_ns) - 1
-            table = stages.get(modules[k][2], {}) if k >= 0 and e.start_ns < modules[k][1] else {}
-            ops.append((name, e.start_ns * 1e-9, e.duration_ns * 1e-9,
-                        table.get(name, NO_SCOPE)))
-    programs = {name: sum(1 for st in table.values() if st != NO_SCOPE)
-                for name, table in stages.items()}
-    return {"spans": spans, "ops": ops, "host_events": host_events, "programs": programs}
-
-
-def innermost(gap_list, spans) -> dict[str, float]:
-    """Seconds of the gaps by the innermost span open at the time: of the
-    spans covering an instant, the one that started last (the shortest
-    where two started together).  What no span covers goes to NO_SPAN."""
-    cuts = set()
-    for g0, g1 in gap_list:
-        cuts.update((g0, g1))
-    for _, s, e, _ in spans:
-        cuts.update((s, e))
-    cuts = sorted(cuts)
-    by_start = sorted(spans, key=lambda sp: sp[1])
-    totals: dict[str, float] = {}
-    active: list[tuple] = []
-    nxt = gi = 0
-    for lo, hi in zip(cuts, cuts[1:]):
-        while gi < len(gap_list) and gap_list[gi][1] <= lo:
-            gi += 1
-        if gi == len(gap_list):
-            break
-        if not (gap_list[gi][0] <= lo and hi <= gap_list[gi][1]):
-            continue
-        while nxt < len(by_start) and by_start[nxt][1] <= lo:
-            active.append(by_start[nxt])
-            nxt += 1
-        active = [sp for sp in active if sp[2] > lo]
-        name = max(active, key=lambda sp: (sp[1], -sp[2]))[0] if active else NO_SPAN
-        totals[name] = totals.get(name, 0.0) + (hi - lo)
-    return totals
+    ev = trace.read_events(path, rehearsal, data=ProfileData.from_serialized_xspace(raw))
+    modules = sorted((s, s + d, name) for name, s, d in ev["modules"])
+    starts = [m[0] for m in modules]
+    ops = []
+    for name, s, d in ev["ops"]:
+        k = bisect.bisect_right(starts, s) - 1
+        table = stages.get(modules[k][2], {}) if k >= 0 and s < modules[k][1] else {}
+        ops.append((name, s, d, table.get(name, stage_of(name) if not modules else NO_SCOPE)))
+    ev["ops"] = ops
+    ev["programs"] = {name: sum(1 for st in table.values() if st != NO_SCOPE)
+                      for name, table in stages.items()}
+    return ev
 
 
 def overlap_s(a, b) -> float:
@@ -253,13 +201,9 @@ def analyse(out_dir: str, rehearsal: bool = False) -> dict:
         raise SystemExit(f"spans: no xplane under {out_dir}/trace")
     ev = read_planes(path, rehearsal)
     spans, ops = ev["spans"], ev["ops"]
-    t0 = t1 = None
-    try:
-        with open(os.path.join(out_dir, "timeline.json"), encoding="utf-8") as f:
-            t0, t1 = 0.0, float(json.load(f)["trace"]["window_s"])
-    except (OSError, KeyError, ValueError, TypeError):
-        pass
-    if t1 is None:
+    if ev["slice"] is not None:
+        t0, t1 = ev["slice"]
+    else:
         starts = [s for _, s, _, _ in spans] + [s for _, s, _, _ in ops]
         ends = [e for _, _, e, _ in spans] + [s + d for _, s, d, _ in ops]
         if not starts:
@@ -267,8 +211,8 @@ def analyse(out_dir: str, rehearsal: bool = False) -> dict:
         t0, t1 = min(starts), max(ends)
     busy_s, busy = trace.busy_seconds([(n, s, d) for n, s, d, _ in ops], t0, t1)
     gap_list = trace.gaps(busy, t0, t1)
-    clipped = [(n, max(s, t0), min(e, t1), b) for n, s, e, b in spans if e > t0 and s < t1]
-    idle = innermost(gap_list, clipped)
+    clipped = trace.clip(spans, t0, t1)
+    idle = trace.innermost(gap_list, clipped)
     idle_s = sum(b - a for a, b in gap_list)
     stages: dict[str, float] = {}
     for name, s, d, stage in ops:
@@ -284,7 +228,7 @@ def analyse(out_dir: str, rehearsal: bool = False) -> dict:
         "host_events": ev["host_events"], "span_events": len(spans), "device_ops": len(ops),
         "window_s": t1 - t0, "busy_s": busy_s, "idle_s": idle_s,
         "idle_by_span": dict(sorted(idle.items(), key=lambda kv: -kv[1])),
-        "idle_named_share": 100.0 * (idle_s - idle.get(NO_SPAN, 0.0)) / idle_s if idle_s else None,
+        "idle_named_share": 100.0 * (idle_s - idle.get(trace.NO_SPAN, 0.0)) / idle_s if idle_s else None,
         "device_s_by_stage": dict(sorted(stages.items(), key=lambda kv: -kv[1])),
         "drain_s": drain_s,
         "drain_overlapped_share": 100.0 * overlap_s(drain, busy) / drain_s if drain_s else None,

@@ -72,3 +72,5 @@ def shrink(config: dict, mix: dict) -> None:
         mix["prebuild_pods_per_s"] = 4000
         mix["prebuild_seconds_margin"] = 1.0
     mix["trace"] = {"seconds": 1.0}
+    if config.get("trace"):
+        config["trace"] = {"seconds": 0.5}

@@ -24,6 +24,10 @@ def run_cell(workload: str, out: str, seconds: float = 1.5, trace: int = 0,
     lines, stderr)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)  # one CPU device, as a serve child gets
+    # a compile cache of the run's own: the checkout's is shared with every
+    # test that runs beside this one, and a file another test's server
+    # writes there during this window would read as compiled inside it
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(out, "jax_cache")
     env.update(env_extra or {})
     argv = [sys.executable, os.path.join(ROOT, "perfbench", "run.py"),
             "--workload", workload, "--seed", "2400000777", "--seconds", str(seconds),

@@ -8,30 +8,33 @@ import types
 import pytest
 
 import _pb
-from perfbench import report, spanread, spans, spec
+from perfbench import report, spanread, spans, spec, trace
 
 HOME = os.path.join(_pb.ROOT, "perfbench")
 
 # Two batches of 4,000 and 1,000 pods.  [name, start_us, dur_us, parent, {sub-times}?]
 RECORDS = [
     {"pods": 4000, "queue_wait": {"pods": 4000, "sum_ms": 8000.0, "max_ms": 90.0},
-     "spans": [["batch/pop", 0, 100, -1], ["pass/dispatch", 200, 6000, -1],
+     "spans": [["batch/pop", 0, 100, -1], ["batch/pack", 120, 70000, -1], ["pass/dispatch", 200, 6000, -1],
                ["pass/fetch_wait", 7000, 50000, -1], ["pass/fetch_copy", 57000, 200, -1],
                ["pipeline/drain", 60000, 2100000, -1],
-               ["drain/journal_append", 60010, 1600000, 4, {"serialize_us": 600000}],
-               ["drain/journal_fsync", 1660100, 2000, 4], ["drain/apply", 1662200, 400000, 4],
-               ["pipeline/snapshot", 2160100, 1000000, -1], ["snapshot/collect", 2160110, 600000, 8],
-               ["snapshot/encode", 2760200, 300000, 8], ["snapshot/write", 3060300, 100000, 8]]},
+               ["drain/journal_append", 60010, 1600000, 5, {"serialize_us": 600000}],
+               ["drain/journal_fsync", 1660100, 2000, 5], ["drain/apply", 1662200, 400000, 5],
+               ["pipeline/snapshot", 2160100, 1000000, -1], ["snapshot/collect", 2160110, 600000, 9],
+               ["snapshot/encode", 2760200, 300000, 9], ["snapshot/write", 3060300, 100000, 9]]},
     {"pods": 1000, "queue_wait": {"pods": 1000, "sum_ms": 1000.0, "max_ms": 40.0},
-     "spans": [["pass/dispatch", 100, 4000, -1], ["pass/fetch_wait", 5000, 10000, -1],
+     "spans": [["batch/pack", 50, 5000, -1], ["pass/dispatch", 100, 4000, -1], ["pass/fetch_wait", 5000, 10000, -1],
                ["pipeline/drain", 20000, 520000, -1],
-               ["drain/journal_append", 20010, 400000, 2, {"serialize_us": 150000}],
-               ["drain/journal_fsync", 420100, 2000, 2], ["drain/apply", 422200, 100000, 2]]},
+               ["drain/journal_append", 20010, 400000, 3, {"serialize_us": 150000}],
+               ["drain/journal_fsync", 420100, 2000, 3], ["drain/apply", 422200, 100000, 3]]},
 ]
 BEFORE = {'scheduler_phase_duration_seconds_sum{phase="spec/publish"}': 1.0,
           "scheduler_gc_pause_seconds_total": 0.25, "scheduler_jax_compile_seconds_total": 4.5}
 AFTER = {'scheduler_phase_duration_seconds_sum{phase="spec/publish"}': 1.2,
-         "scheduler_gc_pause_seconds_total": 0.75, "scheduler_jax_compile_seconds_total": 4.5}
+         "scheduler_gc_pause_seconds_total": 0.75, "scheduler_jax_compile_seconds_total": 4.5,
+         "scheduler_chunk_pack_width": 1.0}
+# the traced slice held 2,500 pods' worth of passes in 0.85 s of device time
+TRACE = {"busy_s": 0.85, "pods_in_slice": 2500.0, "pass_device_s": 0.85, "window_s": 1.0, "device_plane": True}
 
 EXPECTED = {
     "pass_fetch_wait_ms_per_batch": (50000 + 10000) / 2 / 1e3,
@@ -45,19 +48,22 @@ EXPECTED = {
     "snapshot_cpu_share.arrivals": 100.0 * 900000 / 1000000,
     "server_gc_pause_ms": 500.0,
     "setup_compile_s": 4.5,
+    "pack_us_per_pod": (70000 + 5000) / 5000,
+    "pack_width": 1.0,
+    "pass_device_us_per_pod": 0.85 / 2500 * 1e6,
 }
 
 
-def ctx(records, before, after):
-    c = types.SimpleNamespace(records=records, before=before, after=after)
+def ctx(records, before, after, trace=None):
+    c = types.SimpleNamespace(records=records, before=before, after=after, trace=trace)
     c.delta = lambda key: after.get(key, 0.0) - before.get(key, 0.0)
-    c.pods = lambda: sum(int(r.get("pods", 0)) for r in records)
+    c.pods = c.window_pods = lambda: sum(int(r.get("pods", 0)) for r in records)
     return c
 
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))
 def test_reader_gives_the_hand_computed_value(name):
-    value = report.load_reader(HOME, name).read(ctx(RECORDS, BEFORE, AFTER))
+    value = report.load_reader(HOME, name).read(ctx(RECORDS, BEFORE, AFTER, TRACE))
     assert value == pytest.approx(EXPECTED[name])
 
 
@@ -75,9 +81,10 @@ def test_every_new_metric_is_declared_with_its_reader_and_an_accepted_layer():
     bench = _pb.bench()
     declared = {m["name"]: m for m in bench["per_layer"]}
     layers = {m["layer"] for m in bench["per_layer"] if m["name"] not in EXPECTED}
+    backlogs = ["basic_5kn.backlog", "podaffinity_5kn.backlog"]
     for name in EXPECTED:
         m = declared[name]
-        assert m["layer"] in layers and m["source"] in ("program_span", "program_counter")
+        assert m["layer"] in layers and m["source"] in ("program_span", "program_counter", "device_trace")
         assert os.path.exists(os.path.join(HOME, "metrics", name + ".py"))
         cells = m.get("workloads")
         if name == "setup_compile_s":
@@ -85,8 +92,13 @@ def test_every_new_metric_is_declared_with_its_reader_and_an_accepted_layer():
         else:
             assert m in spec.metrics_for(bench, "per_layer", cells[0])
             want = "decision_p50_ms" if name.endswith(".arrivals") else "pods_per_s"
-            assert m["moves"] == want and cells == [
-                "basic_5kn.arrivals" if name.endswith(".arrivals") else "basic_5kn.backlog"]
+            assert m["moves"] == want
+            if name.endswith(".arrivals"):
+                assert cells == ["basic_5kn.arrivals"]
+            elif name in ("pack_us_per_pod", "pack_width"):
+                assert cells == backlogs[1:]  # only that cell's batches are packed
+            else:
+                assert cells == backlogs
     # appended: the accepted entries come first, in the order they had
     names = [m["name"] for m in bench["per_layer"]]
     assert names[-len(EXPECTED):] == [n for n in names if n in EXPECTED]
@@ -106,12 +118,12 @@ def test_idle_goes_to_the_innermost_span_and_sums_to_the_idle_time():
     gaps = [(0.0, 1.0), (2.0, 6.0)]
     sp = [("wire/dispatch", 0.5, 5.5, 1), ("pipeline/drain", 2.5, 4.5, 1),
           ("drain/apply", 3.0, 4.0, 1), ("wire/lock_wait", 3.2, 3.4, 1)]
-    idle = spans.innermost(gaps, sp)
+    idle = trace.innermost(gaps, sp)
     assert idle["drain/apply"] == pytest.approx(0.8)  # 3.0-3.2 and 3.4-4.0
     assert idle["wire/lock_wait"] == pytest.approx(0.2)  # another thread's, started last
     assert idle["pipeline/drain"] == pytest.approx(1.0)  # 2.5-3.0 and 4.0-4.5
     assert idle["wire/dispatch"] == pytest.approx(0.5 + 0.5 + 1.0)  # 0.5-1, 2-2.5, 4.5-5.5
-    assert idle[spans.NO_SPAN] == pytest.approx(0.5 + 0.5)  # 0-0.5, 5.5-6
+    assert idle[trace.NO_SPAN] == pytest.approx(0.5 + 0.5)  # 0-0.5, 5.5-6
     assert sum(idle.values()) == pytest.approx(5.0)
 
 

@@ -41,7 +41,8 @@ class FakeSidecar:
         self.calls += 1
         uid = frame
         take = [uid] + [u for u in self.hinted if u != uid][: self.batch - 1]
-        self.hinted = [u for u in self.hinted if u not in take]
+        taken = set(take)
+        self.hinted = [u for u in self.hinted if u not in taken]
         self.clock.t += self.batch_s + self.stalls.get(self.calls, 0.0)
         for u in take[1:]:
             self.pushed[u] = "n1"
@@ -199,3 +200,115 @@ def test_the_arrivals_readers_time_every_pod_from_its_due_time():
     assert read("generator_lag_p99_ms") == pytest.approx(np.percentile(w.lag_s, 99) * 1e3)
     # the rate's reader has nothing to say of a window that bound nothing
     assert read("pods_per_s") is None
+
+
+# -- the traced slice, the plan's headroom -------------------------------------
+
+
+def test_the_slice_starts_at_a_backlogs_first_call_and_nowhere_else():
+    """``on_boundary`` runs before every wire call; ``first`` is true for
+    the call that follows a backlog's hint frame, when nothing of the
+    backlog is on the device yet."""
+    clock = FakeClock()
+    side = FakeSidecar(clock)
+    pods = FakePods(400)
+    hints = [pods.uids[a: a + 20] for a in range(0, 400, 20)]
+    seen = []
+    loops.closed_loop(side, side, pods, hints, 0, 20, 7.0, clock=clock,
+                      on_boundary=lambda elapsed, first: seen.append((round(elapsed, 3), first, side.calls)))
+    # three backlogs of three calls each (8 + 8 + 4 pods): one boundary a call
+    assert len(seen) == side.calls == 9
+    assert [first for _, first, _ in seen] == [True, False, False] * 3
+    # each before its call, after the hint frame (1 ms) of its backlog
+    assert [(e, c) for e, _, c in seen[:4]] == [(0.001, 0), (1.001, 1), (2.001, 2), (3.002, 3)]
+
+
+def test_the_slice_ends_at_its_bound_and_the_loop_is_not_held_by_the_stop(tmp_path):
+    """The launcher's session ends itself ``seconds`` after its start, on a
+    thread of its own: the asking thread gets its answer at once and goes
+    on working, and whoever asks to stop later is told when the stop began,
+    not when they asked."""
+    import time
+
+    from perfbench import launcher, trace
+
+    sl = launcher.Slice(str(tmp_path))
+    t_ask = time.monotonic()
+    start = sl.start(0.3)
+    assert time.monotonic() - t_ask < 0.25  # the start does not wait for the end
+    turns = 0
+    while time.monotonic() - t_ask < 0.8:  # the loop goes on through the armed stop
+        turns += 1
+        time.sleep(0.001)
+    assert turns > 100 and sl.stop_marks is not None
+    began, done = sl.stop()  # asked half a second after the stop
+    assert (began, done) == sl.stop_marks
+    assert (began - start[1]) * 1e-9 == pytest.approx(0.3, abs=0.1)
+    ev = trace.read_events(trace.find_xplane(str(tmp_path)), rehearsal=True)
+    assert ev["slice"][1] - ev["slice"][0] == pytest.approx(0.3, abs=0.1)
+    with pytest.raises(RuntimeError):
+        sl.start(0.1)  # one slice a run
+
+
+def test_records_closed_after_the_stop_began_are_left_out_of_a_traced_runs_readers():
+    from perfbench import cell, report
+
+    records = [{"ts": 1000.0 + k, "pods": 100, "phases": {"drain": 0.5}} for k in range(6)]
+    stop = (int(1003.5e9), int(1010.0e9))  # the stop began at 1003.5 and took 6.5 s
+    kept = cell.closed_before(records, stop)
+    assert [r["ts"] for r in kept] == [1000.0, 1001.0, 1002.0, 1003.0]
+    assert cell.closed_before(records, None) == records  # no profiler, nothing left out
+    raw = {"window": loops.Window(), "records": records, "records_before_stop": kept, "scrape0": {},
+           "scrape1": {}, "config": {}, "mix": {}, "device": {"kind": "TPU v5 lite"}, "setup_s": 1.0}
+    ctx = report.Ctx(raw, None)
+    assert ctx.pods() == 400 and ctx.window_pods() == 600
+    assert ctx.phase_s("drain") == pytest.approx(2.0)
+    del raw["records_before_stop"]  # an untraced run's readers see every record
+    assert report.Ctx(raw, None).pods() == 600
+
+
+def _plan(name):
+    import os
+
+    from perfbench import cell, spec
+
+    bench = spec.load(os.path.join(_pb.ROOT, "BENCHMARK.json"))
+    _, config, mix = spec.cell(bench, name)
+    return cell.pods_needed(config, mix, float(bench["run_seconds"]), 0), bench["run_seconds"]
+
+
+@pytest.mark.parametrize("name,backlogs", [("basic_5kn.backlog", 19), ("podaffinity_5kn.backlog", 37)])
+def test_the_plan_is_capped_by_the_clusters_room(name, backlogs):
+    plan, _ = _plan(name)
+    assert plan["window"] == backlogs * plan["backlog"]
+    assert plan["initial"] + plan["warm"] + plan["window"] <= 200000
+
+
+def _served(pods_per_s, plan, seconds):
+    """The cell's plan against a sidecar that answers ``pods_per_s``."""
+    clock = FakeClock()
+    side = FakeSidecar(clock, batch=4096, batch_s=4096 / pods_per_s)
+    pods = FakePods(plan["window"])
+    hints = [pods.uids[a: a + plan["backlog"]] for a in range(0, plan["window"], plan["backlog"])]
+    return loops.closed_loop(side, side, pods, hints, 0, plan["backlog"], float(seconds), clock=clock)
+
+
+def test_a_server_twice_as_fast_as_the_ledgers_newest_reading_still_closes_its_window():
+    plan, seconds = _plan("basic_5kn.backlog")
+    w = _served(2 * 4904.7, plan, seconds)  # ledger, PR 26: 4,904.7 pods/s
+    assert not w.short and w.seconds >= seconds and w.asked % plan["backlog"] == 0
+    assert w.asked < plan["window"]
+
+
+def test_a_window_that_runs_out_of_pods_closes_early_and_says_so():
+    from perfbench import report
+
+    plan, seconds = _plan("basic_5kn.backlog")
+    w = _served(3 * 4904.7, plan, seconds)  # past the ceiling of ~10,500 pods/s
+    assert w.asked == w.bound == plan["window"] and w.seconds < seconds
+    assert "early" in w.short and str(plan["window"]) in w.short
+    said = report.earlier_lines({"compiled_in_window": 0, "rehearsal": False, "window_short": w.short})
+    assert len(said) == 1 and "WARNING" in said[0] and "not a measurement" in said[0]
+    # a plan that holds not even one backlog is an error of the plan, not a window
+    with pytest.raises(loops.ClusterFull):
+        _served(1000.0, dict(plan, window=plan["backlog"] - 1), seconds)
