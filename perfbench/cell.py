@@ -43,14 +43,17 @@ def prom(text: str) -> dict[str, float]:
 
 
 def cluster_pod_capacity(config: dict) -> int:
-    """Pods of the configuration's template the cluster can hold, by the
+    """Pods of the configuration's templates the cluster can hold, by the
     arithmetic its file states: per node the least of cpu, memory and the
-    pod limit."""
+    pod limit, each resource at the larger request of the initial and the
+    measured pods' template."""
     alloc = config["cluster"]["node_template"]["status"]["allocatable"]
+    pod = config["pod"]
     req = {"cpu": 0, "memory": 0}
-    for c in config["pod"]["template"]["spec"]["containers"]:
+    for template in (pod["template"], pod.get("initial_template", pod["template"])):
         for k in req:
-            req[k] += int(c.get("requests", {}).get(k, 0))
+            req[k] = max(req[k], sum(int(c.get("requests", {}).get(k, 0))
+                                     for c in template["spec"]["containers"]))
     per_node = min([int(alloc["pods"])] + [int(alloc[k]) // v for k, v in req.items() if v])
     return per_node * int(config["cluster"]["nodes"])
 

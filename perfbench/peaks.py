@@ -6,6 +6,8 @@ the table is an error, never a default.
 
 from __future__ import annotations
 
+from .objects import cycle_length
+
 PEAKS = {
     # Google Cloud documentation, "TPU v5e" (system architecture page):
     # 197 TFLOP/s bf16, 394 TOP/s int8, 16 GB HBM2e at 819 GB/s per chip.
@@ -21,38 +23,43 @@ def peak(device_kind: str, what: str) -> float:
         raise KeyError(f"no peak {what!r} on record for device kind {device_kind!r}") from None
 
 
-def _selected_values(config: dict) -> int:
-    """Label values that the pod template's required pod (anti-)affinity
-    terms select: one per ``matchLabels`` pair and per ``In`` value, times
-    the length of a cycle where the value is one of the configuration's
-    cyclic variables."""
-    pod = config["pod"]
-    cycles = pod.get("cycles", {})
-    aff = pod["template"]["spec"].get("affinity") or {}
-    n = 0
+def _selectors(config: dict):
+    """(topology key, label selector) of everything in the measured pods'
+    template that makes a decision read other pods by domain: each
+    required pod (anti-)affinity term and each topology spread constraint."""
+    spec = config["pod"]["template"]["spec"]
+    aff = spec.get("affinity") or {}
     for side in ("pod_affinity", "pod_anti_affinity"):
         for term in (aff.get(side) or {}).get("required", ()):
-            sel = term.get("label_selector") or {}
-            values = [v for _, v in sel.get("match_labels", ())]
-            for e in sel.get("match_expressions", ()):
-                values += e.get("values", ())
-            for v in values:
-                n += max((c["count"] for var, c in cycles.items() if "{" + var + "}" in v), default=1)
-    return n
+            yield term["topology_key"], term.get("label_selector") or {}
+    for c in spec.get("topology_spread_constraints") or ():
+        yield c["topology_key"], c.get("label_selector") or {}
+
+
+def _selected_values(config: dict, selector: dict) -> int:
+    """Label values a selector selects: one per ``matchLabels`` pair and
+    per ``In`` value, times the length of a cycle where the value is one of
+    the configuration's cyclic variables."""
+    cycles = config["pod"].get("cycles", {})
+    values = [v for _, v in selector.get("match_labels", ())]
+    for e in selector.get("match_expressions", ()):
+        values += e.get("values", ())
+    return sum(max((cycle_length(c) for var, c in cycles.items() if "{" + var + "}" in v), default=1)
+               for v in values)
 
 
 def node_row_bytes(config: dict) -> int:
     """What one node contributes to the state a decision reads: allocatable
     and requested cpu, memory and pods as 64-bit integers (the
     configuration's own units: millicores and bytes, which overflow 32
-    bits), and, where the pod template carries required pod (anti-)affinity
-    terms, one 32-bit domain id for the topology key and one 32-bit count
-    per label value they select."""
-    row = 6 * 8
-    groups = _selected_values(config)
-    if groups:
-        row += 4 + 4 * groups
-    return row
+    bits), and, where the measured pods' template carries required pod
+    (anti-)affinity terms or topology spread constraints, one 32-bit domain
+    id for each distinct topology key they name and one 32-bit count for
+    each label value they select (under a hostname key the domain is the
+    node, and the count is still one a node)."""
+    selectors = list(_selectors(config))
+    keys = {key for key, _ in selectors}
+    return 6 * 8 + 4 * len(keys) + 4 * sum(_selected_values(config, sel) for _, sel in selectors)
 
 
 def pass_bytes(config: dict, pods: int) -> int:

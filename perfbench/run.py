@@ -40,8 +40,6 @@ def main(argv=None) -> int:
                     help="study only: offer this many pods/s instead of the mix's rate")
     ap.add_argument("--out", default=None,
                     help="tests only: where the run's files go (default <checkout>/.perfbench_out)")
-    ap.add_argument("--bench", default=os.path.join(ROOT, "BENCHMARK.json"),
-                    help="tests only: another BENCHMARK.json (its paths are relative to its directory)")
     args = ap.parse_args(argv)
 
     from perfbench import report, spec
@@ -51,7 +49,7 @@ def main(argv=None) -> int:
     except ImportError as exc:
         print(f"perfbench: the program is not in this checkout: {exc}", file=sys.stderr)
         return 3
-    bench = spec.load(args.bench)
+    bench = spec.load(os.path.join(ROOT, "BENCHMARK.json"))
     cell, config, mix = spec.cell(bench, args.workload)
     asked = os.environ.get("JAX_PLATFORMS", "").strip().lower()
     if asked == "cpu" and not args.rehearsal:

@@ -19,8 +19,10 @@ def bench() -> dict:
 
 
 def run_cell(workload: str, out: str, seconds: float = 1.5, trace: int = 0,
-             rehearsal: bool = True, extra=(), env_extra=None, timeout: float = 420.0):
-    """``perfbench/run.py`` in a child, on the CPU.  (returncode, stdout
+             rehearsal: bool = True, env_extra=None, timeout: float = 420.0,
+             root: str = ROOT):
+    """``perfbench/run.py`` of the checkout at ``root`` in a child, started
+    from there as the driver starts it, on the CPU.  (returncode, stdout
     lines, stderr)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)  # one CPU device, as a serve child gets
@@ -29,10 +31,10 @@ def run_cell(workload: str, out: str, seconds: float = 1.5, trace: int = 0,
     # writes there during this window would read as compiled inside it
     env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(out, "jax_cache")
     env.update(env_extra or {})
-    argv = [sys.executable, os.path.join(ROOT, "perfbench", "run.py"),
+    argv = [sys.executable, os.path.join(root, "perfbench", "run.py"),
             "--workload", workload, "--seed", "2400000777", "--seconds", str(seconds),
-            "--trace", str(trace), "--out", out] + list(extra)
+            "--trace", str(trace), "--out", out]
     if rehearsal:
         argv.append("--rehearsal")
-    p = subprocess.run(argv, cwd=ROOT, env=env, capture_output=True, text=True, timeout=timeout)
+    p = subprocess.run(argv, cwd=root, env=env, capture_output=True, text=True, timeout=timeout)
     return p.returncode, p.stdout.splitlines(), p.stderr

@@ -10,6 +10,7 @@ import shutil
 import time
 
 import _pb
+import _upstream
 
 
 def test_a_new_configuration_mix_cell_and_metric_are_files_and_entries(tmp_path):
@@ -19,15 +20,15 @@ def test_a_new_configuration_mix_cell_and_metric_are_files_and_entries(tmp_path)
                     ignore=shutil.ignore_patterns("__pycache__"))
     before = {p: os.path.getmtime(p) for p in map(str, home.rglob("*")) if os.path.isfile(p)}
     bench = _pb.bench()
-    # a configuration of its own: other sizes, other zones
-    with open(home / "configs" / "basic_5kn.json") as f:
-        config = json.load(f)
-    config["name"] = "throwaway_2kn"
-    config["cluster"]["nodes"] = 2000
-    config["cluster"]["cycles"] = {"zone": {"prefix": "zone-", "count": 7}}
-    config["cluster"]["node_template"]["metadata"]["labels"]["topology.kubernetes.io/zone"] = "{zone}"
-    config["pod"]["template"]["spec"]["containers"][0]["requests"] = {"cpu": 500, "memory": 1 << 30}
-    (home / "configs" / "throwaway_2kn.json").write_text(json.dumps(config))
+    # a configuration of its own, in the shape of upstream's TopologySpreading
+    # row: three zones by a cycle that names them, initial pods of a template
+    # without labels, measured pods with a DoNotSchedule constraint; with its
+    # excerpt, its measured pods' template and a reference file of its own
+    config = _upstream.spreading_row(str(home / "configs"))
+    config["reference"] = "throwaway_spreading"
+    (home / "configs" / "throwaway_spreading.json").write_text(json.dumps(config))
+    shutil.copy(_upstream.SPREADING_STAND_IN, home / "references" / "throwaway_spreading.py")
+    _upstream.hold(config, str(home / "configs"))
     # a mix of its own: two rates in one window
     mix = {"name": "steps", "loop": "open", "rate_pods_per_s": 50, "hint_flush_delay_s": 0.002,
            "segments": [{"share": 0.5, "rate_pods_per_s": 30}, {"share": 0.5, "rate_pods_per_s": 70}],  # piecewise
@@ -38,25 +39,33 @@ def test_a_new_configuration_mix_cell_and_metric_are_files_and_entries(tmp_path)
     (home / "metrics" / "wire_ms_per_miss.py").write_text(
         '"""layer: wire. source: host_clock."""\n\n\ndef read(ctx):\n'
         "    w = ctx.window\n    return w.wire_s / w.misses * 1e3 if w.misses else None\n")
-    bench["configs"].append({"name": "throwaway_2kn", "source": "a test", "reduced": [],
-                             "file": "perfbench/configs/throwaway_2kn.json", "why": "a test"})
-    bench["workloads"].append({"name": "throwaway_2kn.steps", "config": "throwaway_2kn",
+    bench["configs"].append({"name": "throwaway_spreading", "source": "a test", "reduced": [],
+                             "file": "perfbench/configs/throwaway_spreading.json", "why": "a test"})
+    bench["workloads"].append({"name": "throwaway_spreading.steps", "config": "throwaway_spreading",
                                "traffic": "steps", "chips": 1, "why": "a test"})
     for m in bench["end_to_end"]:
         if m["name"].startswith("decision_"):
-            m["workloads"].append("throwaway_2kn.steps")
+            m["workloads"].append("throwaway_spreading.steps")
     bench["per_layer"].append({"name": "wire_ms_per_miss", "unit": "ms", "better": "lower",
                                "source": "host_clock", "layer": "wire and hints",
-                               "moves": "decision_p50_ms", "workloads": ["throwaway_2kn.steps"]})
+                               "moves": "decision_p50_ms", "workloads": ["throwaway_spreading.steps"]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     # the throw-away checkout runs the real program
     os.symlink(os.path.join(_pb.ROOT, "kubernetes_tpu"), root / "kubernetes_tpu")
     for trace in (0, 1):
-        rc, out, err = _pb.run_cell("throwaway_2kn.steps", str(tmp_path / "out"), seconds=1.5,
-                                    trace=trace, extra=["--bench", str(root / "BENCHMARK.json")])
+        rc, out, err = _pb.run_cell("throwaway_spreading.steps", str(tmp_path / "out"), seconds=1.5,
+                                    trace=trace, root=str(root))
         assert rc == 0, err[-3000:]
         res = json.loads(out[-1])
         assert res["attempted"] == 90 and res["failed"] == 0  # the toy rates 36/s and 84/s, 0.75 s each
+        # resources, the journal and the constraint (the stand-in counts a
+        # pod bound past max_skew as infeasible) hold on every answer
+        exact = {k: v["value"] for k, v in res["compared"].items() if k != "score_gap_mean"}
+        assert not any(exact.values()), exact
+        info = json.loads(out[-2])["timeline"]["compare_info"]
+        assert info["replayed"] == 40 + 84 + 60 + 90  # initial, warm-up (64 + 20), warm arrivals, the window
+        (skew,) = info["infeasible_examples"]  # the stand-in's one line, and no pod past the constraint
+        assert skew.startswith("largest skew among the measured pods") and int(skew.split()[-1]) <= 5, skew
         if trace:
             assert res["metrics"]["wire_ms_per_miss"]["value"] > 0
         else:

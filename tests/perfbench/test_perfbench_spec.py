@@ -3,8 +3,11 @@
 import json
 import os
 import re
+import shutil
 
 import _pb
+import _upstream
+import pytest
 from perfbench import report, spec
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -58,84 +61,134 @@ def test_configs_cells_and_their_files():
     assert sum(w["chips"] == 4 for w in B["workloads"]) <= max(1, len(cells) // 2)
 
 
-def _quantity(q) -> int:
-    """A Kubernetes quantity in the wire's canonical units: millicores for
-    cpu-like values (``4``, ``100m``), bytes for ``Mi``/``Gi``."""
-    q = str(q)
-    for suffix, mult in (("Gi", 1 << 30), ("Mi", 1 << 20)):
-        if q.endswith(suffix):
-            return int(q[:-2]) * mult
-    return int(q[:-1]) if q.endswith("m") else int(q) * 1000
+CONFIG_HOME = os.path.join(_pb.ROOT, "perfbench", "configs")
+CONFIG_FILES = sorted(f for f in os.listdir(CONFIG_HOME) if f.endswith(".json"))
 
 
-def test_each_configuration_is_its_upstream_templates_in_wire_form():
+@pytest.mark.parametrize("name", CONFIG_FILES)
+def test_each_configuration_is_its_upstream_templates_in_wire_form(name):
     """The templates a run sends say what the vendored upstream YAML says:
-    sizes, labels, the affinity term, the row's counts and namespaces."""
+    sizes, labels, the affinity term, the spread constraints, the row's
+    counts, label strategy and namespaces, a template for each createPods
+    op.  One case a configuration file, each read through its own
+    ``upstream`` block (``_upstream.hold``); none is named here."""
+    named = {c["name"]: c for c in B["configs"]}
+    assert set(named) <= {f[:-5] for f in CONFIG_FILES}
+    with open(os.path.join(CONFIG_HOME, name)) as f:
+        doc = json.load(f)
+    assert doc["name"] == name[:-5]
+    _upstream.hold(doc, CONFIG_HOME, named.get(doc["name"]))
+
+
+@pytest.fixture
+def spreading(tmp_path):
+    """(configuration, home): a row in TopologySpreading's shape, entered
+    into a copy of ``perfbench/configs`` as new files."""
+    home = str(tmp_path / "configs")
+    shutil.copytree(CONFIG_HOME, home)
+    return _upstream.spreading_row(home), home
+
+
+def test_a_row_in_topology_spreadings_shape_enters_beside_the_accepted_ones(spreading):
+    """Two pod templates, a label strategy of three values, a spread
+    constraint, an excerpt of its own."""
+    doc, home = spreading
+    _upstream.hold(doc, home)
+    # the accepted excerpt was not needed, and still serves its own
+    for name in CONFIG_FILES:
+        with open(os.path.join(home, name)) as f:
+            _upstream.hold(json.load(f), home)
+
+
+def _constraint(doc):
+    return doc["pod"]["template"]["spec"]["topology_spread_constraints"][0]
+
+
+def _node_labels(doc):
+    return doc["cluster"]["node_template"]["metadata"]["labels"]
+
+
+ALTERATIONS = {
+    "max_skew": lambda doc: _constraint(doc).update(max_skew=1),
+    "zone_order": lambda doc: doc["cluster"]["cycles"]["zone"]["values"].reverse(),
+    "initial_labels": lambda doc: doc["pod"]["initial_template"]["metadata"].update(labels={"color": "blue"}),
+    "initial_template_left_out": lambda doc: doc["pod"].pop("initial_template"),
+    "literal_zone": lambda doc: _node_labels(doc).update({_upstream.ZONE: "moon-1"}),
+    "a_field_upstream_does_not_have": lambda doc: doc["pod"]["template"]["spec"].update(node_selector={"disk": "ssd"}),
+    "a_constraint_default": lambda doc: _constraint(doc).update(node_taints_policy="Honor"),
+}
+
+
+@pytest.mark.parametrize("how", sorted(ALTERATIONS))
+def test_an_altered_wire_form_fails_the_comparison(spreading, how):
+    doc, home = spreading
+    ALTERATIONS[how](doc)
+    with pytest.raises(AssertionError):
+        _upstream.hold(doc, home)
+
+
+def test_an_upstream_field_the_comparison_does_not_know_fails_it(spreading):
     import yaml
 
-    home = os.path.join(_pb.ROOT, "perfbench", "configs")
-    with open(os.path.join(home, "upstream", "performance-config.excerpt.yaml")) as f:
-        cases = {c["name"]: c for c in yaml.safe_load(f)}
-    named = {c["name"]: c for c in B["configs"]}
-    files = sorted(f for f in os.listdir(home) if f.endswith(".json"))
-    assert len(files) == len(cases) and set(named) <= {f[:-5] for f in files}
-    for name in files:
-        with open(os.path.join(home, name)) as f:
-            doc = json.load(f)
-        # what BENCHMARK.json says of a configuration is what its file says
-        c = named.get(doc["name"], {"reduced": [], "source": doc["source"][:200]})
-        assert doc["source"].startswith(c["source"]) and doc["reduced"] == c["reduced"] == []
-        assert c["source"].startswith("https://github.com/kubernetes/kubernetes/")
-        up = doc["upstream"]
-        case = cases[up["test_case"]]
-        assert up["test_case"] + "/" + up["workload"] in c["source"]
-        (row,) = [w for w in case["workloads"] if w["name"] == up["workload"]]
-        assert row["params"] == up["params"]
-        assert (doc["cluster"]["nodes"], doc["initial_pods"], doc["measure_pods"]) == \
-            (row["params"]["initNodes"], row["params"]["initPods"], row["params"]["measurePods"])
-        assert os.path.basename(up["pod_template"]) == os.path.basename(case["defaultPodTemplatePath"])
-        ops = case["workloadTemplate"]
-        # nodes: upstream's node-default.yaml, with the labels the row's createNodes op adds
-        with open(os.path.join(home, up["node_template"])) as f:
-            node = yaml.safe_load(f)
-        wire_node = doc["cluster"]["node_template"]
-        cap = {k: (int(v) if k == "pods" else _quantity(v)) for k, v in node["status"]["capacity"].items()}
-        assert wire_node["status"]["capacity"] == cap == wire_node["status"]["allocatable"]
-        strategy = ops[0].get("labelNodePrepareStrategy")
-        labels = {strategy["labelKey"]: strategy["labelValues"][0]} if strategy else {}
-        assert len((strategy or {}).get("labelValues", [0])) == 1
-        assert wire_node["metadata"]["labels"] == labels == up.get("node_labels", {})
-        assert not doc["cluster"]["cycles"] and not doc["pod"]["cycles"]
-        # pods: the row's template; the two createPods ops' namespaces
-        with open(os.path.join(home, up["pod_template"])) as f:
-            pod = yaml.safe_load(f)
-        wire_pod = doc["pod"]["template"]
-        assert wire_pod["metadata"]["labels"] == pod["metadata"].get("labels", {})
-        (cont,), (wcont,) = pod["spec"]["containers"], wire_pod["spec"]["containers"]
-        for side in ("requests", "limits"):
-            assert wcont[side] == {k: _quantity(v) for k, v in cont["resources"][side].items()}
-        assert wcont["images"] == [cont["image"]] and wcont["name"] == cont["name"]
-        assert [p["container_port"] for p in wcont["ports"]] == [p["containerPort"] for p in cont["ports"]]
-        assert not any(p["host_port"] for p in wcont["ports"])
-        creates = [op for op in ops if op["opcode"] == "createPods"]
-        spaces = [op.get("namespace", f"namespace-{ops.index(op)}") for op in creates]
-        assert [doc["pod"]["namespaces"]["initial"], doc["pod"]["namespaces"]["measured"]] == spaces
-        assert wire_pod["metadata"]["namespace"] == "{namespace}"
-        aff = pod["spec"].get("affinity")
-        if aff is None:
-            assert wire_pod["spec"]["affinity"] is None
-            continue
-        (term,) = aff["podAffinity"]["requiredDuringSchedulingIgnoredDuringExecution"]
-        wire_aff = wire_pod["spec"]["affinity"]
-        assert wire_aff["node_affinity"] is None and wire_aff["pod_anti_affinity"] is None
-        assert wire_aff["pod_affinity"]["preferred"] == []
-        (wterm,) = wire_aff["pod_affinity"]["required"]
-        assert wterm["topology_key"] == term["topologyKey"] and wterm["namespaces"] == term["namespaces"]
-        assert wterm["namespace_selector"] is None
-        assert wterm["label_selector"] == {
-            "match_labels": [list(kv) for kv in term["labelSelector"]["matchLabels"].items()],
-            "match_expressions": []}
-        assert set(spaces) <= set(term["namespaces"])
+    doc, home = spreading
+    path = os.path.join(home, doc["upstream"]["pod_template"])
+    with open(path) as f:
+        pod = yaml.safe_load(f)
+    pod["spec"]["priorityClassName"] = "high"
+    with open(path, "w") as f:
+        yaml.safe_dump(pod, f)
+    with pytest.raises(AssertionError, match="priorityClassName"):
+        _upstream.hold(doc, home)
+
+
+@pytest.mark.parametrize("name, nodes, pods, uids", [
+    ("basic_5kn", "441716391a32b8ae", "51040e5ea2cd8aa9", "b506a807ed02b990"),
+    ("podaffinity_5kn", "c9176c2467a23449", "1eae8b7d3c6829a2", "9e0507630f73928a"),
+])
+def test_the_accepted_configurations_objects_are_the_parents(name, nodes, pods, uids):
+    """Digests taken on PR 27's tree at seed 2800000001 (the initial pods
+    and 700 more): what an accepted cell sends is byte for byte what it
+    sent before a configuration could carry a second template."""
+    import hashlib
+
+    from perfbench import objects
+
+    def digest(items):
+        h = hashlib.sha256()
+        for x in items:
+            h.update(x if isinstance(x, bytes) else x.encode())
+            h.update(b"\n")
+        return h.hexdigest()[:16]
+
+    with open(os.path.join(CONFIG_HOME, name + ".json")) as f:
+        config = json.load(f)
+    n = objects.Nodes(config, 2800000001)
+    p = objects.Pods(config, 2800000001, config["initial_pods"] + 700, config["initial_pods"])
+    assert (digest(n.jsons), digest(p.jsons), digest(p.uids)) == (nodes, pods, uids)
+
+
+def test_a_template_for_each_createpods_op_and_cycles_that_name_their_values(spreading):
+    from perfbench import cell, objects
+
+    config, _ = spreading
+    config["cluster"]["nodes"] = 7
+    nodes = objects.Nodes(config, 5)
+    zone = {json.loads(j)["metadata"]["name"]: json.loads(j)["metadata"]["labels"][_upstream.ZONE]
+            for j in nodes.jsons}
+    # dealt round-robin by the index before the seed's shuffle
+    assert zone == {f"node-{i}": f"moon-{i % 3 + 1}" for i in range(7)}
+    config["cluster"]["cycles"] = {"zone": {"prefix": "z", "count": 2}}  # the older form: a prefix and a count
+    assert {json.loads(j)["metadata"]["labels"][_upstream.ZONE] for j in objects.Nodes(config, 5).jsons} == {"z0", "z1"}
+    pods = [json.loads(j) for j in objects.Pods(config, 5, 6, initial=4).jsons]
+    assert [p["metadata"]["labels"] for p in pods] == [{}] * 4 + [{"color": "blue"}] * 2
+    assert [len(p["spec"]["topology_spread_constraints"]) for p in pods] == [0] * 4 + [1] * 2
+    assert [p["metadata"]["namespace"] for p in pods] == ["namespace-1"] * 4 + ["namespace-2"] * 2
+    # the room is reckoned with the larger request of the two templates
+    assert cell.cluster_pod_capacity(config) == 7 * 40
+    config["pod"]["initial_template"]["spec"]["containers"][0]["requests"]["cpu"] = 1000
+    assert cell.cluster_pod_capacity(config) == 7 * 4
+    config["pod"]["template"]["spec"]["containers"][0]["requests"]["memory"] = 16 << 30
+    assert cell.cluster_pod_capacity(config) == 7 * 2
 
 
 def test_metrics_names_units_and_readers():

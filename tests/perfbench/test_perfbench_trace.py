@@ -201,16 +201,34 @@ def test_pass_bytes_from_shapes():
     # the node table in once and out once whatever the steps, and 28 bytes a pod
     assert peaks.pass_bytes(config, 4096) == 2 * 5000 * row + 4096 * 28
     assert peaks.pass_bytes(config, 1) == 2 * 5000 * row + 28
-    term = {"label_selector": {"match_labels": [["color", "blue"]],
+    zone, host = "topology.kubernetes.io/zone", "kubernetes.io/hostname"
+    term = {"topology_key": zone,
+            "label_selector": {"match_labels": [["color", "blue"]],
                                "match_expressions": [{"key": "tier", "operator": "In", "values": ["{colour}", "x"]}]}}
+    spec = {"affinity": {"pod_affinity": {"required": [term]}, "pod_anti_affinity": None}}
     aff = {"cluster": {"nodes": 5000}, "serve": {"chunk_size": 64},
-           "pod": {"cycles": {"colour": {"prefix": "c", "count": 50}},
-                   "template": {"spec": {"affinity": {"pod_affinity": {"required": [term]},
-                                                      "pod_anti_affinity": None}}}}}
+           "pod": {"cycles": {"colour": {"prefix": "c", "count": 50}}, "template": {"spec": spec}}}
     # a domain id, and a count for blue, for each of the 50 colours and for x
     assert peaks.node_row_bytes(aff) == row + 4 + 4 * (1 + 50 + 1)
-    with open(os.path.join(_pb.ROOT, "perfbench", "configs", "podaffinity_5kn.json")) as f:
-        assert peaks.node_row_bytes(json.load(f)) == row + 4 + 4
+    # a cycle that names its values is as long as its list
+    aff["pod"]["cycles"] = {"colour": {"values": ["red", "green", "blue"]}}
+    assert peaks.node_row_bytes(aff) == row + 4 + 4 * (1 + 3 + 1)
+    # spread constraints: a count for each value selected; a domain id for
+    # each distinct topology key, whoever names it (the zone key is the term's too)
+    blue = {"match_labels": [["color", "blue"]], "match_expressions": []}
+    spec["topology_spread_constraints"] = [{"topology_key": zone, "label_selector": blue}]
+    assert peaks.node_row_bytes(aff) == row + 4 + 4 * (1 + 3 + 1) + 4
+    spec["topology_spread_constraints"].append({"topology_key": host, "label_selector": blue})
+    assert peaks.node_row_bytes(aff) == row + 2 * 4 + 4 * (1 + 3 + 1) + 2 * 4
+    spec["affinity"] = None
+    assert peaks.node_row_bytes(aff) == row + 2 * 4 + 2 * 4
+
+
+@pytest.mark.parametrize("name, row", [("basic_5kn", 48), ("podaffinity_5kn", 56)])
+def test_the_accepted_configurations_node_rows(name, row):
+    """What ``pass_roofline`` has divided by since PR 24 and PR 27."""
+    with open(os.path.join(_pb.ROOT, "perfbench", "configs", name + ".json")) as f:
+        assert peaks.node_row_bytes(json.load(f)) == row
 
 
 def test_an_unknown_device_kind_is_an_error():
