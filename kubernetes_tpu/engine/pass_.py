@@ -25,7 +25,11 @@ With chunk=1 the pass is the strictly sequential-equivalent scan: each step
 is one reference scheduling cycle — filter → score → selectHost → commit —
 with the assume's row-delta applied to the carried ClusterState so the next
 pod observes it (the reference gets the same effect through its cache assume
-protocol, cache.go:361).  Chunk>1 trades one documented divergence for
+protocol, cache.go:361).  A padded step there costs what a real one costs,
+so the chunk-1 program drives the same step with a loop that stops one past
+the batch's last valid row and reports the steps it ran
+(PassResult.scan_steps); a chunked pass keeps the scan over all k // chunk
+steps.  Chunk>1 trades one documented divergence for
 throughput: non-interacting chunk-mates score against the chunk-start state,
 so resource-driven score drift (e.g. LeastAllocated) within a chunk does not
 influence their relative placement.  Hard constraints are never violated —
@@ -62,6 +66,10 @@ class PassResult(NamedTuple):
     # runtime/framework.go:861 RunFilterPlugins).  Bit order =
     # filter_op_names(profile, active).
     fail_masks: jax.Array
+    # () i32 — steps the ordered (chunk-1) driver of build_pass ran: one past
+    # the batch's last valid row, counted on the device.  None from every
+    # other program, whose step count is its shape's (k // chunk).
+    scan_steps: jax.Array | None = None
 
 
 def filter_op_names(profile: Profile, active: frozenset[str] | None) -> list[str]:
@@ -360,6 +368,8 @@ def build_pass(
     frameworkImpl per profile (profile/profile.go:50) with per-cycle Skip
     sets, plus XLA compilation.  Result picks: node row ≥ 0, -1
     unschedulable, -2 deferred to a strict pass (see module docstring).
+    ``chunk == 1`` builds the ordered driver: its result carries
+    ``scan_steps``, and rows past the last valid one read as padding.
 
     ``batch["step_offset"]`` (optional, (K,) i32): per-pod tie-break step
     offsets — the scheduler ships each pod's ORIGINAL dispatch position so
@@ -644,8 +654,47 @@ def build_pass(
             )
             return carry_, out_
 
+        def _run_ordered(st0, n):
+            """The chunk-1 driver of `step`: rows 0..n-1 in order, where n is
+            one past the batch's last valid row.  A padded step of the
+            ordered program costs what a real one costs (one pod), so it
+            stops where the pods do; the carry is the scan's, and rows at
+            and past n keep what the host reads of a padded row (picks -1,
+            processed 0; the rest 0)."""
+            xs = (cbatch, steps)
+            carry0 = (st0, dom0.group_dom, dom0.et_dom, start0)
+            _, row0 = jax.eval_shape(
+                step, carry0, jax.tree_util.tree_map(lambda x: x[0], xs)
+            )
+            out0 = jax.tree_util.tree_map(
+                lambda r: jnp.zeros((k // c,) + r.shape, r.dtype), row0
+            )
+            out0 = out0._replace(picks=jnp.full_like(out0.picks, -1))
+
+            def body(i, val):
+                carry_, out_ = val
+                x = jax.tree_util.tree_map(
+                    lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), xs
+                )
+                carry_, row = step(carry_, x)
+                out_ = jax.tree_util.tree_map(
+                    lambda o, r: lax.dynamic_update_index_in_dim(o, r, i, 0),
+                    out_, row,
+                )
+                return carry_, out_
+
+            return lax.fori_loop(0, n, body, (carry0, out0))
+
         uniform = uniform_all if fuse_tail else None
-        if uniform is not None:
+        if c == 1:
+            # One step, two drivers, chosen by the chunk width the program
+            # is built for: a chunked pass is k // c fat steps whatever it
+            # holds and keeps the scan below.
+            scan_steps = jnp.max(
+                jnp.where(batch["valid"], jnp.arange(1, k + 1, dtype=jnp.int32), 0)
+            )
+            carry, out = _run_ordered(state, scan_steps)
+        elif uniform is not None:
             # Template-batch all-fail shortcut: when every pod in the
             # batch is featurization-identical (the scheduler ships the
             # flag) and the REPRESENTATIVE is feasible nowhere, every pod
@@ -742,6 +791,8 @@ def build_pass(
                 processed=out.processed,
             )
         state = carry[0]
+        if c == 1:
+            out = out._replace(scan_steps=scan_steps)
         return state, out, (carry[1], carry[2])
 
     if carry_dom:

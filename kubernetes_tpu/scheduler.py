@@ -515,6 +515,11 @@ class TPUScheduler:
             "scheduler_device_dispatch_total",
             "Device pass dispatches by kind (batch/pinned/tail/eval).",
         )
+        self._scan_steps_counter = reg.counter(
+            "scheduler_pass_scan_steps_total",
+            "Steps of the batch pass, by kind: run, and padded_skipped "
+            "(steps of the batch shape the ordered pass did not run).",
+        )
         # Flight-recorder phase attribution (the tiled per-batch segments;
         # journal_append/journal_fsync nest inside featurize+commit and
         # are exported for the durability-tax view, not the tiling sum).
@@ -993,6 +998,16 @@ class TPUScheduler:
         if acc is not None:
             acc[key] = acc.get(key, 0) + n
 
+    def _count_scan_steps(self, steps_run, shape_steps: int) -> None:
+        """One fetched pass's steps into scheduler_pass_scan_steps_total and
+        the open flight record.  ``steps_run`` is the ordered program's own
+        count (PassResult.scan_steps, fetched with the result); a chunked
+        program ships none and ran its shape's ``shape_steps``."""
+        ran = shape_steps if steps_run is None else int(steps_run)
+        self._scan_steps_counter.inc(ran, kind="run")
+        self._scan_steps_counter.inc(shape_steps - ran, kind="padded_skipped")
+        self._flight_add("scan_steps", ran)
+
     # -- software pipeline (ISSUE 15, engine/pipeline.py) ---------------------
 
     def _pipeline_active(self) -> bool:
@@ -1128,6 +1143,7 @@ class TPUScheduler:
             "scheduled": acc["scheduled"],
             "unschedulable": acc["unschedulable"],
             "deferred": acc.get("deferred", 0),
+            "scan_steps": acc.get("scan_steps", 0),
             "dispatch": acc["dispatches"],
             "wall_s": round(wall, 6),
             "phases": {k: round(v, 6) for k, v in phases.items()},
@@ -3867,20 +3883,22 @@ class TPUScheduler:
         # the same fetch.
         spec = ctx.get("spec")
         if spec is not None:
-            (picks, scores, feas, fails, processed,
+            (picks, scores, feas, fails, processed, steps_run,
              sp_picks, sp_vmask) = device_fetch(
                 (result.picks, result.scores, result.feasible_counts,
-                 result.fail_masks, result.processed,
+                 result.fail_masks, result.processed, result.scan_steps,
                  spec["out"].picks, spec["out"].vic_mask),
                 span=self.span,
             )
             ctx["spec_res"] = (sp_picks, sp_vmask)
         else:
-            picks, scores, feas, fails, processed = device_fetch(
+            picks, scores, feas, fails, processed, steps_run = device_fetch(
                 (result.picks, result.scores, result.feasible_counts,
-                 result.fail_masks, result.processed),
+                 result.fail_masks, result.processed, result.scan_steps),
                 span=self.span,
             )
+        if not ctx.get("pinned"):
+            self._count_scan_steps(steps_run, len(picks) // ctx["chunk"])
         if self._truncated:
             # Advance the rotating start by this batch's processedNodes sum
             # (modular sums compose across the scan's per-step updates).
@@ -3976,10 +3994,12 @@ class TPUScheduler:
                         new_state, sub_d, ctx["inv_d"], np.uint32(ctx["cycle0"]),
                         dom_cur[0], dom_cur[1], np.bool_(True),
                     )
-                    p2, s2, f2, fl2 = device_fetch(
-                        (res.picks, res.scores, res.feasible_counts, res.fail_masks)
+                    p2, s2, f2, fl2, steps2 = device_fetch(
+                        (res.picks, res.scores, res.feasible_counts,
+                         res.fail_masks, res.scan_steps)
                     )
                     self._dispatch_counter.inc(kind="tail")
+                    self._count_scan_steps(steps2, size // chunk_level)
                     picks[idx], scores[idx], feas[idx], fails[idx] = (
                         p2[: len(idx)], s2[: len(idx)], f2[: len(idx)], fl2[: len(idx)],
                     )

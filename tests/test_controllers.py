@@ -220,7 +220,7 @@ def test_min_toleration_seconds_wins():
     s.update_node(_tainted("n1", ("maint", t.EFFECT_NO_EXECUTE)))
     uid = "default/p"
     armed, dl = s.taint_eviction.pending[uid]
-    assert dl - armed == 30  # min(300, 30): the 30s toleration bounds it
+    assert dl - armed == pytest.approx(30)  # min(300, 30): the 30s toleration bounds it
 
 
 def test_taint_removal_cancels_pending():
