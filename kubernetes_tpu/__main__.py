@@ -7,7 +7,9 @@ Subcommands:
                                   --journal-dir arms crash-safe durable state)
   recover --journal-dir DIR       offline recovery: rebuild scheduler state from
                                   snapshot + journal and print what survived
-  bench [workload ...]            the scheduler_perf-style harness
+  bench [workload ...]            the scheduler_perf-style harness, in process:
+                                  counts and correctness, never a speed
+                                  (speed: python3 perfbench/run.py)
   soak [--seconds N ...]          open-loop traffic soak: SLO percentiles,
                                   speculation miss-rate knee, journal growth
   fleet <action> --map PATH       shard-map administration for the
@@ -499,7 +501,7 @@ def cmd_soak(args) -> int:
     """Open-loop soak (loadgen/): drive the deployment for --seconds at
     --rate pods/s, then sweep the speculation miss-rate knee over
     --knee-points invalidation intensities.  Prints the artifact JSON
-    (the SOAK_rNN.json schema) and optionally writes it to --out."""
+    (the soak artifact schema) and optionally writes it to --out."""
     from .loadgen.soak import SoakConfig, run_soak, strip_private
 
     knee = tuple(
@@ -1178,7 +1180,12 @@ def main(argv: list[str] | None = None) -> int:
     )
     rec.set_defaults(fn=cmd_recover)
 
-    b = sub.add_parser("bench", help="run benchmark workloads")
+    bench_help = (
+        "run the in-process scheduler_perf-style rows: counts and "
+        "correctness, never a speed (speed is python3 perfbench/run.py on "
+        "the chip, recorded in PERF_LEDGER.jsonl and PERF.md)"
+    )
+    b = sub.add_parser("bench", help=bench_help, description=bench_help)
     b.add_argument("workloads", nargs="*")
     b.add_argument("--profile-dir", default="", help="write a jax.profiler trace here")
     b.set_defaults(fn=cmd_bench)

@@ -57,22 +57,13 @@ def _throughput_percentiles(samples: list[float]) -> dict:
     }
 
 
-def run_workload(
-    w: Workload,
-    attach: Callable | None = None,
-    pipeline_depth: int | None = None,
-) -> dict:
-    """``attach`` is called with the freshly built scheduler before any
-    objects land — the hook bench.py uses to arm the write-ahead journal
-    so the headline run measures journaling overhead in-band.
-    ``pipeline_depth`` overrides the scheduler's batch-loop pipelining
+def run_workload(w: Workload, pipeline_depth: int | None = None) -> dict:
+    """``pipeline_depth`` overrides the scheduler's batch-loop pipelining
     (ISSUE 15): depth 2 drains each batch's group-committed journal
     records under the next batch's in-flight device pass."""
     sched = w.build()
     if pipeline_depth is not None:
         sched.pipeline_depth = max(1, int(pipeline_depth))
-    if attach is not None:
-        attach(sched)
     w.nodes(sched)
     w.warmup(sched)
     sched.schedule_all_pending(wait_backoff=w.wait_backoff)
@@ -1284,6 +1275,14 @@ def row_failed(r: dict) -> bool:
     )
 
 
+def _refuse_unknown(names: list[str] | None) -> None:
+    unknown = [n for n in names or () if n not in WORKLOADS]
+    if unknown:
+        raise SystemExit(
+            f"unknown workload(s): {unknown}; available: {sorted(WORKLOADS)}"
+        )
+
+
 def main(
     names: list[str] | None = None, pipeline_depth: int | None = None
 ) -> list[dict]:
@@ -1293,12 +1292,7 @@ def main(
     every printed row names it."""
     from ..utils import require_device
 
-    if names:
-        unknown = [n for n in names if n not in WORKLOADS]
-        if unknown:
-            raise SystemExit(
-                f"unknown workload(s): {unknown}; available: {sorted(WORKLOADS)}"
-            )
+    _refuse_unknown(names)
     device = require_device()
     results = []
     for name, w in WORKLOADS.items():
@@ -1330,42 +1324,17 @@ def main_isolated(
     import subprocess
     import sys as _sys
 
-    from .integrated import INTEGRATED
-
-    known = set(WORKLOADS) | set(INTEGRATED)
-    if names:
-        unknown = [n for n in names if n not in known]
-        if unknown:
-            raise SystemExit(
-                f"unknown workload(s): {unknown}; available: {sorted(known)}"
-            )
+    _refuse_unknown(names)
     from ..utils import refuse_if_holding_device
 
     refuse_if_holding_device("a benchmark child")
-    selected = [
-        n for n in list(WORKLOADS) + list(INTEGRATED) if not names or n in names
-    ]
     results = []
-    for name in selected:
-        module = (
-            "kubernetes_tpu.benchmarks.integrated"
-            if name in INTEGRATED
-            else "kubernetes_tpu.benchmarks.harness"
-        )
-        argv = [_sys.executable, "-m", module, name]
-        if pipeline_depth is not None and module.endswith("harness"):
+    for name in WORKLOADS:
+        if names and name not in names:
+            continue
+        argv = [_sys.executable, "-m", "kubernetes_tpu.benchmarks.harness", name]
+        if pipeline_depth is not None:
             argv += ["--pipeline-depth", str(pipeline_depth)]
-        elif pipeline_depth is not None:
-            # INTEGRATED rows drive a serve child per-pod over the wire;
-            # the depth knob is not threaded through that deployment yet
-            # (ROADMAP's pipeline follow-up) — say so rather than let a
-            # sweep read as uniformly depth-N.
-            print(
-                f"harness: {name} is an integrated row — "
-                f"--pipeline-depth {pipeline_depth} not applied "
-                "(serve child runs at default depth)",
-                file=_sys.stderr,
-            )
         try:
             proc = subprocess.run(
                 argv, capture_output=True, text=True, timeout=ROW_TIMEOUT_S

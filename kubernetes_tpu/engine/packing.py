@@ -315,8 +315,8 @@ def pack_batch(batch: dict, npods: int, chunk: int) -> PackPlan:
 
 def residual_collisions(classes: np.ndarray, npods: int, width: int) -> int:
     """Forced same-chunk collisions at ``width`` under an optimal deal —
-    the per-width pack-quality number scripts/profile_ipa_pieces.py
-    reports (``Σ max(0, class_size − chunk_count)``)."""
+    the per-width pack-quality number (``Σ max(0, class_size −
+    chunk_count)``)."""
     if width <= 1:
         return 0
     sizes = np.bincount(classes, minlength=1)

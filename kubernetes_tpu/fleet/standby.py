@@ -1,12 +1,13 @@
 """Warm-standby owner pool: pre-warmed `serve` children a promotion
 turns into shard owners in O(handoff) instead of ~15s of cold boot.
 
-ROADMAP named the gap after SOAK_FLEET_r11: mid-incident elasticity —
-an autoscale split under a crest, or a takeover replacing a SIGKILLed
-owner — paid the new child's boot + XLA compile (~15s in the
-two_process_leg) right when the fleet could least afford it.  Tesserae
-(arxiv 2508.04953) frames the requirement: scaling actions are only
-usable under load when their cost is O(handoff), not O(cold start).
+The gap (the round-11 autoscale soak's two-process leg, ``run_soak.py
+--autoscale``, showed it): mid-incident elasticity — an autoscale split
+under a crest, or a takeover replacing a SIGKILLed owner — paid the new
+child's boot + XLA compile (~15s on that CPU box) right when the fleet
+could least afford it.  Tesserae (arxiv 2508.04953) frames the
+requirement: scaling actions are only usable under load when their cost
+is O(handoff), not O(cold start).
 
 This module keeps N children WARM: XLA programs compiled against the
 live featurization schema (a probe propose/remove cycle at spawn),

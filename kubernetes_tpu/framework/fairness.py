@@ -1,13 +1,14 @@
 """Weighted-fair tenant admission: WFQ ordering, rate caps with burst
 credits, and per-tenant starvation SLOs (ISSUE 17).
 
-SOAK_TENANT_r12 recorded the fairness gap this module closes: admission
-was FIFO, so a within-capacity ×8 burst from one tenant pushed its
-queueing delay onto every other tenant.  The policy here is Gavel's
-FAIRNESS objective (arxiv 2008.09213) — weighted accelerator-time
-shares — applied at the queue's admission point, with Tesserae-style
-per-tenant substrate (arxiv 2508.04953): the tenant is the unit of
-admission, not just of attribution.
+The fairness gap this module closes (the round-12 tenant-starvation
+soak, ``run_soak.py --tenant``, showed it): admission was FIFO, so a
+within-capacity ×8 burst from one tenant pushed its queueing delay onto
+every other tenant.  The policy here is Gavel's FAIRNESS objective
+(arxiv 2008.09213) — weighted accelerator-time shares — applied at the
+queue's admission point, with Tesserae-style per-tenant substrate
+(arxiv 2508.04953): the tenant is the unit of admission, not just of
+attribution.
 
 Three mechanisms, one deterministic state machine:
 

@@ -9,8 +9,8 @@ production questions the ROADMAP's sustained-traffic item asks:
   their own schedule (arrivals.py), so a scheduler falling behind
   accrues backlog and its tail degrades honestly.
 - **The speculation miss-rate knee** — a decision-cache miss costs a
-  full wire round trip + device pass (~195 ms in the recorded
-  integrated_serial row) while a hit costs a local map pop.  The knee
+  full wire round trip + device pass while a hit costs a local map
+  pop (what each costs on the chip is PERF.md's business).  The knee
   sweep ramps the invalidation intensity (scenarios.py) across phases
   and records where the hit rate collapses and the latency crosses the
   miss cost — the number nothing measured before this PR.
@@ -35,7 +35,7 @@ always-on draining; the single-threaded driver doesn't need it).
 Deployments: ``two_process=True`` spawns the real ``serve
 --journal-dir --speculate`` CLI as a child and drives it over the unix
 socket (the acceptance configuration); ``two_process=False`` hosts the
-SidecarServer in-process (tier-1 smoke, bench.py's slo block).
+SidecarServer in-process (tier-1 smoke, ``soak --in-process``).
 """
 
 from __future__ import annotations
@@ -988,8 +988,8 @@ def _spawn_serve(cfg: SoakConfig, sock: str, journal_dir: str, out_dir: str):
 
 
 def run_soak(cfg: SoakConfig) -> dict:
-    """Execute one soak and return the artifact document (the
-    ``SOAK_rNN.json`` schema README documents)."""
+    """Execute one soak and return the artifact document (the soak
+    artifact schema README documents)."""
     tmp = tempfile.TemporaryDirectory(prefix="tpu-soak-")
     out_dir = cfg.out_dir or tmp.name
     os.makedirs(out_dir, exist_ok=True)

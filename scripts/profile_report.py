@@ -4,16 +4,17 @@
 Input: the JSON document the flight recorder produces everywhere — an
 auto-dump file (engine fault / quarantine / breaker trip / SIGTERM /
 recovery), `python -m kubernetes_tpu flight --socket S`, or
-`GET /debug/flight` (pipe via `-`) — or a soak artifact
-(``SOAK_rNN.json`` from scripts/run_soak.py / the ``soak``
-subcommand).  Output: where the time went — aggregate per-phase seconds
-and share, per-batch percentiles, the sampled per-plugin table, and the
-transition-marker timeline; for soak artifacts, the SLO block, the
-miss-rate knee curve, journal growth, and the per-phase serving table.
+`GET /debug/flight` (pipe via `-`) — or a soak artifact (what
+scripts/run_soak.py writes to its ``--out`` and the ``soak`` subcommand
+prints; none is committed).  Output: where the time went — aggregate
+per-phase seconds and share, per-batch percentiles, the sampled
+per-plugin table, and the transition-marker timeline; for soak
+artifacts, the SLO block, the miss-rate knee curve, journal growth, and
+the per-phase serving table.
 
     python scripts/profile_report.py /tmp/flight-scheduler-123-001-quarantine.json
     python -m kubernetes_tpu flight --socket S | python scripts/profile_report.py -
-    python scripts/profile_report.py SOAK_r06.json
+    python scripts/profile_report.py <soak artifact>.json
 
 Fleet mode (``--fleet``): render ONE merged timeline from a partitioned
 fleet's flight logs — either a pre-merged document (the fleet soak's
@@ -280,8 +281,8 @@ def _decision_latency_split(doc: dict) -> str:
 
 
 def soak_report(doc: dict) -> str:
-    """Render one SOAK_rNN.json artifact: SLO, knee curve, journal
-    growth, per-phase serving table."""
+    """Render one soak artifact: SLO, knee curve, journal growth,
+    per-phase serving table."""
     out = []
     cfg = doc.get("config", {})
     out.append(
@@ -575,61 +576,6 @@ def _measured_matrix_table(matrix: dict) -> str:
     return _table(rows, ("workload class", *accels))
 
 
-def bench_report(doc: dict) -> str:
-    """Render one bench payload (bench.py stdout): headline + flagship
-    and the device they ran on, then the PR 16 blocks — the sentinel
-    guard table and the measured-matrix provenance stamp."""
-    out = [
-        f"bench payload: {doc.get('metric')} = {doc.get('value')} "
-        f"{doc.get('unit', '')}".rstrip()
-        + f" on {doc.get('platform', '?')} ({doc.get('device_kind', '?')})"
-    ]
-    fl = doc.get("flagship") or {}
-    if fl:
-        out.append(
-            f"flagship: {fl.get('metric', fl.get('name', '?'))} = "
-            f"{fl.get('value')} {fl.get('unit', '')}".rstrip()
-        )
-    sent = doc.get("sentinel")
-    if sent:
-        out.append(
-            f"\nsentinel: ok={sent.get('ok')} "
-            f"hard_failures={sent.get('hard_failures')} "
-            f"warnings={sent.get('warnings')} missing={sent.get('missing')}"
-        )
-        rows = []
-        for g in sent.get("guards", ()):
-            if "ratio" in g:
-                detail = (
-                    f"ratio {g['ratio']} vs {g.get('reference')} "
-                    f"[{g.get('source_file', '?')}]"
-                )
-                limits = f"warn>{g.get('warn_above')} hard>{g.get('hard_above')}"
-            elif "value" in g:
-                src = f" [{g['source_file']}]" if "source_file" in g else ""
-                detail = f"value {g['value']}{src}"
-                cmp_ = "<" if g.get("op") == "min" else ">"
-                limits = (
-                    f"warn{cmp_}{g.get('warn_limit')} "
-                    f"hard{cmp_}{g.get('hard_limit')}"
-                )
-            else:
-                detail = f"missing {g.get('missing', '?')}"
-                limits = "-"
-            rows.append((g["name"], g["status"], detail, limits))
-        out.append(_table(rows, ("guard", "status", "detail", "limits")))
-    mm = doc.get("measured_matrix")
-    if mm:
-        win = mm.get("window") or {}
-        out.append(
-            f"\nmeasured matrix: {mm.get('file')} v{mm.get('version')} "
-            f"(artifact sha {str(mm.get('sha256', ''))[:12]}…, "
-            f"{win.get('binds')} binds over {win.get('records')} records, "
-            f"lc window [{win.get('lc_lo')}, {win.get('lc_hi')}])"
-        )
-    return "\n".join(out)
-
-
 def _load_flight_module():
     """Import ``kubernetes_tpu/framework/flight.py`` by FILE PATH (it is
     stdlib-only; the package root imports JAX and must stay out)."""
@@ -796,10 +742,6 @@ def main(argv=None) -> int:
         ("soak_", "fleet_soak_", "tenant_soak")
     ) or ("knee" in doc and "slo" in doc):
         print(soak_report(doc))
-    elif "sentinel" in doc or str(doc.get("metric", "")).startswith(
-        "scheduling_throughput"
-    ):
-        print(bench_report(doc))
     else:
         print(report(doc))
     return 0

@@ -1,8 +1,10 @@
 #!/usr/bin/env python
 """The recorded-soak runner: the ≥5-minute seeded soak of the REAL
-two-process journaled deployment behind the committed SOAK_rNN.json
-artifacts, plus the determinism cross-check the acceptance bar asks
-for.
+two-process journaled deployment, plus the determinism cross-check the
+acceptance bar asks for.  It writes a soak artifact (``--out``; the
+default names below land in the working directory) and none is
+committed: a soak run on a CPU box proves the mechanics and counts,
+and speed is ``perfbench/``'s business (PERF.md).
 
 Three parts, one document:
 
@@ -17,8 +19,7 @@ Three parts, one document:
    kubernetes_tpu serve --journal-dir --speculate`` as a child,
    driven at the configured arrival rate for the sustained phase, then
    the miss-rate knee sweep across the invalidation intensities.
-3. The merged artifact is written to ``--out`` (SOAK_r06.json for the
-   r06 recording).
+3. The merged artifact is written to ``--out`` (default SOAK_r06.json).
 
     JAX_PLATFORMS=cpu python scripts/run_soak.py --out SOAK_r06.json
 
@@ -370,7 +371,7 @@ def tenant_streams(args) -> tuple:
 
 
 def run_tenant(args) -> int:
-    """--tenant: the tenant-starvation soak (ISSUE 12), recorded as
+    """--tenant: the tenant-starvation soak (ISSUE 12), written to
     SOAK_TENANT_r12.json — a 2-shard fleet serving two tenant-tagged
     arrival streams where one tenant bursts mid-run and the other holds
     steady.  Four legs, one document:
@@ -587,7 +588,7 @@ def run_tenant(args) -> int:
 
 def run_tenant_fair(args) -> int:
     """--tenant-fair: the weighted-fair admission soak (ISSUE 17),
-    recorded as SOAK_TENANT_r17.json — the r12 starvation scenario
+    written to SOAK_TENANT_r17.json — the r12 starvation scenario
     re-run with framework/fairness ARMED on the fleet router's queue.
     Five legs, one document:
 
@@ -1064,8 +1065,8 @@ def run_fleet(args) -> int:
 PROD_OUT_DEFAULT = "SOAK_PROD_r18.json"
 
 # The ~15s serve-child cold boot+compile this box pays without the
-# standby pool — the SOAK_FLEET_r11 recording's documented multi-process
-# resize transition cost, and the baseline every promotion latency in
+# standby pool — the round-11 autoscale soak's multi-process resize
+# transition cost, and the baseline every promotion latency in
 # the production-day artifact is compared against.
 PROD_COLD_BOOT_BASELINE_S = 15.0
 
@@ -1398,7 +1399,7 @@ def prod_phases(art, cfg, window_s=30.0) -> dict:
 
 def run_prod(args) -> int:
     """--prod: the hour-scale "production day" recording (ISSUE 18),
-    written as SOAK_PROD_r18.json.  Three legs, one document:
+    written to SOAK_PROD_r18.json.  Three legs, one document:
 
     1. determinism cross-check (2× virtual, full composition small):
        bindings, timeline, admission order AND the driver-state digest
@@ -1644,23 +1645,23 @@ def main() -> int:
     ap.add_argument("--node-loss", action="store_true",
                     help="arm the node-lifecycle loop and kill churn-node "
                     "heartbeats mid-soak: staleness → taints → eviction → "
-                    "requeue → reschedule, recorded as SOAK_r09.json")
+                    "requeue → reschedule, written to SOAK_r09.json")
     ap.add_argument("--autoscale", action="store_true",
                     help="fleet only: arm the elastic shard autoscaler and "
                     "the hot-spot diurnal mix — skew must trip a live "
-                    "split with the per-shard p99 recovering, recorded as "
+                    "split with the per-shard p99 recovering, written to "
                     "SOAK_FLEET_r11.json")
     ap.add_argument("--tenant", action="store_true",
                     help="the tenant-starvation soak (ISSUE 12): two "
                     "tenant-tagged streams over a multi-process fleet, "
                     "one bursting mid-run — per-tenant SLO split + solo "
-                    "baseline, recorded as SOAK_TENANT_r12.json")
+                    "baseline, written to SOAK_TENANT_r12.json")
     ap.add_argument("--tenant-fair", action="store_true",
                     help="the weighted-fair admission soak (ISSUE 17): "
                     "the r12 starvation scenario with WFQ + rate caps "
                     "armed on the router queue, plus the armed "
                     "determinism and ≥1k-tenant hashed-tier legs, "
-                    "recorded as SOAK_TENANT_r17.json")
+                    "written to SOAK_TENANT_r17.json")
     ap.add_argument("--prod", action="store_true",
                     help="the hour-scale 'production day' soak (ISSUE "
                     "18): diurnal tenant-tagged hetero traffic under "
@@ -1668,7 +1669,7 @@ def main() -> int:
                     "restarts, adversarial invalidations, scripted "
                     "owner kills revived from the WARM STANDBY POOL, "
                     "and autoscale splits served from it too — with "
-                    "the resumable checkpointer armed, recorded as "
+                    "the resumable checkpointer armed, written to "
                     f"{PROD_OUT_DEFAULT}")
     ap.add_argument("--resume", action="store_true",
                     help="--prod only: resume a killed production-day "

@@ -1,10 +1,10 @@
 """Tier-1 soak smoke (loadgen/): a seconds-scale seeded soak runs end
 to end in-process, populates the SLO-percentile and miss-rate-knee
 fields, and is deterministic — the same seed reproduces the arrival
-schedule exactly and lands bit-identical final bindings.  The
-committed SOAK_rNN.json artifacts come from scripts/run_soak.py's
-minutes-scale two-process run; this is the always-on guard that the
-harness itself stays correct and replayable."""
+schedule exactly and lands bit-identical final bindings.
+scripts/run_soak.py makes the minutes-scale two-process run; this is
+the always-on guard that the harness itself stays correct and
+replayable."""
 
 import json
 
