@@ -1047,8 +1047,10 @@ def main(argv: list[str] | None = None) -> int:
     )
     s.add_argument(
         "--snapshot-every", type=int, default=64, metavar="BATCHES",
-        help="checkpoint the store+queue and truncate the journal every "
-        "N batches (0 disables periodic snapshots)",
+        help="a checkpoint once the journal holds as many records past "
+        "the last one as this many full batches (N x --batch-size "
+        "records: the log a recovery replays; 0 disables periodic "
+        "snapshots)",
     )
     s.add_argument(
         "--node-grace-s", type=float, default=0.0, metavar="SECONDS",

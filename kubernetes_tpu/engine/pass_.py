@@ -4,7 +4,7 @@ This replaces both reference hot loops — the goroutine-parallel Filter over
 nodes (schedule_one.go:591 findNodesThatPassFilters) and the 3-pass parallel
 Score (runtime/framework.go:1101) — with vectorized ops over the node axis,
 and replaces the serialized one-pod-at-a-time outer loop (scheduler.go:470)
-with a `lax.scan` over the pod batch.
+with a loop over the pod batch.
 
 Chunking: each scan step schedules a CHUNK of `chunk` pods.  Filter, score,
 and selectHost are vmapped over the chunk (one set of vectorized ops services
@@ -25,11 +25,10 @@ With chunk=1 the pass is the strictly sequential-equivalent scan: each step
 is one reference scheduling cycle — filter → score → selectHost → commit —
 with the assume's row-delta applied to the carried ClusterState so the next
 pod observes it (the reference gets the same effect through its cache assume
-protocol, cache.go:361).  A padded step there costs what a real one costs,
-so the chunk-1 program drives the same step with a loop that stops one past
-the batch's last valid row and reports the steps it ran
-(PassResult.scan_steps); a chunked pass keeps the scan over all k // chunk
-steps.  Chunk>1 trades one documented divergence for
+protocol, cache.go:361).  A padded step costs what a real one costs, at
+every chunk width, so one loop drives the step to the chunk that holds the
+batch's last valid row and the program reports the steps it ran
+(PassResult.scan_steps).  Chunk>1 trades one documented divergence for
 throughput: non-interacting chunk-mates score against the chunk-start state,
 so resource-driven score drift (e.g. LeastAllocated) within a chunk does not
 influence their relative placement.  Hard constraints are never violated —
@@ -66,9 +65,9 @@ class PassResult(NamedTuple):
     # runtime/framework.go:861 RunFilterPlugins).  Bit order =
     # filter_op_names(profile, active).
     fail_masks: jax.Array
-    # () i32 — steps the ordered (chunk-1) driver of build_pass ran: one past
-    # the batch's last valid row, counted on the device.  None from every
-    # other program, whose step count is its shape's (k // chunk).
+    # () i32 — steps build_pass's loop ran, counted on the device: up to the
+    # chunk that holds the batch's last valid row (0 where the uniform
+    # all-fail shortcut answered instead).  None from every other program.
     scan_steps: jax.Array | None = None
 
 
@@ -368,8 +367,8 @@ def build_pass(
     frameworkImpl per profile (profile/profile.go:50) with per-cycle Skip
     sets, plus XLA compilation.  Result picks: node row ≥ 0, -1
     unschedulable, -2 deferred to a strict pass (see module docstring).
-    ``chunk == 1`` builds the ordered driver: its result carries
-    ``scan_steps``, and rows past the last valid one read as padding.
+    The result carries ``scan_steps``, the steps the loop ran, and rows past
+    the chunk of the last valid one read as padding.
 
     ``batch["step_offset"]`` (optional, (K,) i32): per-pod tie-break step
     offsets — the scheduler ships each pod's ORIGINAL dispatch position so
@@ -648,23 +647,15 @@ def build_pass(
             inv["scan_start"].astype(jnp.uint32) if truncated else jnp.uint32(0)
         )
 
-        def _run_scan(st0):
-            carry_, out_ = lax.scan(
-                step, (st0, dom0.group_dom, dom0.et_dom, start0), (cbatch, steps)
-            )
-            return carry_, out_
-
-        def _run_ordered(st0, n):
-            """The chunk-1 driver of `step`: rows 0..n-1 in order, where n is
-            one past the batch's last valid row.  A padded step of the
-            ordered program costs what a real one costs (one pod), so it
-            stops where the pods do; the carry is the scan's, and rows at
-            and past n keep what the host reads of a padded row (picks -1,
-            processed 0; the rest 0)."""
-            xs = (cbatch, steps)
-            carry0 = (st0, dom0.group_dom, dom0.et_dom, start0)
+        def _drive(fn, carry0, xs, n):
+            """The one driver of `step` and `step_tail`, at every chunk
+            width: fn over chunks 0..n-1 of xs in order.  A padded step
+            costs what a real one costs, so the walk stops where the pods
+            do; the carry is a scan's, and the chunks at and past n keep
+            what the host reads of a padded row (picks -1, processed 0;
+            the rest 0)."""
             _, row0 = jax.eval_shape(
-                step, carry0, jax.tree_util.tree_map(lambda x: x[0], xs)
+                fn, carry0, jax.tree_util.tree_map(lambda x: x[0], xs)
             )
             out0 = jax.tree_util.tree_map(
                 lambda r: jnp.zeros((k // c,) + r.shape, r.dtype), row0
@@ -676,7 +667,7 @@ def build_pass(
                 x = jax.tree_util.tree_map(
                     lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), xs
                 )
-                carry_, row = step(carry_, x)
+                carry_, row = fn(carry_, x)
                 out_ = jax.tree_util.tree_map(
                     lambda o, r: lax.dynamic_update_index_in_dim(o, r, i, 0),
                     out_, row,
@@ -685,22 +676,29 @@ def build_pass(
 
             return lax.fori_loop(0, n, body, (carry0, out0))
 
-        uniform = uniform_all if fuse_tail else None
-        if c == 1:
-            # One step, two drivers, chosen by the chunk width the program
-            # is built for: a chunked pass is k // c fat steps whatever it
-            # holds and keeps the scan below.
-            scan_steps = jnp.max(
-                jnp.where(batch["valid"], jnp.arange(1, k + 1, dtype=jnp.int32), 0)
+        # How far to walk is read from the batch itself: to the chunk that
+        # holds its last valid row (0 for an empty batch, k // c for a full
+        # one, holes included).
+        past_last = jnp.max(
+            jnp.where(batch["valid"], jnp.arange(1, k + 1, dtype=jnp.int32), 0)
+        )
+        n_steps = (past_last + (c - 1)) // c
+
+        def _run_steps(st0):
+            carry_, out_ = _drive(
+                step, (st0, dom0.group_dom, dom0.et_dom, start0),
+                (cbatch, steps), n_steps,
             )
-            carry, out = _run_ordered(state, scan_steps)
-        elif uniform is not None:
+            return carry_, out_, n_steps
+
+        uniform = uniform_all if fuse_tail else None
+        if uniform is not None:
             # Template-batch all-fail shortcut: when every pod in the
             # batch is featurization-identical (the scheduler ships the
             # flag) and the REPRESENTATIVE is feasible nowhere, every pod
-            # fails identically — the scan would commit nothing and each
-            # chunk would reproduce the same verdict k/c times.  One
-            # evaluation replaces the whole scan (the full-cluster
+            # fails identically — the walk would commit nothing and each
+            # chunk would reproduce the same verdict.  One evaluation
+            # replaces the whole walk (the full-cluster
             # preemption shape: the main pass exists only to prove
             # failure before the chained dry-run does the real work).
             # Sound under the fused-tail gating (node-axis-only ops) —
@@ -722,11 +720,11 @@ def build_pass(
                     fail_masks=jnp.where(valid, fail0, jnp.zeros((), fail0.dtype)),
                     processed=jnp.zeros(valid.shape, _pr0.dtype),
                 )
-                return carry_, out_
+                return carry_, out_, jnp.zeros_like(n_steps)
 
-            carry, out = lax.cond(allfail, fail_branch, _run_scan, state)
+            carry, out, scan_steps = lax.cond(allfail, fail_branch, _run_steps, state)
         else:
-            carry, out = _run_scan(state)
+            carry, out, scan_steps = _run_steps(state)
         out = jax.tree_util.tree_map(
             lambda x: x.reshape((k,) + x.shape[2:]), out
         )
@@ -776,8 +774,12 @@ def build_pass(
                     carry2,
                 )
 
+            # A deferred row is a row a step decided, so the tail walks
+            # as far as the main walk did and no further.
             with jax.named_scope("pass/tail"):
-                carry, out2 = lax.scan(step_tail, carry, (cbatch2, steps2))
+                carry, out2 = _drive(
+                    step_tail, carry, (cbatch2, steps2), scan_steps
+                )
             out2 = jax.tree_util.tree_map(
                 lambda x: x.reshape((k,) + x.shape[2:]), out2
             )
@@ -790,10 +792,7 @@ def build_pass(
                 fail_masks=jnp.where(deferred1, out2.fail_masks, out.fail_masks),
                 processed=out.processed,
             )
-        state = carry[0]
-        if c == 1:
-            out = out._replace(scan_steps=scan_steps)
-        return state, out, (carry[1], carry[2])
+        return carry[0], out._replace(scan_steps=scan_steps), (carry[1], carry[2])
 
     if carry_dom:
         return jax.jit(_run)

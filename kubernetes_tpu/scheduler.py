@@ -452,8 +452,7 @@ class TPUScheduler:
         # in-memory configuration; attach_journal() arms the commit-path
         # hooks, snapshot cadence and scheduler_journal_* metrics.
         self.journal = None
-        self.snapshot_every_batches = 0
-        self._last_snapshot_batch = 0
+        self.snapshot_every_records = 0
         # Speculative frontend (sidecar/speculate.py), when one wraps this
         # scheduler: registered so snapshots can persist its decision-cache
         # epoch.  _recovered_spec_epoch carries the journaled epoch across
@@ -518,7 +517,7 @@ class TPUScheduler:
         self._scan_steps_counter = reg.counter(
             "scheduler_pass_scan_steps_total",
             "Steps of the batch pass, by kind: run, and padded_skipped "
-            "(steps of the batch shape the ordered pass did not run).",
+            "(steps of the batch shape the pass did not run).",
         )
         # Flight-recorder phase attribution (the tiled per-batch segments;
         # journal_append/journal_fsync nest inside featurize+commit and
@@ -771,7 +770,8 @@ class TPUScheduler:
         """Arm the write-ahead binding journal: every bind/preempt/
         quarantine/delete decision is appended (and fsync'd, per the
         journal's policy) BEFORE it is applied, snapshots checkpoint the
-        store+queue every ``snapshot_every_batches`` batches (0 = only on
+        store+queue once the log holds as many records past the last
+        barrier as ``snapshot_every_batches`` full batches (0 = only on
         explicit snapshot), and the journal's counters export as
         scheduler_journal_* at scrape time.  Recovery (journal.recover)
         must run BEFORE attaching — its replay drives this scheduler's
@@ -780,7 +780,7 @@ class TPUScheduler:
         self.queue.journal = journal
         journal.spans = self.spans
         if snapshot_every_batches:
-            self.snapshot_every_batches = snapshot_every_batches
+            self.snapshot_every_records = snapshot_every_batches * self.batch_size
         reg = self.metrics.registry
         appends = reg.counter(
             "scheduler_journal_appends_total",
@@ -874,22 +874,16 @@ class TPUScheduler:
                 self.provenance.note_seq(pod.uid, seq)
 
     def maybe_snapshot(self) -> bool:
-        """Checkpoint when the cadence is due AND the log has grown since
-        the last barrier (an idle scheduler never rewrites its snapshot)."""
+        """Checkpoint once the journal holds ``snapshot_every_records``
+        records past the last barrier: as many as attach_journal's
+        ``snapshot_every_batches`` full batches write.  The cadence bounds
+        the log a recovery replays, so it counts records: the bound is the
+        same whatever the batches hold, and an idle scheduler never
+        rewrites its snapshot."""
         j = self.journal
-        if j is None or not self.snapshot_every_batches:
+        if j is None or not self.snapshot_every_records:
             return False
-        if self._last_snapshot_batch > self.metrics.batches:
-            # The batch counter moved backwards (the bench harness resets
-            # metrics after warmup): re-base instead of stalling the
-            # cadence until the counter catches back up.
-            self._last_snapshot_batch = 0
-        if (
-            self.metrics.batches - self._last_snapshot_batch
-            < self.snapshot_every_batches
-        ):
-            return False
-        if j.seq == j.snapshot_seq:
+        if j.seq - j.snapshot_seq < self.snapshot_every_records:
             return False
         from . import journal as journal_mod
 
@@ -897,7 +891,6 @@ class TPUScheduler:
             with self.span("snapshot/collect"):
                 state = journal_mod.scheduler_state(self)
             j.snapshot(state)
-        self._last_snapshot_batch = self.metrics.batches
         return True
 
     def _note_slow_span(self, tr: Trace) -> None:
@@ -1000,10 +993,10 @@ class TPUScheduler:
 
     def _count_scan_steps(self, steps_run, shape_steps: int) -> None:
         """One fetched pass's steps into scheduler_pass_scan_steps_total and
-        the open flight record.  ``steps_run`` is the ordered program's own
-        count (PassResult.scan_steps, fetched with the result); a chunked
-        program ships none and ran its shape's ``shape_steps``."""
-        ran = shape_steps if steps_run is None else int(steps_run)
+        the open flight record.  ``steps_run`` is the program's own count
+        (PassResult.scan_steps, fetched with the result) of the
+        ``shape_steps`` its padded shape holds."""
+        ran = int(steps_run)
         self._scan_steps_counter.inc(ran, kind="run")
         self._scan_steps_counter.inc(shape_steps - ran, kind="padded_skipped")
         self._flight_add("scan_steps", ran)
@@ -2937,9 +2930,10 @@ class TPUScheduler:
             f"Successfully assigned {pod.uid} to {node_name}",
         )
         # One fleet commit ≈ one reference scheduling cycle (the extender
-        # path counts the same way): tick the snapshot cadence, or a
-        # fleet owner's WAL would grow forever — the router never drives
-        # schedule_batch, so the batch-loop call site can't fire here.
+        # path counts the same way).  The checkpoint's gate is consulted
+        # here, or a fleet owner's WAL would grow forever — the router
+        # never drives schedule_batch, so the batch-loop call site can't
+        # fire.
         self.metrics.batches += 1
         self.maybe_snapshot()
         return ScheduleOutcome(pod, node_name)

@@ -375,6 +375,19 @@ def _record_lease_truth(sched, state_dir: str) -> None:
     sched.renew_node_lease = renew
 
 
+def _every_batch(sched) -> None:
+    """The matrices probe the compaction windows, so their schedulers
+    checkpoint at every batch boundary where the log grew: a cadence of one
+    record (attach_journal states its cadence in full batches of records,
+    which these short batches never fill)."""
+    sched.snapshot_every_records = 1
+
+
+def _attach_every_batch(sched, journal) -> None:
+    sched.attach_journal(journal)
+    _every_batch(sched)
+
+
 def _journaled_scheduler(state_dir: str):
     """(scheduler, journal): the golden basic-session scheduler with the
     write-ahead journal armed under the journal lease's fencing epoch,
@@ -452,7 +465,7 @@ def kill_child(state_dir: str) -> None:
     from kubernetes_tpu.faults import KillSwitch
 
     sched, journal = _journaled_scheduler(state_dir)
-    sched.attach_journal(journal, snapshot_every_batches=1)
+    _attach_every_batch(sched, journal)
     ks = KillSwitch.from_env()
     if ks is not None:
         ks.arm()
@@ -482,7 +495,7 @@ def recover_child(state_dir: str) -> None:
 
     sched, journal = _journaled_scheduler(state_dir)
     recover(sched, journal)
-    sched.attach_journal(journal, snapshot_every_batches=1)
+    _attach_every_batch(sched, journal)
     nodes, bound, pending = scenario_objects()
     deleted = _truth_deleted(state_dir)
     src_n, src_p = FakeSource(), FakeSource()
@@ -685,7 +698,7 @@ def _pack_child(state_dir: str, chunk: int) -> None:
     from kubernetes_tpu.faults import KillSwitch
 
     sched, journal = _pack_scheduler(state_dir, chunk)
-    sched.attach_journal(journal, snapshot_every_batches=1)
+    _attach_every_batch(sched, journal)
     ks = KillSwitch.from_env()
     if ks is not None:
         ks.arm()
@@ -727,7 +740,7 @@ def pack_recover_child(state_dir: str) -> None:
     # The carried DomTables are process state: recovery must start cold
     # and rebuild from the journaled store on the next dispatch.
     assert sched._dom_carry is None, "dom carry survived recovery"
-    sched.attach_journal(journal, snapshot_every_batches=1)
+    _attach_every_batch(sched, journal)
     nodes, pods = pack_scenario_objects()
     src_n, src_p = FakeSource(), FakeSource()
     for n in nodes:
@@ -935,7 +948,7 @@ def tenant_kill_child(state_dir: str) -> None:
     from kubernetes_tpu.faults import KillSwitch
 
     sched, journal = _tenant_scheduler(state_dir)
-    sched.attach_journal(journal, snapshot_every_batches=1)
+    _attach_every_batch(sched, journal)
     ks = KillSwitch.from_env()
     if ks is not None:
         ks.arm()
@@ -960,7 +973,7 @@ def tenant_recover_child(state_dir: str) -> None:
 
     sched, journal = _tenant_scheduler(state_dir)
     recover(sched, journal)
-    sched.attach_journal(journal, snapshot_every_batches=1)
+    _attach_every_batch(sched, journal)
     nodes, pods = tenant_scenario_objects()
     src_n, src_p = FakeSource(), FakeSource()
     for n in nodes:
@@ -1316,9 +1329,8 @@ def _fleet_build(state_dir: str, recover: bool = False):
         if recover:
             owner = recover_shard(sdir, take(k), k, smap, map_path=map_path)
         else:
-            owner = ShardOwner(
-                k, factory(), smap, state_dir=sdir, snapshot_every_batches=1
-            )
+            owner = ShardOwner(k, factory(), smap, state_dir=sdir)
+            _every_batch(owner.sched)
         orig_delete = owner.sched.delete_pod
 
         def delete_pod(uid: str, notify: bool = True, _orig=orig_delete):
@@ -1588,11 +1600,8 @@ def standby_promo_child(state_dir: str) -> None:
     for k in range(2):
         sdir = os.path.join(state_dir, f"shard{k}")
         os.makedirs(sdir, exist_ok=True)
-        owners[k] = wrap_delete(
-            ShardOwner(
-                k, factory(), smap, state_dir=sdir, snapshot_every_batches=1
-            )
-        )
+        owners[k] = wrap_delete(ShardOwner(k, factory(), smap, state_dir=sdir))
+        _every_batch(owners[k].sched)
     router = FleetRouter(owners, smap, batch_size=8)
     router.profile_filters = tuple(owners[0].sched.profile.filters)
     nodes, bound, pending = scenario_objects()
@@ -1619,11 +1628,10 @@ def standby_promo_child(state_dir: str) -> None:
     sched1 = payload["sched"] if payload else factory()
     owners[1] = wrap_delete(
         ShardOwner(
-            1, sched1, smap,
-            state_dir=os.path.join(state_dir, "shard1"),
-            snapshot_every_batches=1,
+            1, sched1, smap, state_dir=os.path.join(state_dir, "shard1")
         )
     )
+    _every_batch(owners[1].sched)
     # Rebuild the router over the recovered truth (the revive_owner
     # idiom): nodes relist, parked journal bindings re-apply, the router
     # adopts, bound pods re-feed idempotently, then the tail runs.
@@ -2037,7 +2045,7 @@ def node_loss_child(state_dir: str) -> None:
     from kubernetes_tpu.faults import KillSwitch
 
     sched, journal = _node_loss_scheduler(state_dir)
-    sched.attach_journal(journal, snapshot_every_batches=1)
+    _attach_every_batch(sched, journal)
     _record_lease_truth(sched, state_dir)
     ks = KillSwitch.from_env()
     if ks is not None:
@@ -2075,7 +2083,7 @@ def node_loss_recover_child(state_dir: str) -> None:
 
     sched, journal = _node_loss_scheduler(state_dir)
     recover(sched, journal)
-    sched.attach_journal(journal, snapshot_every_batches=1)
+    _attach_every_batch(sched, journal)
     nodes, bound, pending = node_loss_objects()
     deleted = _truth_deleted(state_dir)
     evicted = _truth_evicted(state_dir)
@@ -2312,8 +2320,9 @@ def _fleet_node_loss_build(state_dir: str, recover: bool = False):
         else:
             owner = ShardOwner(
                 k, _fleet_node_loss_sched(), smap, state_dir=sdir,
-                snapshot_every_batches=1, lifecycle=FLEET_LIFECYCLE,
+                lifecycle=FLEET_LIFECYCLE,
             )
+            _every_batch(owner.sched)
         orig_delete = owner.sched.delete_pod
         orig_evict = owner.sched.evict_pod
 
@@ -2813,12 +2822,9 @@ def _autoscale_build(state_dir: str, recover: bool = False):
     def make_owner(k: int) -> ShardOwner:
         sdir = os.path.join(state_dir, f"shard{k}")
         os.makedirs(sdir, exist_ok=True)
-        return _wrap_truth(
-            ShardOwner(
-                k, _autoscale_sched(), smap, state_dir=sdir,
-                snapshot_every_batches=1,
-            )
-        )
+        owner = ShardOwner(k, _autoscale_sched(), smap, state_dir=sdir)
+        _every_batch(owner.sched)
+        return _wrap_truth(owner)
 
     owners = {}
     if recover:
@@ -3143,8 +3149,8 @@ def wire_sidecar_child(state_dir: str) -> None:
         os.path.join(state_dir, "sidecar.sock"),
         scheduler=session_schedulers()["basic_session"](),
         journal=journal,
-        snapshot_every_batches=1,
     )
+    _every_batch(srv.scheduler)
     srv.serve_forever()
 
 

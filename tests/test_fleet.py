@@ -70,9 +70,10 @@ def build_fleet(
     owners = {}
     for k in range(n_shards):
         sdir = os.path.join(state_root, f"shard{k}") if state_root else None
-        owners[k] = ShardOwner(
-            k, factory(), smap, state_dir=sdir, snapshot_every_batches=1
-        )
+        owners[k] = ShardOwner(k, factory(), smap, state_dir=sdir)
+        # A checkpoint behind every commit (a cadence of one record): the
+        # takeover tests recover from a snapshot and the log's tail.
+        owners[k].sched.snapshot_every_records = 1
     router = FleetRouter(owners, smap, batch_size=8)
     router.profile_filters = tuple(owners[0].sched.profile.filters)
     return router, owners, smap
@@ -925,9 +926,9 @@ def test_owner_snapshot_persists_lifecycle_clock(tmp_path):
     smap = ShardMap(n_shards=1, n_buckets=16, overrides=pin)
     owner = ShardOwner(
         0, mk_lifecycle_sched(), smap,
-        state_dir=os.path.join(root, "shard0"),
-        snapshot_every_batches=1, lifecycle=LIFECYCLE,
+        state_dir=os.path.join(root, "shard0"), lifecycle=LIFECYCLE,
     )
+    owner.sched.snapshot_every_records = 1  # a checkpoint behind every commit
     owner.add_object("Node", big_node("left"))
     sticky = (
         make_pod("sticky").req({"cpu": "1"})

@@ -263,6 +263,7 @@ def test_epoch_monotonicity_feeds_journal(tmp_path):
 def scenario_sched(journal=None):
     s = small_sched()
     if journal is not None:
+        # One full batch of records (8, the scheduler's batch size).
         s.attach_journal(journal, snapshot_every_batches=1)
     for i in range(3):
         s.add_node(node(f"n{i}"))
@@ -292,8 +293,9 @@ def test_recovery_from_journal_only(tmp_path):
 def test_recovery_from_snapshot_and_journal(tmp_path):
     j = Journal(str(tmp_path), epoch=1)
     s1 = scenario_sched(journal=j)
-    s1.add_pod(pod("w1"))
-    s1.schedule_all_pending()  # snapshot_every_batches=1 → checkpointed
+    for i in range(8):
+        s1.add_pod(pod(f"w1-{i}"))
+    s1.schedule_all_pending()  # one full batch of binds → checkpointed
     assert j.snapshots >= 1
     s1.add_pod(pod("w2"))
     s1.journal = None  # crash window: w2's bind never journals...
@@ -576,6 +578,79 @@ def test_wal_bounded_under_unbounded_append_stream(tmp_path):
     s2 = scenario_sched()
     recover(s2, Journal(str(tmp_path / "compact"), epoch=2))
     assert bindings_of(s2) == want
+
+
+# -- the cadence is counted in records (ISSUE 32) ----------------------------
+
+
+def cadence_sched(journal=None):
+    """Batches of 8 rows, a checkpoint every two full batches of records
+    (16), on nodes with room for every pod of these tests."""
+    s = small_sched()
+    if journal is not None:
+        s.attach_journal(journal, snapshot_every_batches=2)
+    for i in range(4):
+        s.add_node(node(f"n{i}", cpu="64"))
+    return s
+
+
+def _bind_batch(sched, tag: str, n: int) -> None:
+    for i in range(n):
+        sched.add_pod(pod(f"{tag}-{i}"))
+    assert all(o.node_name for o in sched.schedule_all_pending())
+
+
+def test_short_batches_checkpoint_by_the_records_they_hold(tmp_path):
+    """Five batches of three pods are 15 records: under 2 x 8, so no
+    checkpoint however many batches they were; one record more takes one."""
+    j = Journal(str(tmp_path), epoch=1)
+    s = cadence_sched(j)
+    for b in range(5):
+        _bind_batch(s, f"short{b}", 3)
+    assert s.metrics.batches == 5
+    assert (j.seq, j.snapshot_seq, j.snapshots) == (15, 0, 0)
+    _bind_batch(s, "one-more", 1)
+    assert (j.seq, j.snapshot_seq, j.snapshots) == (16, 16, 1)
+
+
+def test_full_batches_checkpoint_exactly_where_they_did(tmp_path):
+    j = Journal(str(tmp_path), epoch=1)
+    s = cadence_sched(j)
+    barriers = []
+    for b in range(6):
+        _bind_batch(s, f"full{b}", 8)
+        barriers.append(j.snapshot_seq)
+    assert barriers == [0, 16, 16, 32, 32, 48] and j.snapshots == 3
+
+
+def test_short_batches_without_a_checkpoint_recover_every_binding(tmp_path):
+    j = Journal(str(tmp_path), epoch=1)
+    s1 = cadence_sched(j)
+    for b in range(5):
+        _bind_batch(s1, f"short{b}", 3)
+    assert j.snapshots == 0
+    want = bindings_of(s1)
+    assert len(want) == 15
+    j.close()
+    s2 = cadence_sched()
+    stats = recover(s2, Journal(str(tmp_path), epoch=2))
+    assert not stats["snapshot"] and stats["records"] == 15
+    assert bindings_of(s2) == want  # node for node
+
+
+def test_idle_scheduler_never_rewrites_its_snapshot(tmp_path):
+    j = Journal(str(tmp_path), epoch=1)
+    s = cadence_sched(j)
+    _bind_batch(s, "full0", 8)
+    _bind_batch(s, "full1", 8)
+    assert j.snapshots == 1
+    snap = os.path.join(j.dir, Journal.SNAP)
+    before = (os.stat(snap).st_mtime_ns, os.stat(snap).st_ino)
+    for _ in range(40):  # idle polls, each a batch boundary
+        assert s.schedule_all_pending() == []
+        assert not s.maybe_snapshot()
+    assert j.snapshots == 1
+    assert (os.stat(snap).st_mtime_ns, os.stat(snap).st_ino) == before
 
 
 @pytest.mark.faults
@@ -1011,8 +1086,9 @@ def test_recover_cli_reports_bindings(tmp_path):
     jdir = str(tmp_path / "j")
     j = Journal(jdir, epoch=1)
     s1 = scenario_sched(journal=j)  # snapshot cadence: nodes checkpointed
-    s1.add_pod(pod("w1"))
-    s1.schedule_all_pending()
+    for i in range(8):
+        s1.add_pod(pod(f"w1-{i}"))
+    s1.schedule_all_pending()  # one full batch of binds → checkpointed
     assert j.snapshots >= 1
     want = bindings_of(s1)
     j.close()

@@ -45,9 +45,11 @@ def run_golden_session(stem: str, depth: int, journal_dir: str):
     the write-ahead journal armed so the drain exercises group commit."""
     sched = session_schedulers()[stem]()
     sched.pipeline_depth = depth
-    sched.attach_journal(
-        Journal(journal_dir, epoch=1), snapshot_every_batches=1
-    )
+    sched.attach_journal(Journal(journal_dir, epoch=1))
+    # A checkpoint behind every batch that journalled anything (a cadence
+    # of one record: the session's batches are short), so the snapshot
+    # falls between pipelined batches too.
+    sched.snapshot_every_records = 1
     nodes, bound, pending = scenario_objects()
     for n in nodes:
         sched.add_node(n)

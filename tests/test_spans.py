@@ -186,10 +186,10 @@ def test_depth1_phases_are_the_spans_that_feed_them_and_tile_the_wall(tmp_path):
     s = _sched(tmp_path)
     for i in range(3):
         s.add_node(_node(f"n{i}"))
-    for i in range(6):
+    for i in range(8):  # a full batch of records: the checkpoint falls due
         s.add_pod(_pod(f"p{i}"))
     s.trace_threshold_s = 0.0
-    assert sum(1 for o in s.schedule_batch() if o.node_name) == 6
+    assert sum(1 for o in s.schedule_batch() if o.node_name) == 8
     (rec,) = s.flight.records()
     names = _by_name(rec)
     for name in EVERY_BATCH + CHECKPOINT:
