@@ -519,6 +519,12 @@ class TPUScheduler:
             "Steps of the batch pass, by kind: run, and padded_skipped "
             "(steps of the batch shape the pass did not run).",
         )
+        self._filter_rejecting_counter = reg.counter(
+            "scheduler_pass_filter_rejecting_pods_total",
+            "Pods for which a filter op of the compiled pass ruled out at "
+            "least one node that every earlier filter had let through, by "
+            "plugin, counted once a pod.",
+        )
         # Flight-recorder phase attribution (the tiled per-batch segments;
         # journal_append/journal_fsync nest inside featurize+commit and
         # are exported for the durability-tax view, not the tiling sum).
@@ -1001,6 +1007,24 @@ class TPUScheduler:
         self._scan_steps_counter.inc(shape_steps - ran, kind="padded_skipped")
         self._flight_add("scan_steps", ran)
 
+    def _count_filter_rejections(self, fails, picks, n: int, bit_names) -> None:
+        """One batch's settled fail masks into
+        scheduler_pass_filter_rejecting_pods_total{plugin} and the open
+        flight record's ``filter_rejecting``: for every filter op of the
+        compiled pass (``bit_names`` = filter_op_names) the valid rows whose
+        bit is set.  Once a pod: a tail overwrote its rows in place before
+        this runs, and a row sent back to the queue (pick -3) is counted by
+        the batch that decides it.  Host arithmetic on the array the batch
+        fetched anyway; no program changes for it."""
+        live = fails[:n][picks[:n] != -3]
+        acc = self._flight_acc
+        for b, name in enumerate(bit_names):
+            hit = int(np.count_nonzero(live & np.uint32(1 << b)))
+            self._filter_rejecting_counter.inc(hit, plugin=name)
+            if hit and acc is not None:
+                rec = acc.setdefault("filter_rejecting", {})
+                rec[name] = rec.get(name, 0) + hit
+
     # -- software pipeline (ISSUE 15, engine/pipeline.py) ---------------------
 
     def _pipeline_active(self) -> bool:
@@ -1137,6 +1161,7 @@ class TPUScheduler:
             "unschedulable": acc["unschedulable"],
             "deferred": acc.get("deferred", 0),
             "scan_steps": acc.get("scan_steps", 0),
+            "filter_rejecting": acc.get("filter_rejecting", {}),
             "dispatch": acc["dispatches"],
             "wall_s": round(wall, 6),
             "phases": {k: round(v, 6) for k, v in phases.items()},
@@ -4014,6 +4039,8 @@ class TPUScheduler:
                 if deferred:
                     run_tail(deferred, 1, self.tail_size)
             tail_placed = any(picks[i] >= 0 for i in all_deferred)
+        bit_names = filter_op_names(profile, active)
+        self._count_filter_rejections(fails, picks, len(infos), bit_names)
         # The commit stage starts where the pass ends: this span's start
         # closes the `device` phase (dispatch to fetched, tails included).
         with self.span("commit/stage", phase="commit") as cs:
@@ -4358,10 +4385,9 @@ class TPUScheduler:
                         **self._trace_extra(),
                     )
             # Diagnosis from the device's per-op fail bitmask (bit order =
-            # filter_op_names): which plugins rejected nodes this cycle.  A
+            # ``bit_names``): which plugins rejected nodes this cycle.  A
             # uniform failing batch (5k no-fit pods, the Unschedulable shape)
             # produces ONE distinct mask — build each mask's plugin set once.
-            bit_names = filter_op_names(profile, active)
             mask_sets: dict[int, set] = {}
             failed2 = []
             for i, qp, _ in failed:
