@@ -41,6 +41,12 @@ from functools import partial  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 
+# Keys of a featurized batch that are not feature rows of a pod's template.
+_PER_BATCH_KEYS = frozenset(
+    {"valid", "nominated_row", "uniform_all", "step_offset"}
+)
+
+
 @partial(jax.jit, static_argnums=3)
 def _expand_uniform(small, valid, nomrow, k):
     """Broadcast a uniform batch's single representative feature row to
@@ -518,6 +524,12 @@ class TPUScheduler:
             "scheduler_pass_scan_steps_total",
             "Steps of the batch pass, by kind: run, and padded_skipped "
             "(steps of the batch shape the pass did not run).",
+        )
+        self._pass_inputs_counter = reg.counter(
+            "scheduler_pass_inputs_total",
+            "Input leaves of the batch pass per dispatch, by kind: shipped "
+            "(crossed from the host) and resident (the device's own from "
+            "an earlier dispatch).",
         )
         self._filter_rejecting_counter = reg.counter(
             "scheduler_pass_filter_rejecting_pods_total",
@@ -1007,6 +1019,17 @@ class TPUScheduler:
         self._scan_steps_counter.inc(shape_steps - ran, kind="padded_skipped")
         self._flight_add("scan_steps", ran)
 
+    def _count_pass_inputs(self, total: int, shipped: int, nbytes: int) -> None:
+        """One dispatch's input leaves into scheduler_pass_inputs_total
+        {kind} and the open flight record: of the ``total`` leaves the pass
+        takes besides the state and the domain tables, ``shipped`` crossed
+        from the host (``nbytes`` in all) and the rest were the device's
+        own from an earlier dispatch."""
+        self._pass_inputs_counter.inc(shipped, kind="shipped")
+        self._pass_inputs_counter.inc(max(total - shipped, 0), kind="resident")
+        self._flight_add("inputs_shipped", shipped)
+        self._flight_add("inputs_shipped_bytes", nbytes)
+
     def _count_filter_rejections(self, fails, picks, n: int, bit_names) -> None:
         """One batch's settled fail masks into
         scheduler_pass_filter_rejecting_pods_total{plugin} and the open
@@ -1161,6 +1184,8 @@ class TPUScheduler:
             "unschedulable": acc["unschedulable"],
             "deferred": acc.get("deferred", 0),
             "scan_steps": acc.get("scan_steps", 0),
+            "inputs_shipped": acc.get("inputs_shipped", 0),
+            "inputs_shipped_bytes": acc.get("inputs_shipped_bytes", 0),
             "filter_rejecting": acc.get("filter_rejecting", {}),
             "dispatch": acc["dispatches"],
             "wall_s": round(wall, 6),
@@ -3194,6 +3219,23 @@ class TPUScheduler:
         inv["nom_req"], inv["nom_cnt"], inv["nom_prio"] = nom_req, nom_cnt, nom_prio
         return inv
 
+    def _resident_inv(self) -> tuple[dict, dict | None, tuple | None]:
+        """The batch invariants for a dispatch: (host arrays, their device
+        copy or None, the key they may stay resident under or None).  The
+        device keeps them (builder.resident) while the schema and the term
+        vocabulary stand and the nominator is empty, the overlay then being
+        the constant it is; on a hit the host arrays are not rebuilt
+        either.  A nominated pod, or truncated mode (order_pos and
+        scan_start move with every batch), builds and ships them whole."""
+        if self._truncated or self.nominator:
+            return self._full_inv(), None, None
+        b = self.builder
+        held = b.resident.get("inv")
+        if held is not None and held[0] == (b.schema, len(b.interns.terms)):
+            return held[1], held[2], held[0]
+        inv = self._full_inv()  # may grow the schema: the key reads it after
+        return inv, None, (b.schema, len(b.interns.terms))
+
     def schedule_batch(self) -> list[ScheduleOutcome]:
         """Pop up to batch_size pods and schedule them in one device pass
         per profile (pods group by .spec.scheduler_name).  Binds completed
@@ -3465,9 +3507,12 @@ class TPUScheduler:
     def _featurize_batch(self, infos: list[QueuedPodInfo], profile: Profile) -> dict:
         """Host featurization for one batch — separable from dispatch so the
         driver can overlap featurize(k+1) with device(k).  Featurization may
-        grow vocab/schema (forcing a state rebuild at dispatch).  Always
-        pads to the full batch size: one batch shape → one XLA program.
-        The caller's span times it and sets ``feat_s``."""
+        grow vocab/schema (forcing a state rebuild at dispatch).  The host
+        arrays always have the full batch size (one batch shape → one XLA
+        program); a one-template batch is a broadcast view of its one row
+        under a fresh ``valid``, and what of it reaches the device is
+        _dispatch_pass's to decide.  The caller's span times it and sets
+        ``feat_s``."""
         # ~10% of batches record per-plugin featurize durations
         # (plugin_execution_duration_seconds, metrics.go:256).
         sample = (
@@ -3582,11 +3627,37 @@ class TPUScheduler:
     def _dispatch_pass(
         self, infos: list[QueuedPodInfo], profile: Profile, work: dict
     ) -> dict:
-        """From the featurized rows to the jitted call returning (async):
-        invariants, state flush, packing, one device_put, the call."""
+        """From the featurized rows to the jitted call returning (async),
+        as five child spans of `pass/dispatch`: `dispatch/inv` (the batch
+        invariants, or the resident copy's key check), `dispatch/state`
+        (the dirty-row flush), packing, `dispatch/put` (ONE device_put of
+        the inputs whose device copy missed), `dispatch/expand` (the
+        uniform batch's broadcast, on a miss; both in _pass_inputs) and
+        `dispatch/call`.
+
+        An input that did not change since the last dispatch is neither
+        rebuilt nor sent nor broadcast again; the device array of that
+        dispatch is passed again (builder.resident):
+
+        - the invariants (_resident_inv), under (schema, terms interned),
+          with an empty nominator and outside truncated mode;
+        - a uniform batch's broadcast feature arrays, under (the batch's
+          one _featsig, the featurization's version, the active ops, the
+          profile, the schema): the last template's;
+        - by shape alone: the identity step_offset, the all -1
+          nominated_row, the two values of a flag (uniform_all, dom_valid),
+          and the mask `arange < n` for each pod count seen (at most 128).
+
+        Each is used only where the batch's own host arrays show it is the
+        same (offsets the packer did not write, no nominated row, a prefix
+        mask), so a packed, nominated, non-uniform or pinned batch sends
+        what it always sent.  In steady state a uniform batch sends the
+        cycle counter, and a mask the first time a pod count is seen.
+        _count_pass_inputs says what crossed."""
         # Batch invariants (interned term → topo slot) may grow TK/DV: build
         # them after featurization, before the state flush.
-        inv = self._full_inv()
+        with self.span("dispatch/inv"):
+            inv, inv_d, inv_key = self._resident_inv()
         # Carried-DomTables validity must be judged BEFORE state() clears
         # the dirty flags: the carry is sound only when nothing host-side
         # mutated since it was stashed (mutation_epoch) AND no dirty rows
@@ -3598,7 +3669,8 @@ class TPUScheduler:
             and not self.builder._dirty_all
             and not self.builder._dirty_rows
         )
-        state = self.builder.state()
+        with self.span("dispatch/state"):
+            state = self.builder.state()
         # Pinned fast path (PreFilterResult node-set reduction): every pod
         # resolved to one candidate row and no active op needs the domain
         # tables ⇒ one vmapped own-row evaluation instead of the (K, N)
@@ -3670,17 +3742,11 @@ class TPUScheduler:
                 self.metrics.pack_width = plan.width
                 self.metrics.pack_classes = plan.n_classes
             pack_s = sp_p.dur_s
-        if "step_offset" not in work["batch"]:
-            # Identity offsets: ONE compiled program shape whether or not
-            # this batch was reordered.
-            work["batch"]["step_offset"] = np.arange(
-                self.batch_size, dtype=np.int32
-            )
         run = self.passes.get(
             profile, self.builder.schema, self.builder.res_col, work["active"],
             chunk, carry_dom=True,
         )
-        uniform = False
+        featsig = None
         if chunk > 1 and not self._truncated:
             # Template-batch flag for the pass's all-fail shortcut: every
             # pod featurization-identical (pass_.py uniform_all).  Pods
@@ -3691,41 +3757,17 @@ class TPUScheduler:
             }
             uniform = len(sigs) == 1
             work["batch"]["uniform_all"] = np.bool_(uniform)
-        # ONE coalesced host→device transfer for the whole input pytree:
-        # fewer, larger transfers — the ~20 feature/invariant arrays ride
-        # one batched device_put instead of each being shipped
-        # individually at the jit boundary.
-        batch_np = work["batch"]
-        if uniform:
-            # A uniform batch's feature rows are identical by the same
-            # signature equality the all-fail shortcut trusts: ship ONE
-            # representative row and broadcast on device — ~0.5MB of
-            # identical rows otherwise cross to the device every preemption/
-            # daemonset batch.  valid (padding) and nominated_row (injected
-            # post-featurize) genuinely vary per pod and ship in full.
-            bkeys = tuple(sorted(
-                kk for kk in batch_np
-                if kk not in (
-                    "valid", "nominated_row", "uniform_all", "step_offset"
-                )
-            ))
-            small = {kk: np.ascontiguousarray(batch_np[kk][:1]) for kk in bkeys}
-            small_d, valid_d, nom_d, soff_d, inv_d = jax.device_put(
-                (small, batch_np["valid"], batch_np["nominated_row"],
-                 batch_np["step_offset"], inv)
-            )
-            batch_d = _expand_uniform(
-                small_d, valid_d, nom_d, batch_np["valid"].shape[0]
-            )
-            batch_d["uniform_all"] = batch_np["uniform_all"]
-            batch_d["step_offset"] = soff_d
-        else:
-            batch_d, inv_d = jax.device_put((batch_np, inv))
-        dom_in = self._dom_carry if dom_ok else self._dom_placeholder()
-        new_state, result, dom_out = run(
-            state, batch_d, inv_d, np.uint32(cycle0), dom_in[0], dom_in[1],
-            np.bool_(dom_ok),
+            if uniform:
+                featsig = getattr(infos[0].pod, "_featsig", None)
+        batch_d, inv_d, flag = self._pass_inputs(
+            work, profile, inv, inv_d, inv_key, featsig
         )
+        dom_in = self._dom_carry if dom_ok else self._dom_placeholder()
+        with self.span("dispatch/call"):
+            new_state, result, dom_out = run(
+                state, batch_d, inv_d, np.uint32(cycle0), dom_in[0], dom_in[1],
+                flag[dom_ok],
+            )
         if dom_ok:
             self.metrics.dom_carry_hits += 1
         else:
@@ -3738,6 +3780,114 @@ class TPUScheduler:
             schema=self.builder.schema, chunk=chunk,
             cycle0=cycle0, pack_s=pack_s, dom_out=dom_out,
         )
+
+    def _pass_inputs(
+        self, work: dict, profile: Profile, inv: dict, inv_d: dict | None,
+        inv_key: tuple | None, featsig,
+    ) -> tuple[dict, dict, tuple]:
+        """The device side of a dispatch's batch and invariants, and the two
+        device values of a flag (``flag[b]`` for bool ``b``).
+
+        The pass's inputs stay on the device from one dispatch to the next
+        (builder.resident, dropped with the device mirror): of each this
+        asks "is the device's copy still this?", by a key the batch itself
+        shows, and ships in ONE device_put only what missed.  The pass
+        donates nothing, so an array may be passed any number of times;
+        what it receives is bit for bit what a full ship gives.
+        ``featsig``: the one signature of a uniform batch whose pods carry
+        one, else None.  Counts what crossed (_count_pass_inputs), the
+        cycle counter of the call included."""
+        batch_np = work["batch"]
+        k = self.batch_size
+        res = self.builder.resident
+        ship: dict = {}
+        if inv_d is None:
+            ship["inv"] = inv
+        # Constants of shape: identity offsets (ONE compiled program shape
+        # whether or not the packer reordered this batch), the all -1
+        # nominated rows, the two flags (uniform_all, dom_valid).
+        const = res.get("const")
+        if const is None:
+            ship["const"] = {
+                "step_offset": np.arange(k, dtype=np.int32),
+                "nominated_row": np.full(k, -1, np.int32),
+                "flag": (np.bool_(False), np.bool_(True)),
+            }
+        identity_soff = "step_offset" not in batch_np
+        if identity_soff:
+            batch_np["step_offset"] = np.arange(k, dtype=np.int32)
+        else:
+            ship["step_offset"] = batch_np["step_offset"]
+        nom_const = int(batch_np["nominated_row"].max()) < 0
+        if not nom_const:
+            ship["nominated_row"] = batch_np["nominated_row"]
+        # `valid` of n pods is the mask arange < n: the few masks a window
+        # uses stay resident, one a pod count.
+        valid = batch_np["valid"]
+        n_valid = int(np.count_nonzero(valid))
+        prefix = not valid[n_valid:].any()
+        masks = res.setdefault("valid", {})
+        valid_d = masks.get(n_valid) if prefix else None
+        if valid_d is None:
+            ship["valid"] = valid
+        fkeys = tuple(sorted(kk for kk in batch_np if kk not in _PER_BATCH_KEYS))
+        uniform = bool(batch_np.get("uniform_all", False))
+        feats_d = None
+        if uniform:
+            # A uniform batch's feature rows are identical by the same
+            # signature equality the all-fail shortcut trusts: ONE
+            # representative row is shipped and broadcast on the device
+            # (_expand_uniform), and the broadcast arrays stay resident for
+            # the next batch of the same template under the same
+            # vocabularies (the featurization cache's own key).
+            ukey = (
+                featsig, work["version"], work["active"], profile,
+                self.builder.schema,
+            )
+            held = res.get("uniform")
+            if featsig is not None and held is not None and held[0] == ukey:
+                feats_d = held[1]
+            else:
+                ship["small"] = {
+                    kk: np.ascontiguousarray(batch_np[kk][:1]) for kk in fkeys
+                }
+        else:
+            ship["batch"] = {kk: batch_np[kk] for kk in fkeys}
+        with self.span("dispatch/put"):
+            put = jax.device_put(ship) if ship else ship
+        if "inv" in put:
+            inv_d = put["inv"]
+            if inv_key is not None:
+                res["inv"] = (inv_key, inv, inv_d)
+        if const is None:
+            const = res["const"] = put["const"]
+        soff_d = const["step_offset"] if identity_soff else put["step_offset"]
+        nom_d = const["nominated_row"] if nom_const else put["nominated_row"]
+        if valid_d is None:
+            valid_d = put["valid"]
+            if prefix:
+                if len(masks) >= 128:
+                    masks.clear()
+                masks[n_valid] = valid_d
+        if not uniform:
+            feats_d = put["batch"]
+        elif feats_d is None:
+            with self.span("dispatch/expand"):
+                full = _expand_uniform(put["small"], valid_d, nom_d, k)
+            feats_d = {kk: full[kk] for kk in fkeys}
+            if featsig is not None:
+                res["uniform"] = (ukey, feats_d)
+        batch_d = dict(
+            feats_d, valid=valid_d, nominated_row=nom_d, step_offset=soff_d
+        )
+        if "uniform_all" in batch_np:
+            batch_d["uniform_all"] = const["flag"][uniform]
+        leaves = jax.tree_util.tree_leaves(ship)
+        self._count_pass_inputs(
+            len(batch_d) + len(inv_d) + 2, len(leaves) + 1,
+            sum(x.nbytes for x in leaves) + 4,
+        )
+        return batch_d, inv_d, const["flag"]
 
     def _schedule_infos(
         self, infos: list[QueuedPodInfo], profile: Profile | None = None
