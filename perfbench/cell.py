@@ -46,7 +46,10 @@ def cluster_pod_capacity(config: dict) -> int:
     """Pods of the configuration's templates the cluster can hold, by the
     arithmetic its file states: per node the least of cpu, memory and the
     pod limit, each resource at the larger request of the initial and the
-    measured pods' template."""
+    measured pods' template, and where the file states one a further cap a
+    node (``capacity.pods_per_node_max``: an attach limit at one volume a
+    pod, say), so that the plan never prebuilds pods the cluster has no
+    room for."""
     alloc = config["cluster"]["node_template"]["status"]["allocatable"]
     pod = config["pod"]
     req = {"cpu": 0, "memory": 0}
@@ -55,7 +58,20 @@ def cluster_pod_capacity(config: dict) -> int:
             req[k] = max(req[k], sum(int(c.get("requests", {}).get(k, 0))
                                      for c in template["spec"]["containers"]))
     per_node = min([int(alloc["pods"])] + [int(alloc[k]) // v for k, v in req.items() if v])
+    cap = (config.get("capacity") or {}).get("pods_per_node_max")
+    if cap is not None:
+        per_node = min(per_node, int(cap))
     return per_node * int(config["cluster"]["nodes"])
+
+
+def bind_echo(config: dict) -> bool:
+    """Whether the configuration states that a pod goes back bound when it
+    is answered (``pod.bind_echo``: ``"answered"``); anything else it may
+    say is refused by name, there being one shape so far."""
+    stated = config["pod"].get("bind_echo")
+    if stated not in (None, "answered"):
+        raise SystemExit(f"perfbench: pod.bind_echo: 'answered' or absent, not {stated!r}")
+    return stated is not None
 
 
 def pods_needed(config: dict, mix: dict, seconds: float, n_open: int) -> dict:
@@ -113,6 +129,9 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
         out_root: str | None = None, log=print) -> dict:
     """Returns the raw material of the result: window, flight records,
     scrapes, correctness numbers, device, trace directory."""
+    if mix["loop"] == "open" and (config["pod"].get("companions") or bind_echo(config)):
+        raise SystemExit(f"perfbench: {cell['name']}: an open mix on a configuration whose pods have companions "
+                         "or a bind echo is refused: only the closed loop sends them, until a cell needs more")
     out = os.path.join(out_root or os.path.join(root, ".perfbench_out"), cell["name"])
     shutil.rmtree(out, ignore_errors=True)
     os.makedirs(os.path.join(out, "flight"))
@@ -146,17 +165,23 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
         total = plan["initial"] + plan["warm"] + len(warm_offsets) + plan["window"]
         pods = objects.Pods(config, seed, total, plan["initial"])
         pod_json_by_uid = dict(zip(pods.uids, pods.jsons))
+        companions = objects.Companions(config, nodes, pods, plan["initial"])
 
         def hint_frame(a: int, z: int) -> bytes:
             return wire.pending_pods_frame(pods.jsons[a:z])
 
         first_window = plan["initial"] + plan["warm"] + len(warm_offsets)
         window_hint_frames = []
+        setup_groups = [(0, plan["initial"]), (plan["initial"], plan["initial"] + plan["warm"])]
+        window_companions = None
+        setup_companions = [None] * len(setup_groups)
+        echo = pods.bound_frame if bind_echo(config) else None
         if mix["loop"] == "closed":
-            window_hint_frames = [
-                hint_frame(a, a + plan["backlog"])
-                for a in range(first_window, total, plan["backlog"])
-            ]
+            starts = range(first_window, total, plan["backlog"])
+            window_hint_frames = [hint_frame(a, a + plan["backlog"]) for a in starts]
+            if companions.per_pod:
+                window_companions = [companions.frames(a, a + plan["backlog"]) for a in starts]
+                setup_companions = [[companions.frames(a, z)] for a, z in setup_groups]
         built_s = time.monotonic() - t_build
         r.srv.wait_listening(sock, 900.0)
         listening_s = time.monotonic() - t_start
@@ -167,6 +192,8 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
         if not rehearsal and (device["platform"] != "tpu" or device["count"] < cell["chips"]):
             raise NoChip(f"serve runs on {device}, the cell asks for {cell['chips']} TPU chip(s)")
         r.conn.add_many("Node", nodes.jsons)
+        for kind, jsons in companions.of_nodes:  # right after the nodes, in the nodes' order
+            r.conn.add_many(kind, jsons)
         nodes_s = time.monotonic() - t_start
         r.push = wire.PushMap(sock)
         asked: dict[str, str] = {}
@@ -181,11 +208,16 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
         # Set-up traffic: the configuration's initial pods, then a warm-up
         # that uses every program of the window twice, over the same calls.
         cursor = 0
-        for count in (plan["initial"], plan["warm"]):
-            if count:
-                take(loops.closed_loop(conn, r.push, pods, [hint_frame(cursor, cursor + count)],
-                                       cursor, count, float("inf"), max_backlogs=1))
-                cursor += count
+        sent_setup = {"objects": 0, "s": 0.0, "echoes": 0}
+        for (a, z), group_companions in zip(setup_groups, setup_companions):
+            if z > a:
+                ws = loops.closed_loop(conn, r.push, pods, [hint_frame(a, z)], a, z - a, float("inf"),
+                                       max_backlogs=1, companions=group_companions, echo=echo)
+                take(ws)
+                sent_setup["objects"] += ws.companion_objects
+                sent_setup["s"] += ws.companion_s
+                sent_setup["echoes"] += ws.echo_objects
+                cursor = z
         if warm_offsets:
             take(loops.open_loop(conn, r.push, pods, cursor, warm_offsets, hint_frame,
                                  float(mix["hint_flush_delay_s"])))
@@ -227,7 +259,8 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
         wall_open = time.time()
         if mix["loop"] == "closed":
             w = loops.closed_loop(conn, r.push, pods, window_hint_frames, first_window,
-                                  plan["backlog"], seconds, on_boundary=on_boundary)
+                                  plan["backlog"], seconds, on_boundary=on_boundary,
+                                  companions=window_companions, echo=echo)
         else:
             w = loops.open_loop(conn, r.push, pods, first_window, offsets, hint_frame,
                                 float(mix["hint_flush_delay_s"]), on_boundary=on_boundary)
@@ -273,7 +306,7 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
         reader.start()
         try:
             verdict = correct.compare(config, nodes.jsons, nodes.names, pod_json_by_uid,
-                                      list(commit_order), asked, measured, journal)
+                                      list(commit_order), asked, measured, journal, companions)
         finally:
             reader.join()
         recovered = read_back.get("bindings")
@@ -289,6 +322,8 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
             "trace_marks": marks if traced else None,  # the serving process's clock around start and stop
             "wall_open": wall_open, "wall_close": wall_close, "serve_rc": rc,
             "out": out, "plan": plan, "config": config, "mix": mix, "cell": cell,
+            "companions": {"of_nodes": companions.node_objects, "setup": sent_setup["objects"],
+                           "setup_s": sent_setup["s"], "setup_echoes": sent_setup["echoes"]},
             "seconds": seconds, "push": {"frames": r.push.frames, "invalidations": r.push.invalidations,
                                          "decided": len(commit_order)},
         }

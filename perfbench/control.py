@@ -27,10 +27,16 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 
-def answers(config: dict, mix: dict, seed: int, window_pods: int, stale: int,
-            drop_affinity: bool = False, wander: float = 0.0, wander_to: str = "random"):
-    """(node jsons, node names, {uid: json}, commit order, asked, measured)
-    of the reference standing in for the program over the set-up's pods and
+def answers(*args, **how):
+    """``stand_in`` without the companions: what a configuration that has
+    none needs for ``correct.compare``."""
+    return stand_in(*args, **how)[:6]
+
+
+def stand_in(config: dict, mix: dict, seed: int, window_pods: int, stale: int,
+             drop_affinity: bool = False, wander: float = 0.0, wander_to: str = "random"):
+    """(node jsons, node names, {uid: json}, commit order, asked, measured,
+    companions) of the reference standing in for the program over the set-up's pods and
     ``window_pods`` more."""
     from perfbench import cell, correct, objects
 
@@ -39,8 +45,9 @@ def answers(config: dict, mix: dict, seed: int, window_pods: int, stale: int,
     plan = cell.pods_needed(config, mix, 0.0, 0)
     setup = plan["initial"] + plan["warm"]
     pods = objects.Pods(config, seed, setup + window_pods, plan["initial"])
-    cluster = ref.Cluster(nodes.jsons, nodes.names)
-    stream = [(uid, ref.pod_facts(raw)) for uid, raw in zip(pods.uids, pods.jsons)]
+    companions = objects.Companions(config, nodes, pods, plan["initial"])
+    cluster, facts = correct.stand_up(ref, nodes.jsons, nodes.names, companions)
+    stream = [(uid, facts(uid, raw)) for uid, raw in zip(pods.uids, pods.jsons)]
     # the set-up is placed soundly: the control breaks the window
     chunk = int(config["serve"]["chunk_size"])
     order = ref.place(cluster, stream[:setup], chunk, seed)
@@ -48,7 +55,7 @@ def answers(config: dict, mix: dict, seed: int, window_pods: int, stale: int,
                        wander=wander, wander_to=wander_to)
     asked = dict(order)
     measured = set(pods.uids[setup:])
-    return nodes.jsons, nodes.names, dict(zip(pods.uids, pods.jsons)), order, asked, measured
+    return nodes.jsons, nodes.names, dict(zip(pods.uids, pods.jsons)), order, asked, measured, companions
 
 
 def main(argv=None) -> int:
@@ -66,10 +73,10 @@ def main(argv=None) -> int:
 
     bench = spec.load(args.bench)
     _, config, mix = spec.cell(bench, args.workload)
-    node_jsons, names, by_uid, order, asked, measured = answers(
+    node_jsons, names, by_uid, order, asked, measured, companions = stand_in(
         config, mix, args.seed, args.pods, args.stale, wander=args.wander, wander_to=args.wander_to)
     # the control's journal is its own answers: durability is not what it breaks
-    res = correct.compare(config, node_jsons, names, by_uid, order, asked, measured, dict(asked))
+    res = correct.compare(config, node_jsons, names, by_uid, order, asked, measured, dict(asked), companions)
     for name, v in res["numbers"].items():
         print(f"control: compared {name} = {v['value']} (limit {v['limit']})", file=sys.stderr)
     print(json.dumps({"control": args.workload, "seed": args.seed, "stale": args.stale,

@@ -8,6 +8,14 @@ the batch it started), and what ``recover`` reads back out of the journal
 once the server has stopped.  The plain reference named by the
 configuration replays the commits on its own cluster.
 
+A reference module that defines ``COMPANION_KINDS`` (a tuple of kinds) is
+given the configuration's companion objects of those kinds
+(objects.Companions): the nodes' when its cluster is built, a pod's own
+beside the pod's JSON.  A module without the name is called as it always
+was.  What such a reference holds the answers to (an attach limit, a claim
+bound to a volume that exists) it adds to ``over_capacity`` and
+``infeasible``, as its configuration's guarantees state.
+
 Each number has a limit of its own; exact comparisons have the limit 0,
 ``score_gap_mean`` has the limit the configuration file carries, set from
 chip readings as PERF.md records.
@@ -26,17 +34,38 @@ def load_reference(name: str):
                                  "perfbench_reference_" + name)
 
 
+def stand_up(ref, node_jsons, node_names, companions=None):
+    """(the reference's cluster, facts(uid, raw)): the one place that knows
+    which references take companions.  ``companions``: objects.Companions
+    of the run, or None where the configuration has none."""
+    kinds = getattr(ref, "COMPANION_KINDS", None)
+    if kinds is None:
+        return ref.Cluster(node_jsons, node_names), lambda uid, raw: ref.pod_facts(raw)
+    of_nodes = {kind: [] for kind in kinds}
+    for kind, jsons in (companions.of_nodes if companions is not None else ()):
+        if kind in of_nodes:
+            of_nodes[kind].extend(jsons)
+
+    def facts(uid, raw):
+        own = companions.of_uid(uid) if companions is not None else {}
+        return ref.pod_facts(raw, {kind: own.get(kind, []) for kind in kinds})
+
+    return ref.Cluster(node_jsons, node_names, companions=of_nodes), facts
+
+
 def compare(config: dict, node_jsons, node_names, pod_json_by_uid: dict,
-            commit_order, asked: dict, measured: set, recovered: dict | None) -> dict:
+            commit_order, asked: dict, measured: set, recovered: dict | None,
+            companions=None) -> dict:
     """``asked``: uid -> node for every pod this run asked for ("" = came
     back without one).  ``commit_order``: [(uid, node)] as committed.
     ``measured``: uids due in the window (their gaps are the ones
     averaged).  ``recovered``: uid -> node out of the journal, or None if
     it could not be read, or a call that gives either once the replay is
-    done (the run reads the journal back meanwhile).  Returns {"numbers": {name: {"value", "limit"}}
+    done (the run reads the journal back meanwhile).  ``companions``: the
+    run's objects.Companions, for a reference that asks for them.  Returns {"numbers": {name: {"value", "limit"}}
     in the order they are printed, "info": what a reader wants beside them}."""
     ref = load_reference(config["reference"])
-    cluster = ref.Cluster(node_jsons, node_names)
+    cluster, facts = stand_up(ref, node_jsons, node_names, companions)
     replay = ref.Replay(cluster)
     seen: dict[str, str] = {}
     conflicts = 0
@@ -51,7 +80,7 @@ def compare(config: dict, node_jsons, node_names, pod_json_by_uid: dict,
         if raw is None:
             replay.unknown_node += 1
             continue
-        replay.step(uid, node, ref.pod_facts(raw), uid in measured)
+        replay.step(uid, node, facts(uid, raw), uid in measured)
     unanswered = sum(1 for node in asked.values() if not node)
     conflicts += sum(1 for uid, node in asked.items() if node and seen.get(uid, node) != node)
     never_committed = sum(1 for uid, node in asked.items() if node and uid not in seen)

@@ -14,7 +14,26 @@ The window rules live here and nowhere else:
           backlogs, the same mix of full and short batches whatever its
           length.  The window's end is the time of the last answer, and
           the rate is every pod answered with a node over that whole
-          length: stalls included, no median of pieces.  A run prebuilds
+          length: stalls included, no median of pieces.  Where the
+          configuration gives its pods companions (objects.Companions:
+          upstream creates a pod's claim and volume inside the measured
+          createPods op, and an operator's job brings its claims with
+          it), a backlog's companions go out as pipelined adds
+          immediately before its hint frame, inside the window: it opens
+          at the first companion frame of the first measured backlog.
+          Where the configuration says so (``pod.bind_echo``), every
+          pod goes back bound the moment it is answered (objects.Pods.
+          bound_frame), as the plugin forwards the informer's update of a
+          pod the host scheduler has bound (go/tpubatchscore/plugin.go,
+          upsertPod: one AddObject a pod on the request connection): the
+          frame is posted, not waited for, so the sidecar takes the
+          echoes while the loop goes on answering, and the window closes
+          at the last answer as in every cell.  (The sidecar rolls back
+          every decision it does not know to be bound when an object
+          arrives that the decision depends on, as the next backlog's
+          claims and volumes do; a deployment's binds are long confirmed
+          by then.  The accepted configurations state no echo and send
+          none: PERF.md section 8.)  A run prebuilds
           its pods; a system so fast that they are all answered before
           ``seconds`` closes its window there, early, and the window says
           so (``short``): the result line carries it, not a traceback.
@@ -48,6 +67,10 @@ class Window:
     wire_s: float = 0.0
     hint_frames: int = 0
     hint_s: float = 0.0
+    companion_objects: int = 0  # closed loop: the pods' companions sent inside the window
+    companion_s: float = 0.0  # ... and the time their pipelined adds took
+    echo_objects: int = 0  # closed loop, where the configuration states them: bind echoes posted inside the window
+    echo_s: float = 0.0  # ... and the loop's own time in making and posting them
     first: int = 0  # index of the first pod of the window
     nodes: list = field(default_factory=list)  # node per asked pod, in order
     answer_t: list = field(default_factory=list)  # clock at each answer
@@ -63,10 +86,14 @@ class Window:
 
 def closed_loop(conn, push, pods, hint_frames, first: int, backlog: int,
                 seconds: float, clock=time.perf_counter, on_boundary=None,
-                max_backlogs: int | None = None) -> Window:
+                max_backlogs: int | None = None, companions=None, echo=None) -> Window:
     """Backlogs of ``backlog`` pods from ``pods[first:]`` until the window
     rule closes it.  ``hint_frames[b]`` is the prebuilt PendingPods frame
-    of the b-th backlog.  ``on_boundary(elapsed, first)`` runs at every
+    of the b-th backlog, ``companions[b]`` (where given) the prebuilt
+    AddObject frames of its pods' companions and their count, sent just
+    before it, ``echo(k, node)`` (where given) what makes the bind echo
+    of pod ``k``, posted as soon as the pod is answered with a node.
+    ``on_boundary(elapsed, first)`` runs at every
     batch boundary, just before the wire call that starts a batch;
     ``first`` says that the call is the first after its backlog's hint
     frame, when nothing of the backlog is on the device yet (the traced
@@ -89,6 +116,11 @@ def closed_loop(conn, push, pods, hint_frames, first: int, backlog: int,
             w.short = (f"every prebuilt pod ({i - first}) was answered {now - t_open:.3f} s into a "
                        f"window of {seconds} s: it closed there, early")
             break
+        if companions is not None and companions[b][1]:
+            t0 = clock()
+            conn.call_many(*companions[b])
+            w.companion_s += clock() - t0
+            w.companion_objects += companions[b][1]
         t0 = clock()
         conn.call_raw(hint_frames[b])
         w.hint_s += clock() - t0
@@ -117,6 +149,11 @@ def closed_loop(conn, push, pods, hint_frames, first: int, backlog: int,
                 last = clock()
             nodes.append(node)
             answer_t.append(last)
+            if echo is not None and node:
+                t0 = clock()
+                conn.post(echo(k, node))
+                w.echo_objects += 1
+                w.echo_s += clock() - t0
         i += backlog
         if max_backlogs is not None and b >= max_backlogs:
             break

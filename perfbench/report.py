@@ -18,7 +18,12 @@ def load_reader(home: str, name: str):
 
 class Ctx:
     """What a metric's reader may read.  ``window`` is the client's side
-    (loops.Window), ``records`` the flight recorder's per-batch records of
+    (loops.Window: among the rest ``companion_objects`` and
+    ``companion_s``, the pods' companion objects sent inside the window and
+    the seconds their adds took, ``echo_objects`` and ``echo_s``, the bind
+    echoes posted inside it and the loop's own seconds in making and
+    posting them, beside ``hint_frames`` and ``hint_s``),
+    ``records`` the flight recorder's per-batch records of
     the window (of a traced run: those closed before the profiler's stop
     began; ``window_records`` has them all, for a reader that divides a
     counter of the whole window), ``before``/``after`` the metrics frame
@@ -97,6 +102,12 @@ def build(bench: dict, raw: dict, traced: bool, rehearsal: bool, rate) -> tuple[
         result["study_rate_pods_per_s"] = rate
     if w.short:
         result["window_short"] = w.short
+    # every object beside nodes and pods that the run sent (the nodes'
+    # companions, the set-up pods', the window's), and the seconds the
+    # window's took
+    sent = raw.get("companions") or {}
+    result["companion_objects"] = sent.get("of_nodes", 0) + sent.get("setup", 0) + w.companion_objects
+    result["companion_s"] = w.companion_s
     result["compared"] = numbers
 
     recs = raw["records"]
@@ -104,7 +115,10 @@ def build(bench: dict, raw: dict, traced: bool, rehearsal: bool, rate) -> tuple[
     summary = {
         "cell": cell["name"], "seconds_asked": raw["seconds"], "window_s": w.seconds,
         "pods_asked": w.asked, "pods_bound": w.bound, "local_hits": w.hits, "wire_misses": w.misses,
-        "hint_frames": w.hint_frames, "wire_s": w.wire_s,
+        "hint_frames": w.hint_frames, "hint_s": w.hint_s, "wire_s": w.wire_s,
+        "companion_objects": result["companion_objects"], "companion_s": w.companion_s,
+        "companions": dict(sent, window=w.companion_objects, window_s=w.companion_s,
+                           window_echoes=w.echo_objects, window_echo_s=w.echo_s),
         "setup_s": raw["setup_s"], "listening_s": raw["listening_s"], "nodes_added_s": raw["nodes_s"],
         "objects_built_s": raw.get("built_s"), "plan": raw.get("plan"), "window_short": w.short,
         "batches": len(recs),

@@ -228,7 +228,7 @@ def analyse(out_dir: str, rehearsal: bool = False) -> dict:
         "host_events": ev["host_events"], "span_events": len(spans), "device_ops": len(ops),
         "window_s": t1 - t0, "busy_s": busy_s, "idle_s": idle_s,
         "idle_by_span": dict(sorted(idle.items(), key=lambda kv: -kv[1])),
-        "idle_named_share": 100.0 * (idle_s - idle.get(trace.NO_SPAN, 0.0)) / idle_s if idle_s else None,
+        "idle_named_share": 100.0 * ((idle_s - idle.get(trace.NO_SPAN, 0.0)) / idle_s) if idle_s else None,
         "device_s_by_stage": dict(sorted(stages.items(), key=lambda kv: -kv[1])),
         "drain_s": drain_s,
         "drain_overlapped_share": 100.0 * overlap_s(drain, busy) / drain_s if drain_s else None,

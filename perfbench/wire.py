@@ -92,14 +92,22 @@ def read_envelope(sock: socket.socket):
 
 class Conn:
     """One request/response connection.  Frames built ahead of time carry
-    seq 0, which the server echoes; calls are strictly one at a time."""
+    seq 0, which the server echoes; the server answers in the order it was
+    asked.  Calls are one at a time, but for ``post``: a frame written now
+    whose acknowledgement is read later, by the next posts as they write or
+    by the next call before it sends."""
 
     def __init__(self, path: str, timeout_s: float = 300.0):
         self.sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
         self.sock.settimeout(timeout_s)
         self.sock.connect(path)
+        self._owed = 0  # posted frames whose acknowledgement has not been read
+        self._inbuf = bytearray()  # the part of an acknowledgement an earlier read left
+        self._errors: list[str] = []
 
     def call_raw(self, data: bytes):
+        if self._owed or self._errors:  # what was posted is settled first
+            self.call_many(b"", 0)
         self.sock.sendall(data)
         env = read_envelope(self.sock)
         if env.response.error:
@@ -133,13 +141,44 @@ class Conn:
         return results[0].node_name if results else ""
 
     def add_many(self, kind: str, object_jsons) -> None:
-        """Pipelined adds (the informer's initial list): write while
-        draining acks, so neither side's socket buffer fills."""
-        data = memoryview(b"".join(add_frame(kind, j) for j in object_jsons))
-        want = len(object_jsons)
+        """Pipelined adds (the informer's initial list)."""
+        self.call_many(b"".join(add_frame(kind, j) for j in object_jsons), len(object_jsons))
+
+    def _acks(self) -> int:
+        """Reads what the socket holds, if anything (the caller has it
+        non-blocking), and counts the whole acknowledgements in it; their
+        errors are kept."""
+        try:
+            chunk = self.sock.recv(1 << 16)
+        except BlockingIOError:
+            return 0
+        if not chunk:
+            raise ConnectionError("sidecar closed the connection")
+        buf = self._inbuf
+        buf += chunk
+        off = got = 0
+        while len(buf) - off >= 4:
+            (n,) = _LEN.unpack_from(buf, off)
+            if len(buf) - off - 4 < n:
+                break
+            env = _pb().Envelope()
+            env.ParseFromString(bytes(buf[off + 4: off + 4 + n]))
+            if env.response.error:
+                self._errors.append(env.response.error)
+            got += 1
+            off += 4 + n
+        del buf[:off]
+        return got
+
+    def call_many(self, frames: bytes, want: int) -> None:
+        """``want`` prebuilt AddObject frames, of any kinds, pipelined in
+        the order given: write while draining acks, so neither side's
+        socket buffer fills.  Returns once they and every frame posted
+        before them are acknowledged."""
+        data = memoryview(frames)
+        want += self._owed
+        self._owed = 0
         got = 0
-        errors: list[str] = []
-        inbuf = bytearray()
         sock = self.sock
         sock.setblocking(False)
         try:
@@ -153,29 +192,36 @@ class Conn:
                     except BlockingIOError:
                         pass
                 if rl:
-                    try:
-                        chunk = sock.recv(1 << 16)
-                    except BlockingIOError:
-                        continue
-                    if not chunk:
-                        raise ConnectionError("sidecar closed the connection")
-                    inbuf += chunk
-                    off = 0
-                    while len(inbuf) - off >= 4:
-                        (n,) = _LEN.unpack_from(inbuf, off)
-                        if len(inbuf) - off - 4 < n:
-                            break
-                        env = _pb().Envelope()
-                        env.ParseFromString(bytes(inbuf[off + 4: off + 4 + n]))
-                        if env.response.error:
-                            errors.append(env.response.error)
-                        got += 1
-                        off += 4 + n
-                    del inbuf[:off]
+                    got += self._acks()
         finally:
             sock.settimeout(300.0)
-        if errors:
-            raise RuntimeError(f"{len(errors)} of {want} adds failed; first: {errors[0]}")
+        if self._errors:
+            errors, self._errors = self._errors, []
+            raise RuntimeError(f"{len(errors)} adds failed ({want} were waited for); first: {errors[0]}")
+
+    def post(self, data: bytes) -> None:
+        """One prebuilt AddObject frame, written now and not waited for:
+        its acknowledgement is read while later posts write, or by the
+        next call on this connection before it sends (the server takes a
+        connection's frames in order, so nothing overtakes it)."""
+        self._owed += 1
+        view = memoryview(data)
+        sock = self.sock
+        sock.setblocking(False)
+        try:
+            while True:
+                try:
+                    view = view[sock.send(view):]
+                except BlockingIOError:
+                    pass
+                self._owed -= self._acks()
+                if not view:
+                    return
+                rl, wl, _ = select.select([sock], [sock], [], 300.0)
+                if not rl and not wl:
+                    raise TimeoutError("sidecar stopped reading adds")
+        finally:
+            sock.settimeout(300.0)
 
     def close(self) -> None:
         self.sock.close()

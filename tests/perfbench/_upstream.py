@@ -6,7 +6,16 @@ configurations may share one file), upstream's node template, and one pod
 template for each ``createPods`` op of the row.  It then compares the
 wire's canonical JSON with them field by field.  A field of an upstream
 template, or of the wire's pod, that it does not know how to compare
-fails it, so a later row cannot slip one past.
+fails it, so a later row cannot slip one past; so does an opcode or an
+op's key it does not know, by name.  Beside nodes and pods it knows the
+row's *companion objects*: a ``nodeAllocatableStrategy`` on the createNodes
+op (counts added to the node's allocatable, and a CSINode a node:
+``cluster.companions``), and a ``persistentVolumeTemplatePath`` with a
+``persistentVolumeClaimTemplatePath`` on the measured createPods op (a
+claim and a volume a pod, ``pod.companions``: access modes, capacity and
+request in bytes, the CSI driver, the binding both ways, the pod's volume
+naming its claim).  What upstream writes and the wire has no field for has
+to stand in the configuration's ``assumed.no_wire_field``, with the reason.
 
 ``spreading_row`` writes a configuration in the shape of upstream's
 TopologySpreading row (two pod templates, a label strategy of three
@@ -91,8 +100,10 @@ def _hold_spread(constraints: list, wire_constraints: list) -> None:
         }
 
 
-def hold_pod(pod: dict, wire_pod: dict, spaces) -> None:
-    """One upstream pod template against one wire template."""
+def hold_pod(pod: dict, wire_pod: dict, spaces, volumes=()) -> None:
+    """One upstream pod template against one wire template.  ``volumes``:
+    what the wire's pod has to carry beyond the template, where the op
+    that creates it adds a volume to every pod."""
     assert set(pod) == {"apiVersion", "kind", "metadata", "spec"} and pod["kind"] == "Pod"
     assert set(pod["metadata"]) <= {"generateName", "labels"}, sorted(pod["metadata"])
     assert wire_pod["metadata"] == {"annotations": {}, "labels": pod["metadata"].get("labels", {}),
@@ -101,7 +112,7 @@ def hold_pod(pod: dict, wire_pod: dict, spaces) -> None:
     spec, wire_spec = pod["spec"], wire_pod["spec"]
     assert set(spec) <= {"containers", "affinity", "topologySpreadConstraints"}, sorted(spec)
     compared = {"containers", "affinity", "topology_spread_constraints"}
-    assert {k: v for k, v in wire_spec.items() if k not in compared} == POD_SPEC_DEFAULTS
+    assert {k: v for k, v in wire_spec.items() if k not in compared} == dict(POD_SPEC_DEFAULTS, volumes=list(volumes))
     assert compared <= set(wire_spec)
     (cont,), (wcont,) = spec["containers"], wire_spec["containers"]
     assert set(cont) == {"image", "name", "ports", "resources"}, sorted(cont)
@@ -123,7 +134,111 @@ def hold_pod(pod: dict, wire_pod: dict, spaces) -> None:
     _hold_spread(spec.get("topologySpreadConstraints", []), wire_spec["topology_spread_constraints"])
 
 
-def _hold_nodes(doc: dict, home: str, op: dict) -> None:
+def _no_wire_field(doc: dict, found: set) -> None:
+    """Fields upstream writes for which the wire's object has no field: the
+    configuration's ``assumed.no_wire_field`` names each, and says why."""
+    said = doc["assumed"].get("no_wire_field", {})
+    assert set(said) == found and all(said.values()), f"assumed.no_wire_field: {sorted(said)} != {sorted(found)}"
+
+
+def _hold_allocatable(doc: dict, strategy: dict | None, cap: dict) -> set:
+    """The createNodes op's nodeAllocatableStrategy: ``nodeAllocatable`` (counts
+    added to the node's allocatable) and ``csiNodeAllocatable`` (a CSINode a
+    node: ``cluster.companions``' template).  Returns the fields met for
+    which the wire has none."""
+    up, wire_node = doc["upstream"], doc["cluster"]["node_template"]
+    csinodes = [c for c in doc["cluster"].get("companions", ()) if c["kind"] == "CSINode"]
+    assert len(csinodes) == len(doc["cluster"].get("companions", ())), "cluster.companions: a kind no strategy gives"
+    if not strategy:
+        assert wire_node["status"]["allocatable"] == cap and not csinodes and "node_allocatable" not in up
+        return set()
+    unknown = set(strategy) - {"nodeAllocatable", "csiNodeAllocatable", "migratedPlugins"}
+    assert not unknown, f"nodeAllocatableStrategy: {sorted(unknown)}"
+    assert up["node_allocatable"] == strategy, "upstream.node_allocatable"
+    extra = {k: int(v) for k, v in strategy.get("nodeAllocatable", {}).items()}
+    assert wire_node["status"]["allocatable"] == dict(cap, **extra), f"nodeAllocatable: {extra}"
+    limits = {}
+    for driver, alloc in strategy.get("csiNodeAllocatable", {}).items():
+        assert set(alloc) == {"count"}, f"csiNodeAllocatable.{driver}: {sorted(alloc)}"
+        limits[driver] = int(alloc["count"])
+    if limits:
+        (csinode,) = csinodes
+        assert csinode["template"] == {"name": "{name}", "driver_limits": limits}, \
+            f"csiNodeAllocatable: {limits} != {csinode['template']}"
+    else:
+        assert not csinodes
+    return {"migratedPlugins"} & set(strategy)
+
+
+def _hold_volumes(doc: dict, home: str, op: dict, spaces) -> tuple[list, set]:
+    """The measured createPods op's persistentVolumeTemplatePath and
+    persistentVolumeClaimTemplatePath against ``pod.companions``: a claim
+    and a volume for every measured pod, bound to each other before the pod
+    exists, and the pod's one volume naming the claim.  Returns the
+    volumes the wire's pod has to carry and the fields met for which the
+    wire has none."""
+    up, pod = doc["upstream"], doc["pod"]
+    paths = {k: op.get(k) for k in ("persistentVolumeTemplatePath", "persistentVolumeClaimTemplatePath")}
+    companions = pod.get("companions", [])
+    if not any(paths.values()):
+        assert not companions and not {"pv_template", "pvc_template"} & set(up)
+        return [], set()
+    assert all(paths.values()), f"one of {sorted(paths)} without the other"
+    assert os.path.basename(up["pv_template"]) == os.path.basename(paths["persistentVolumeTemplatePath"])
+    assert os.path.basename(up["pvc_template"]) == os.path.basename(paths["persistentVolumeClaimTemplatePath"])
+    assert sorted(c["kind"] for c in companions) == ["PersistentVolume", "PersistentVolumeClaim"]
+    assert all(c["of"] == "measured" and set(c) == {"kind", "template", "of"} for c in companions), \
+        "pod.companions: of the measured pods, whose op names the templates"
+    wire = {c["kind"]: c["template"] for c in companions}
+    wire_pv, wire_pvc = wire["PersistentVolume"], wire["PersistentVolumeClaim"]
+    pv, pvc = _yaml(home, up["pv_template"]), _yaml(home, up["pvc_template"])
+    missing = set()
+    # the volume
+    assert set(pv) <= {"apiVersion", "kind", "metadata", "spec"} and pv["kind"] == "PersistentVolume"
+    assert set(pv.get("metadata", {})) <= {"name"}, sorted(pv["metadata"])
+    unknown = set(pv["spec"]) - {"accessModes", "capacity", "csi", "persistentVolumeReclaimPolicy", "storageClassName"}
+    assert not unknown, f"PersistentVolume.spec: {sorted(unknown)}"
+    missing |= {"persistentVolumeReclaimPolicy"} & set(pv["spec"])
+    assert set(pv["spec"]["capacity"]) == {"storage"} and set(pv["spec"]["csi"]) == {"driver"}
+    assert wire_pv["csi_driver"] == pv["spec"]["csi"]["driver"], f"csi.driver: {wire_pv['csi_driver']}"
+    assert wire_pv["capacity"] == quantity(pv["spec"]["capacity"]["storage"]), "capacity.storage"
+    assert wire_pv["access_modes"] == pv["spec"]["accessModes"], "PersistentVolume accessModes"
+    # the claim
+    assert set(pvc) <= {"apiVersion", "kind", "metadata", "spec"} and pvc["kind"] == "PersistentVolumeClaim"
+    assert set(pvc.get("metadata", {})) <= {"name", "annotations"}, sorted(pvc["metadata"])
+    missing |= {"annotations"} & set(pvc.get("metadata", {}))
+    unknown = set(pvc["spec"]) - {"accessModes", "resources", "storageClassName"}
+    assert not unknown, f"PersistentVolumeClaim.spec: {sorted(unknown)}"
+    assert pvc["spec"]["resources"] == {"requests": {"storage": pvc["spec"]["resources"]["requests"]["storage"]}}
+    assert wire_pvc["request"] == quantity(pvc["spec"]["resources"]["requests"]["storage"]), \
+        f"resources.requests.storage: {wire_pvc['request']}"
+    assert wire_pvc["access_modes"] == pvc["spec"]["accessModes"], "PersistentVolumeClaim accessModes"
+    assert wire_pvc["request"] <= wire_pv["capacity"] and set(wire_pvc["access_modes"]) <= set(wire_pv["access_modes"])
+    # names of the pod's own, and the binding both ways
+    for which in (wire_pv, wire_pvc):
+        assert "{name}" in which["name"], f"{which['name']}: one for each pod"
+    assert wire_pvc["namespace"] == "{namespace}"
+    assert wire_pv["claim_ref"] == "{namespace}/" + wire_pvc["name"], f"claim_ref: {wire_pv['claim_ref']}"
+    assert wire_pvc["volume_name"] == wire_pv["name"], f"volume_name: {wire_pvc['volume_name']}"
+    assert wire_pv == {"name": wire_pv["name"], "capacity": wire_pv["capacity"], "access_modes": wire_pv["access_modes"],
+                       "storage_class": pv["spec"].get("storageClassName", ""), "node_affinity": None, "labels": {},
+                       "claim_ref": wire_pv["claim_ref"], "csi_driver": wire_pv["csi_driver"]}
+    assert wire_pvc == {"name": wire_pvc["name"], "namespace": "{namespace}", "request": wire_pvc["request"],
+                        "storage_class": pvc["spec"].get("storageClassName", ""),
+                        "access_modes": wire_pvc["access_modes"], "volume_name": wire_pv["name"]}
+    # what CreatePodWithPersistentVolume gives every pod: one volume, naming its claim
+    return [{"name": "vol", "pvc": wire_pvc["name"], "device_id": "", "read_only": False}], missing
+
+
+OP_KEYS = {
+    "createNodes": {"opcode", "countParam", "nodeTemplatePath", "labelNodePrepareStrategy", "nodeAllocatableStrategy"},
+    "createNamespaces": {"opcode", "prefix", "count"},
+    "createPods": {"opcode", "countParam", "podTemplatePath", "namespace", "collectMetrics",
+                   "persistentVolumeTemplatePath", "persistentVolumeClaimTemplatePath"},
+}
+
+
+def _hold_nodes(doc: dict, home: str, op: dict) -> set:
     """Upstream's node template, with the labels the row's createNodes op adds."""
     up = doc["upstream"]
     assert os.path.basename(op.get("nodeTemplatePath", "config/node-default.yaml")) == \
@@ -131,7 +246,8 @@ def _hold_nodes(doc: dict, home: str, op: dict) -> None:
     node = _yaml(home, up["node_template"])
     wire_node = doc["cluster"]["node_template"]
     cap = {k: (int(v) if k == "pods" else quantity(v)) for k, v in node["status"]["capacity"].items()}
-    assert wire_node["status"]["capacity"] == cap == wire_node["status"]["allocatable"]
+    assert wire_node["status"]["capacity"] == cap
+    missing = _hold_allocatable(doc, op.get("nodeAllocatableStrategy"), cap)
     strategy = op.get("labelNodePrepareStrategy")
     labels, cycles = {}, {}
     if strategy and len(strategy["labelValues"]) == 1:
@@ -145,6 +261,7 @@ def _hold_nodes(doc: dict, home: str, op: dict) -> None:
         cycles = {label[1:-1]: {"values": list(strategy["labelValues"])}}
     assert wire_node["metadata"]["labels"] == labels == up.get("node_labels", {})
     assert doc["cluster"]["cycles"] == cycles
+    return missing
 
 
 def hold(doc: dict, home: str, entry: dict | None = None) -> None:
@@ -163,8 +280,12 @@ def hold(doc: dict, home: str, entry: dict | None = None) -> None:
     assert (doc["cluster"]["nodes"], doc["initial_pods"], doc["measure_pods"]) == \
         (row["params"]["initNodes"], row["params"]["initPods"], row["params"]["measurePods"])
     ops = case["workloadTemplate"]
+    for op in ops:
+        assert op["opcode"] in OP_KEYS, f"opcode {op['opcode']}"
+        unknown = set(op) - OP_KEYS[op["opcode"]]
+        assert not unknown, f"{op['opcode']}: {sorted(unknown)}"
     (nodes_op,) = [op for op in ops if op["opcode"] == "createNodes"]
-    _hold_nodes(doc, home, nodes_op)
+    missing = _hold_nodes(doc, home, nodes_op)
     # pods: a template for each createPods op, the op's own or the case's
     # default; the op that collects metrics is the measured one, the one
     # before it the initial pods'
@@ -177,8 +298,18 @@ def hold(doc: dict, home: str, entry: dict | None = None) -> None:
     assert [pod["namespaces"]["initial"], pod["namespaces"]["measured"]] == spaces
     assert not pod["cycles"]
     assert os.path.basename(up["pod_template"]) == paths[1]
-    hold_pod(_yaml(home, up["pod_template"]), pod["template"], spaces)
-    if paths[0] == paths[1]:
+    assert not {"persistentVolumeTemplatePath", "persistentVolumeClaimTemplatePath"} & set(creates[0]), \
+        "persistentVolumeTemplatePath on the initial pods' op"
+    volumes, more = _hold_volumes(doc, home, measured, spaces)
+    _no_wire_field(doc, missing | more)
+    hold_pod(_yaml(home, up["pod_template"]), pod["template"], spaces, volumes)
+    if paths[0] == paths[1] and volumes:
+        # one template upstream, two on the wire: the initial pods' is the
+        # measured pods' without the volume the op adds
+        bare = dict(pod["template"], spec=dict(pod["template"]["spec"], volumes=[]))
+        assert pod.get("initial_template") == bare, "pod.initial_template: the initial pods carry no volume"
+        assert "initial_pod_template" not in up
+    elif paths[0] == paths[1]:
         assert "initial_template" not in pod and "initial_pod_template" not in up
     else:
         assert "initial_template" in pod and os.path.basename(up["initial_pod_template"]) == paths[0]

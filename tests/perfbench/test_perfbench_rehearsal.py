@@ -24,7 +24,9 @@ def test_rehearsal_of_each_cell(cell, tmp_path):
     res = json.loads(out[-1])
     keys = set(res)
     assert _pb.RESULT_KEYS <= keys
-    assert keys - _pb.RESULT_KEYS <= {"breakdown", "rehearsal", "compared"}
+    assert keys - _pb.RESULT_KEYS <= {"breakdown", "rehearsal", "compared", "companion_objects", "companion_s"}
+    # an accepted configuration has no companion objects and sends none
+    assert (res["companion_objects"], res["companion_s"]) == (0, 0.0)
     assert list(res)[-1] == "compared"  # the numbers compared come last
     assert "not a chip run" in res["rehearsal"]
     assert res["device"]["platform"] == "cpu"
@@ -39,6 +41,8 @@ def test_rehearsal_of_each_cell(cell, tmp_path):
     assert timeline["batches"] > 0
     assert timeline["compiled_in_window"] == 0, {k: timeline[k] for k in (
         "jax_compiles_in_window", "compiled_programs", "cache_entries")}
+    assert timeline["companions"] == {"of_nodes": 0, "setup": 0, "setup_s": 0.0, "setup_echoes": 0, "window": 0,
+                                      "window_s": 0.0, "window_echoes": 0, "window_echo_s": 0.0}
     assert timeline["os_cpu_count"] and timeline["compare_info"]["journal_bindings"] >= res["attempted"]
     group = "per_layer" if trace else "end_to_end"
     assert set(res["metrics"]) <= _metric_names(group, cell)

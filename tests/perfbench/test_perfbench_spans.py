@@ -77,15 +77,32 @@ def test_reader_returns_nothing_from_a_program_without_the_primitive(name):
     assert reader.read(ctx([], {}, {})) is None
 
 
+# out of ``per_layer`` since PR 35: no checkpoint falls due in any window, so no
+# run could report it; the reader file and its arithmetic above stay for the
+# window in which one does (PERF.md section 8)
+WITHOUT_AN_ENTRY = {"snapshot_cpu_share.arrivals"}
+# the order in which the accepted entries stand in ``per_layer``, wherever
+# later entries stand among or after them
+ACCEPTED_ORDER = [
+    "pass_fetch_wait_ms_per_batch", "pass_fetch_wait_ms_per_batch.arrivals", "pass_dispatch_ms_per_batch.arrivals",
+    "journal_append_us_per_pod", "journal_serialize_us_per_pod", "drain_apply_us_per_pod", "publish_us_per_pod",
+    "queue_wait_ms_mean.arrivals", "server_gc_pause_ms", "setup_compile_s", "pass_device_us_per_pod",
+    "pack_us_per_pod", "pack_width",
+]
+
+
 def test_every_new_metric_is_declared_with_its_reader_and_an_accepted_layer():
     bench = _pb.bench()
     declared = {m["name"]: m for m in bench["per_layer"]}
     layers = {m["layer"] for m in bench["per_layer"] if m["name"] not in EXPECTED}
     backlogs = ["basic_5kn.backlog", "podaffinity_5kn.backlog"]
+    assert set(ACCEPTED_ORDER) == set(EXPECTED) - WITHOUT_AN_ENTRY and not WITHOUT_AN_ENTRY & set(declared)
     for name in EXPECTED:
+        assert os.path.exists(os.path.join(HOME, "metrics", name + ".py"))
+        if name in WITHOUT_AN_ENTRY:
+            continue
         m = declared[name]
         assert m["layer"] in layers and m["source"] in ("program_span", "program_counter", "device_trace")
-        assert os.path.exists(os.path.join(HOME, "metrics", name + ".py"))
         cells = m.get("workloads")
         if name == "setup_compile_s":
             assert cells is None and m["moves"] == "setup_s"
@@ -93,15 +110,16 @@ def test_every_new_metric_is_declared_with_its_reader_and_an_accepted_layer():
             assert m in spec.metrics_for(bench, "per_layer", cells[0])
             want = "decision_p50_ms" if name.endswith(".arrivals") else "pods_per_s"
             assert m["moves"] == want
+            # the cells a list began with, in their order; later cells follow
             if name.endswith(".arrivals"):
-                assert cells == ["basic_5kn.arrivals"]
+                assert cells[:1] == ["basic_5kn.arrivals"]
             elif name in ("pack_us_per_pod", "pack_width"):
-                assert cells == backlogs[1:]  # only that cell's batches are packed
+                # only the batches of an ordered configuration are packed
+                assert cells[:1] == backlogs[1:] and backlogs[0] not in cells
             else:
-                assert cells == backlogs
-    # appended: the accepted entries come first, in the order they had
-    names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(EXPECTED):] == [n for n in names if n in EXPECTED]
+                assert cells[:2] == backlogs
+    # the accepted entries stand in the order they had
+    assert [m["name"] for m in bench["per_layer"] if m["name"] in EXPECTED] == ACCEPTED_ORDER
 
 
 def test_a_silent_checkpoint_share_in_a_window_without_one():
@@ -204,7 +222,9 @@ def test_spans_on_a_trace_recorded_here(tmp_path):
                     for _ in range(3):
                         x = (x @ x / 256.0).block_until_ready()
             with TraceAnnotation("sched/pipeline/snapshot", batch=7):
+                t0 = time.perf_counter()
                 time.sleep(0.02)
+                slept = time.perf_counter() - t0
         time.sleep(0.01)
     finally:
         jax.profiler.stop_trace()
@@ -212,7 +232,9 @@ def test_spans_on_a_trace_recorded_here(tmp_path):
     assert r["span_events"] == 4 and r["batches_in_slice"] == 1 and r["device_ops"] > 0
     assert r["idle_s"] + r["busy_s"] == pytest.approx(r["window_s"])
     assert sum(r["idle_by_span"].values()) == pytest.approx(r["idle_s"])
-    assert r["idle_by_span"]["pipeline/snapshot"] == pytest.approx(0.02, abs=0.01)
+    # the sleep as it was timed here (20 ms, or what a loaded host made of it):
+    # no more is booked to the span, and no less
+    assert slept >= 0.02 and r["idle_by_span"]["pipeline/snapshot"] == pytest.approx(slept, abs=0.01)
     assert 0.0 < r["idle_named_share"] <= 100.0
     assert 0.0 < r["drain_overlapped_share"] <= 100.0
     assert r["spans_in_slice"] == {"drain/apply": 1, "pipeline/drain": 1,

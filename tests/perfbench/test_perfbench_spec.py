@@ -149,22 +149,192 @@ def test_the_accepted_configurations_objects_are_the_parents(name, nodes, pods, 
     """Digests taken on PR 27's tree at seed 2800000001 (the initial pods
     and 700 more): what an accepted cell sends is byte for byte what it
     sent before a configuration could carry a second template."""
-    import hashlib
-
     from perfbench import objects
-
-    def digest(items):
-        h = hashlib.sha256()
-        for x in items:
-            h.update(x if isinstance(x, bytes) else x.encode())
-            h.update(b"\n")
-        return h.hexdigest()[:16]
 
     with open(os.path.join(CONFIG_HOME, name + ".json")) as f:
         config = json.load(f)
     n = objects.Nodes(config, 2800000001)
     p = objects.Pods(config, 2800000001, config["initial_pods"] + 700, config["initial_pods"])
-    assert (digest(n.jsons), digest(p.jsons), digest(p.uids)) == (nodes, pods, uids)
+    assert (_digest(n.jsons), _digest(p.jsons), _digest(p.uids)) == (nodes, pods, uids)
+
+
+def _digest(items):
+    import hashlib
+
+    h = hashlib.sha256()
+    for x in items:
+        h.update(x if isinstance(x, bytes) else x.encode())
+        h.update(b"\n")
+    return h.hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name, seed, want", [
+    ("basic_5kn", 3500000011, "8017ea597b7330cb 27f4019adca47a51 781e0f271f6ecac9 d29c47a68487456d cde301ed4c6dd944 0a9a4242c525a597"),
+    ("basic_5kn", 2147483659, "b06fcb47915f6a30 0dfd4d65e384177a 9ee895653860cf8b 885bea4246c28994 8165d1c7d20fcc41 4bb7b0ecfadcaa54"),
+    ("podaffinity_5kn", 3500000011, "8017ea597b7330cb 6942a9bf01065c7c 781e0f271f6ecac9 5a3921bc978fdae3 7b6aa0a1e5a6ffb6 d58c5893e26d0b88"),
+    ("podaffinity_5kn", 2147483659, "b06fcb47915f6a30 35a59c5593eaf8b0 9ee895653860cf8b f4b1aec36da043d0 128b985931131609 eb03af04dec008b5"),
+    ("topology_spreading_5kn", 3500000011, "8017ea597b7330cb 34b05a4f9470f699 781e0f271f6ecac9 d29c47a68487456d a36bcf574e74dce6 21be86dd89727c91"),
+    ("topology_spreading_5kn", 2147483659, "b06fcb47915f6a30 e5331fc3028f1409 9ee895653860cf8b 885bea4246c28994 704f032a445c7754 2a02e5cf01c7b655"),
+])
+def test_the_accepted_configurations_traffic_did_not_move_when_companions_came(name, seed, want):
+    """Digests taken on PR 34's tree (e6bce10), before ``objects.py`` learnt
+    companions: the node names and JSON, the pod names, uids and JSON of a
+    whole run's plan at ``spec.shrink`` sizes, and the first measured hint
+    frame.  An accepted configuration has no companions, sends none, echoes
+    no bind, and draws from the seed what it drew."""
+    from perfbench import cell, objects, traffic, wire
+
+    with open(os.path.join(CONFIG_HOME, name + ".json")) as f:
+        config = json.load(f)
+    mix = traffic.load(os.path.join(_pb.ROOT, "perfbench", "traffic", "backlog.json"))
+    spec.shrink(config, mix)
+    plan = cell.pods_needed(config, mix, 1.5, 0)
+    n = objects.Nodes(config, seed)
+    p = objects.Pods(config, seed, plan["initial"] + plan["warm"] + plan["window"], plan["initial"])
+    first = plan["initial"] + plan["warm"]
+    frame = wire.pending_pods_frame(p.jsons[first: first + plan["backlog"]])
+    got = [_digest(x) for x in (n.names, n.jsons, p.names, p.uids, p.jsons, [frame])]
+    assert " ".join(got) == want
+    c = objects.Companions(config, n, p, plan["initial"])
+    assert (c.of_nodes, c.per_pod, c.node_objects, c.frames(0, len(p)), c.of_pod(first)) == ([], False, 0, (b"", 0), {})
+
+
+@pytest.fixture
+def csi(tmp_path):
+    """(configuration, home): the throw-away row with companion objects,
+    entered into a copy of ``perfbench/configs`` as new files."""
+    import csi_stand_in
+
+    home = str(tmp_path / "configs")
+    shutil.copytree(CONFIG_HOME, home)
+    return csi_stand_in.row(home), home
+
+
+def test_a_row_with_companion_objects_enters_beside_the_accepted_ones(csi):
+    """A nodeAllocatableStrategy, a persistentVolumeTemplatePath and a
+    persistentVolumeClaimTemplatePath, an excerpt and two templates of its own."""
+    doc, home = csi
+    _upstream.hold(doc, home)
+    for name in CONFIG_FILES:
+        with open(os.path.join(home, name)) as f:
+            _upstream.hold(json.load(f), home)
+
+
+def _companion(doc, kind):
+    (entry,) = [c for c in doc["pod"]["companions"] + doc["cluster"]["companions"] if c["kind"] == kind]
+    return entry["template"]
+
+
+def _excerpt(old, new):
+    def alter(doc, home):
+        path = os.path.join(home, doc["upstream"]["row"])
+        with open(path) as f:
+            text = f.read()
+        assert old in text
+        with open(path, "w") as f:
+            f.write(text.replace(old, new))
+    return alter
+
+
+COMPANION_ALTERATIONS = {
+    "the_drivers_count": (lambda doc, home: _companion(doc, "CSINode")["driver_limits"].update(
+        {"throwaway.csi.example": 39}), "csiNodeAllocatable"),
+    "the_nodes_own_allocatable": (lambda doc, home: doc["cluster"]["node_template"]["status"]["allocatable"].pop(
+        "attachable-volumes-csi-throwaway.csi.example"), "nodeAllocatable"),
+    "the_pvs_driver": (lambda doc, home: _companion(doc, "PersistentVolume").update(csi_driver="ebs.csi.aws.com"),
+                       "csi.driver"),
+    "the_claims_request": (lambda doc, home: _companion(doc, "PersistentVolumeClaim").update(request=1 << 29),
+                           "resources.requests.storage"),
+    "the_binding_volume_to_claim": (lambda doc, home: _companion(doc, "PersistentVolume").update(
+        claim_ref="{namespace}/pvc-0"), "claim_ref"),
+    "the_binding_claim_to_volume": (lambda doc, home: _companion(doc, "PersistentVolumeClaim").update(
+        volume_name=""), "volume_name"),
+    "one_claim_for_all_pods": (lambda doc, home: (
+        _companion(doc, "PersistentVolumeClaim").update(name="pvc"),
+        _companion(doc, "PersistentVolume").update(claim_ref="{namespace}/pvc")), "one for each pod"),
+    "the_pods_volume_names_another_claim": (lambda doc, home: doc["pod"]["template"]["spec"]["volumes"][0].update(
+        pvc="pvc-other"), None),
+    "the_initial_pods_carry_the_volume_too": (lambda doc, home: doc["pod"].pop("initial_template"), "initial_template"),
+    "companions_for_the_initial_pods": (lambda doc, home: doc["pod"]["companions"][0].update(of="all"), "of the measured pods"),
+    "a_field_the_wire_lacks_left_unsaid": (lambda doc, home: doc["assumed"]["no_wire_field"].pop("migratedPlugins"),
+                                           "no_wire_field"),
+    "an_opcode_it_does_not_know": (_excerpt("  - opcode: createPods\n    countParam: $initPods\n",
+                                            "  - opcode: churn\n  - opcode: createPods\n    countParam: $initPods\n"),
+                                   "opcode churn"),
+    "an_ops_key_it_does_not_know": (_excerpt("    collectMetrics: true\n", "    collectMetrics: true\n    skipWaitToCompletion: true\n"),
+                                    "skipWaitToCompletion"),
+    "a_strategy_it_does_not_know": (_excerpt("    nodeAllocatableStrategy:", "    uniqueNodeLabelStrategy:\n      labelKey: x\n    nodeAllocatableStrategy:"),
+                                    "uniqueNodeLabelStrategy"),
+    "a_key_of_the_strategy_it_does_not_know": (_excerpt("      migratedPlugins:", "      somethingNew: 1\n      migratedPlugins:"),
+                                               "somethingNew"),
+}
+
+
+@pytest.mark.parametrize("how", sorted(COMPANION_ALTERATIONS))
+def test_an_altered_companion_fails_the_comparison_by_name(csi, how):
+    doc, home = csi
+    alter, named = COMPANION_ALTERATIONS[how]
+    alter(doc, home)
+    with pytest.raises(AssertionError, match=named and named.replace(".", r"\.")):
+        _upstream.hold(doc, home)
+
+
+def test_companions_are_one_a_node_and_one_a_selected_pod_in_the_files_order(csi):
+    from perfbench import cell, objects, wire
+
+    config, _ = csi
+    config["cluster"]["nodes"] = 5
+    config["cluster"]["cycles"] = {"zone": {"values": ["a", "b"]}}
+    config["cluster"]["companions"][0]["template"]["driver_limits"] = {"d-{zone}": 3}
+    nodes = objects.Nodes(config, 7)
+    pods = objects.Pods(config, 7, 6, initial=2)
+    c = objects.Companions(config, nodes, pods, 2)
+    ((kind, csinodes),) = c.of_nodes
+    # one a node, in the nodes' shuffled order, filled as the node is
+    assert kind == "CSINode" and [json.loads(j)["name"] for j in csinodes] == nodes.names
+    assert {json.loads(j)["name"]: list(json.loads(j)["driver_limits"]) for j in csinodes} == \
+        {f"node-{i}": ["d-" + "ab"[i % 2]] for i in range(5)}
+    assert c.node_objects == 5 and c.per_pod
+    # of "measured": none for the initial pods; kind by kind in the file's order
+    assert c.frames(0, 2) == (b"", 0) and c.of_pod(1) == {}
+    data, n = c.frames(1, 5)
+    claims = [c.of_pod(k)["PersistentVolumeClaim"][0] for k in (2, 3, 4)]
+    volumes = [c.of_pod(k)["PersistentVolume"][0] for k in (2, 3, 4)]
+    assert n == 6 and data == b"".join([wire.add_frame("PersistentVolumeClaim", j) for j in claims]
+                                       + [wire.add_frame("PersistentVolume", j) for j in volumes])
+    for k in (2, 3, 4):
+        pod, claim, volume = json.loads(pods.jsons[k]), json.loads(claims[k - 2]), json.loads(volumes[k - 2])
+        assert pod["spec"]["volumes"][0]["pvc"] == claim["name"] == "pvc-" + pods.names[k]
+        assert claim["namespace"] == pod["metadata"]["namespace"] == "namespace-2"
+        assert claim["volume_name"] == volume["name"] and volume["claim_ref"] == f"namespace-2/{claim['name']}"
+        assert c.of_uid(pods.uids[k]) == c.of_pod(k)
+    config["pod"]["companions"][1]["of"] = "initial"
+    config["pod"]["companions"][0]["of"] = "all"
+    c = objects.Companions(config, nodes, pods, 2)
+    assert [len(v) for v in c.of_pod(0).values()] == [1, 1] and list(c.of_pod(4)) == ["PersistentVolumeClaim"]
+    assert c.frames(0, 6)[1] == 6 + 2
+    config["pod"]["companions"][0]["of"] = "some"
+    with pytest.raises(ValueError, match="some"):
+        objects.Companions(config, nodes, pods, 2)
+    # the pods' names and JSON are drawn as without companions
+    bare = dict(config, pod={k: v for k, v in config["pod"].items() if k not in ("companions", "bind_echo")})
+    assert objects.Pods(bare, 7, 6, initial=2).jsons == pods.jsons
+    # a bind echo is the pod as it was sent, with its node; a pod that was
+    # not sent as pending cannot be echoed, and says so
+    echoed = json.loads(pods.jsons[3])
+    echoed["spec"]["node_name"] = "node-4"
+    assert pods.bound_frame(3, "node-4") == wire.add_frame("Pod", json.dumps(echoed, sort_keys=True).encode())
+    pods.jsons[4] = pods.jsons[4].replace(b'"node_name": ""', b'"node_name": "node-9"')
+    with pytest.raises(ValueError, match="cannot be echoed"):
+        pods.bound_frame(4, "node-4")
+    # the echo is the configuration's to state, in the one shape there is
+    assert not cell.bind_echo(bare) and cell.bind_echo(config)
+    with pytest.raises(SystemExit, match="bind_echo.*'answered'.*'each_backlog'"):
+        cell.bind_echo(dict(config, pod=dict(config["pod"], bind_echo="each_backlog")))
+    # the room: a further cap a node
+    assert cell.cluster_pod_capacity(config) == 5 * 3
+    del config["capacity"]["pods_per_node_max"]
+    assert cell.cluster_pod_capacity(config) == 5 * 40
 
 
 def test_a_template_for_each_createpods_op_and_cycles_that_name_their_values(spreading):

@@ -104,6 +104,163 @@ def test_window_opens_at_the_first_hint_frame():
     assert w.hint_frames >= 1 and w.answer_t[0] > w.t_open
 
 
+class Companioned(FakeSidecar):
+    """A sidecar that also takes pipelined adds, 1 ms a hundred objects, and
+    posted frames, 10 us of the loop's own time each, and keeps the order
+    in which everything reached it."""
+
+    def __init__(self, clock, **kw):
+        super().__init__(clock, **kw)
+        self.log = []
+
+    def call_raw(self, frame):
+        self.log.append(("hint", len(frame), self.clock.t))
+        super().call_raw(frame)
+
+    def schedule_raw(self, frame):
+        self.log.append(("call", frame, self.clock.t))
+        return super().schedule_raw(frame)
+
+    def call_many(self, data, want):
+        self.log.append((data, want, self.clock.t))
+        self.clock.t += 0.00001 * want
+
+    def post(self, frame):
+        self.log.append(("echo", frame, self.clock.t))
+        self.clock.t += 0.00001
+
+
+def _closed_with_companions(seconds, companions="each", echo=True, backlog=20):
+    clock = FakeClock()
+    side = Companioned(clock)
+    pods = FakePods(400)
+    hints = [pods.uids[a: a + backlog] for a in range(0, 400, backlog)]
+    if companions == "each":  # a claim and a volume a pod
+        companions = [("companions", 2 * backlog)] * len(hints)
+    w = loops.closed_loop(side, side, pods, hints, 0, backlog, seconds, clock=clock, companions=companions,
+                          echo=(lambda k, node: (pods.uids[k], node)) if echo else None)
+    return w, side
+
+
+def test_companions_go_out_before_each_hint_frame_and_inside_the_window():
+    w, side = _closed_with_companions(7.0)
+    kinds = [e[0] for e in side.log]
+    backlogs = w.asked // 20
+    assert backlogs == 3
+    # a backlog: its companions, its hint frame, and three batches (8, 8 and
+    # 4 pods): each a call, then every pod of it echoed as it is answered
+    assert kinds == (["companions", "hint"] + ["call"] + ["echo"] * 8 + ["call"] + ["echo"] * 8
+                     + ["call"] + ["echo"] * 4) * backlogs
+    assert [e[1] for e in side.log if e[0] == "companions"] == [40] * backlogs
+    # the window opens at the first companion frame and counts them all
+    assert side.log[0][2] == w.t_open == 100.0
+    assert w.companion_objects == 2 * w.asked and w.companion_s == pytest.approx(backlogs * 0.0004)
+    assert w.hint_frames == backlogs
+    # on_boundary(first=True) is still the first wire call after the hint frame
+    assert [side.log[k + 1][0] for k, e in enumerate(side.log) if e[0] == "hint"] == ["call"] * backlogs
+
+
+def test_a_pod_goes_back_bound_as_soon_as_it_is_answered_and_the_window_closes_at_the_last_answer():
+    w, side = _closed_with_companions(7.0, companions=None)
+    echoes = [e for e in side.log if e[0] == "echo"]
+    # every answered pod once, in the order answered, with the node it got,
+    # posted at its answer: nothing waits for a backlog's end
+    assert [e[1] for e in echoes] == [(f"u{k}", "n1") for k in range(w.asked)]
+    assert [e[2] for e in echoes] == w.answer_t
+    assert w.echo_objects == w.asked and w.echo_s == pytest.approx(w.asked * 0.00001)
+    # one rule for the window's end: the last answer, as without echoes
+    assert w.t_close == w.answer_t[-1] == echoes[-1][2]
+    plain, _ = _closed(7.0)
+    assert w.asked == plain.asked and w.seconds == pytest.approx(plain.seconds + (w.asked - 1) * 0.00001)
+    # a pod that came back without a node is not echoed
+    clock = FakeClock()
+    side = Companioned(clock)
+    side.schedule_raw = lambda frame: (FakeSidecar.schedule_raw(side, frame), "")[1]
+    pods = FakePods(40)
+    w = loops.closed_loop(side, side, pods, [pods.uids[:20]], 0, 20, 1.0, clock=clock, max_backlogs=1,
+                          echo=lambda k, node: side.log.append(("made", k)) or (k, node))
+    assert w.asked == 20 and w.bound == 17 and w.echo_objects == 17
+    assert [e[1] for e in side.log if e[0] == "made"] == [k for k in range(20) if k not in (0, 8, 16)]
+
+
+def test_a_configuration_without_companions_sends_what_it_sent_and_opens_where_it_did():
+    plain, side0 = _closed(7.0)
+    for companions in (None, [(b"", 0)] * 20):
+        w, side = _closed_with_companions(7.0, companions=companions, echo=False)
+        assert [e[0] for e in side.log] == ["hint", "call", "call", "call"] * 3  # no add of any kind
+        assert (w.t_open, w.t_close, w.asked, w.hint_frames, w.misses) == \
+            (plain.t_open, plain.t_close, plain.asked, plain.hint_frames, plain.misses)
+        assert w.answer_t == plain.answer_t and w.nodes == plain.nodes
+        assert (w.companion_objects, w.companion_s, w.echo_objects, w.echo_s) == (0, 0.0, 0, 0.0)
+    assert not hasattr(side0, "call_many") and not hasattr(side0, "post")  # the accepted loops never ask for either
+
+
+def test_an_open_mix_on_a_configuration_with_pod_companions_is_refused_at_the_start(tmp_path):
+    from perfbench import cell
+
+    for pod in ({"companions": [{"kind": "PersistentVolumeClaim", "of": "measured", "template": {}}]},
+                {"bind_echo": "answered"}):
+        with pytest.raises(SystemExit, match="open mix.*companions.*bind echo.*refused"):
+            cell.run(str(tmp_path), {"name": "a.arrivals", "chips": 1}, {"pod": pod}, {"loop": "open"}, 1, 1.0,
+                     False, True, 0.0, out_root=str(tmp_path))
+    assert not list(tmp_path.iterdir())  # before anything was started or written
+
+
+def test_posted_frames_are_acknowledged_behind_the_loop_and_settled_before_the_next_call(tmp_path):
+    """``wire.Conn.post`` against a server that takes a connection's frames
+    in order, answers each, and is slow to start reading: 3,000 frames of
+    2 KB outgrow both socket buffers, so a post that neither drained
+    acknowledgements nor waited for room would hang; the call that follows
+    finds every acknowledgement read, and a refused frame is reported."""
+    import socket
+    import threading
+
+    from perfbench import wire
+
+    pb = wire._pb()
+    path = str(tmp_path / "s.sock")
+    srv = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
+    srv.bind(path)
+    srv.listen(1)
+    seen = []
+
+    def serve():
+        peer, _ = srv.accept()
+        threading.Event().wait(0.2)  # the client runs ahead of it
+        try:
+            while True:
+                env = wire.read_envelope(peer)
+                seen.append(env.add.kind or env.WhichOneof("msg"))
+                out = pb.Envelope()
+                out.response.SetInParent()
+                if env.add.kind == "Refused":
+                    out.response.error = "no such kind"
+                peer.sendall(wire.frame(out))
+        except ConnectionError:
+            peer.close()
+
+    t = threading.Thread(target=serve, daemon=True)
+    t.start()
+    conn = wire.Conn(path)
+    frame = wire.add_frame("Pod", b"x" * 2048)
+    for _ in range(3000):
+        conn.post(frame)
+    assert 0 < conn._owed <= 3000
+    assert conn.call_raw(wire.add_frame("Node", b"{}")).error == ""
+    assert conn._owed == 0 and seen == ["Pod"] * 3000 + ["Node"]
+    # acknowledgements still owed ride the next pipelined call
+    conn.post(frame)
+    conn.call_many(wire.add_frame("Node", b"{}") * 5, 5)
+    assert conn._owed == 0 and seen[-6:] == ["Pod"] + ["Node"] * 5
+    # a posted frame that the server refuses is reported by the call that settles it
+    conn.post(wire.add_frame("Refused", b"{}"))
+    with pytest.raises(RuntimeError, match="1 adds failed.*first: no such kind"):
+        conn.call_raw(frame)
+    conn.close()
+    t.join(5.0)
+    srv.close()
+
+
 def test_percentile_matches_numpy():
     rng = random.Random(5)
     xs = [rng.random() for _ in range(997)]
