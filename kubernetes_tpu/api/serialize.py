@@ -115,7 +115,18 @@ def featsig_from_data(namespace, labels, spec_data) -> tuple:
     cache entries: the key is (namespace, sort-keys labels JSON or "",
     sort-keys spec JSON) over the canonical data model, and the two
     paths produce string-identical dumps because the canonical dumper
-    emits exactly the parsed wire shape."""
+    emits exactly the parsed wire shape.
+
+    The NAMES of the pod's claims stay out of it: pods that differ only in
+    which claim each names (a claim of its own a pod) share the signature,
+    and the cache's key puts back what featurization reads of each claim
+    (engine/features._claim_key)."""
+    vols = spec_data.get("volumes")
+    if vols and any(v.get("pvc") for v in vols):
+        spec_data = dict(
+            spec_data,
+            volumes=[dict(v, pvc="*") if v.get("pvc") else v for v in vols],
+        )
     return (
         namespace or "default",
         json.dumps(labels, sort_keys=True) if labels else "",

@@ -678,16 +678,13 @@ def _pv_measured(count: int, zones: int = 10, driver: str = ""):
     return measure
 
 
-def _pv_warm(total_claims: int, zones: int = 10, driver: str = ""):
+def _pv_warm(zones: int = 10, driver: str = ""):
     """Volume-workload warmup: schedule a volume-ACTIVE wave (so the
-    VB/VZ/NVL-active XLA program compiles here, not in the measured window)
-    and pre-grow the claim-vocabulary bucket to the measured scale (a CV
-    bucket growth mid-run would recompile)."""
+    VB/VZ/NVL-active XLA program compiles here, not in the measured
+    window).  No shape follows the number of claims the row brings: each
+    is a per-node count."""
 
     def warm(s: TPUScheduler) -> None:
-        from ..snapshot import _bucket
-
-        s.builder._ensure(CV=_bucket(total_claims + 512))
         for i in range(512):
             pv_name = f"warmpv-{i}"
             s.add_pv(make_pv(pv_name, zone=f"zone-{i % zones}", csi_driver=driver))
@@ -706,7 +703,7 @@ _register(
         baseline_pods_per_sec=90.0,
         build=_default(),
         nodes=_basic_nodes(5000, zones=10),
-        warmup=_pv_warm(2000),
+        warmup=_pv_warm(),
         measured=_pv_measured(2000),
     )
 )
@@ -727,7 +724,7 @@ _register(
         baseline_pods_per_sec=35.0,
         build=_default(),
         nodes=_migrated_nodes,
-        warmup=_pv_warm(5000, driver="pd.csi.storage.gke.io"),
+        warmup=_pv_warm(driver="pd.csi.storage.gke.io"),
         measured=_pv_measured(5000, driver="pd.csi.storage.gke.io"),
     )
 )
@@ -736,9 +733,6 @@ _register(
 # SchedulingCSIPVs: WaitForFirstConsumer claims dynamically provisioned at
 # PreBind (volumebinding's delayed path).
 def _csi_warm(s: TPUScheduler) -> None:
-    from ..snapshot import _bucket
-
-    s.builder._ensure(CV=_bucket(6000))
     s.add_storage_class(
         t.StorageClass(
             name="csi-sc",

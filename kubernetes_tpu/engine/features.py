@@ -18,6 +18,7 @@ from ..framework.config import Profile
 from ..ops import common as opcommon
 from ..snapshot import POD_PORT_SLOTS, SnapshotBuilder, _bucket
 from ..utils import const_array
+from ..volumes import claim_uids
 
 opcommon.feature_fill("ipa_own_terms", -1)
 opcommon.feature_fill("vol_dev_ids", -1)
@@ -119,6 +120,48 @@ def _spec_eq_mod_pin(a: t.PodSpec, b: t.PodSpec) -> bool:
     return ma.key == mb.key and ma.operator == mb.operator
 
 
+def _claim_key(sig, pod: t.Pod, builder: SnapshotBuilder):
+    """The featurization cache key of a pod whose signature is ``sig``.
+    The signature leaves the NAMES of the pod's claims out
+    (serialize.featsig_from_data), so that pods that differ only there
+    share one featurization; what featurization reads of each claim comes
+    back in here: the catalog's answer for it (VolumeCatalog.claim_featsig),
+    its row if it is shared, and which of the pod's volumes name one claim
+    twice.  A claim that has to be featurized by itself stands in the key
+    under its own name."""
+    if not pod.spec.volumes:
+        return sig
+    uids = claim_uids(pod)
+    if not uids:
+        return sig
+    cat, rows = builder.volumes, builder.csi_rows
+    parts = []
+    for uid in uids:
+        rid = rows.get(uid)
+        fs = cat.claim_featsig(uid) if rid is None else None
+        parts.append(uid if fs is None else fs)
+    if len(uids) > 1:
+        parts.append(tuple(uids.index(u) for u in uids))
+    return (sig, tuple(parts))
+
+
+def _own_claims(delta: dict, pod: t.Pod) -> dict:
+    """A cached delta made the delta of ``pod``: everything in it is the
+    template's but the names of the claims, which are the pod's own.  The
+    key (_claim_key) holds the two pods' claims to the same order, the same
+    repeats and the same drivers."""
+    pvcs = delta.get("pvcs")
+    if not pvcs:
+        return delta
+    uids = claim_uids(pod)
+    if uids == pvcs:
+        return delta
+    slot = {old: i for i, old in reversed(list(enumerate(pvcs)))}
+    delta["csivols"] = [(uids[slot[old]], did) for old, did in delta["csivols"]]
+    delta["pvcs"] = uids
+    return delta
+
+
 def build_pod_batch(
     pods: list[t.Pod],
     builder: SnapshotBuilder,
@@ -126,6 +169,7 @@ def build_pod_batch(
     k: int,
     force_active: frozenset[str] | None = None,
     sample_into: dict | None = None,
+    info: dict | None = None,
 ) -> tuple[dict, list[dict], frozenset[str]]:
     """Featurize up to ``k`` pods into a dict of (k, …) numpy arrays, plus the
     per-pod commit deltas (reused by the cache's assume step so pods are
@@ -135,7 +179,11 @@ def build_pod_batch(
 
     Featurization may grow vocabularies/schema (new scalar resources, label
     pairs, topology keys), which is why it must run before the device state is
-    flushed for the pass."""
+    flushed for the pass.
+
+    ``info`` (optional) receives ``uniform_key``: the one cache key every
+    pod of the batch shares, where every row of the batch is the same row,
+    else None."""
     assert len(pods) <= k
     fctx = opcommon.FeaturizeContext(builder=builder, profile=profile)
     all_ops = [opcommon.get(name) for name in dict.fromkeys(
@@ -161,7 +209,7 @@ def build_pod_batch(
         # pre-stamped from the raw JSON (serialize.pod_from_data).
         memo = getattr(pod, "_featsig", None)
         if memo is not None:
-            keys.append(memo)
+            keys.append(_claim_key(memo, pod, builder))
             pins.append(None)
             continue
         pin = pin_name(pod)
@@ -175,7 +223,7 @@ def build_pod_batch(
             continue
         key = pod_sig(pod)
         pod._featsig = key
-        keys.append(key)
+        keys.append(_claim_key(key, pod, builder))
         pins.append(None)
     if force_active is not None:
         # Rebuild for the strict tail: the pass is already compiled for this
@@ -236,13 +284,12 @@ def build_pod_batch(
     # uniform_all, pin_row) but never mutate the arrays.
     uniform_key = None
     uniform_version = version
-    if (
-        sample_into is None
-        and force_active is None
-        and pods
-        and keys[0] is not None
-        and all(k2 == keys[0] for k2 in keys)
-    ):
+    one_key = bool(pods) and keys[0] is not None and all(
+        k2 == keys[0] for k2 in keys
+    )
+    if info is not None:
+        info["uniform_key"] = keys[0] if one_key else None
+    if sample_into is None and force_active is None and one_key:
         # Count-independent: every row is the template row (broadcast
         # views), so a 1-pod warm batch and a 1000-pod measured batch share
         # the entry; only `valid` depends on the count and is built fresh.
@@ -256,7 +303,7 @@ def build_pod_batch(
             batch["valid"] = valid
             return (
                 batch,
-                [dict(delta0) for _ in range(len(pods))],
+                [_own_claims(dict(delta0), pod) for pod in pods],
                 active,
             )
     # Pin templates: (ns, labels, spec, feats, delta) per distinct pinned
@@ -266,7 +313,7 @@ def build_pod_batch(
         if key is not None:
             hit = store.get(key)
             if hit is not None:
-                deltas.append(dict(hit[1]))
+                deltas.append(_own_claims(dict(hit[1]), pod))
                 per_pod.append(dict(hit[0]))
                 continue
         elif pin is not None:
@@ -348,8 +395,10 @@ def build_pod_batch(
         if cvols:
             csi_ids = np.full(_bucket(len(cvols), 1), -1, np.int32)
             csi_drv = np.full(csi_ids.shape[0], -1, np.int32)
-            for j, (vid, did) in enumerate(cvols):
-                csi_ids[j] = vid
+            for j, (uid, did) in enumerate(cvols):
+                # A claim of the pod's own has no row: the device needs to
+                # know only its driver.
+                csi_ids[j] = builder.csi_rows.get(uid, -1)
                 csi_drv[j] = did
         else:
             csi_ids = csi_drv = _I32_NEG1

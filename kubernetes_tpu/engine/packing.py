@@ -161,10 +161,14 @@ def conflict_classes(batch: dict, npods: int) -> np.ndarray:
         space = int(ids.max(initial=-1)) + 1
         add_edges(vp, ids[vp, vs], max(space, 0))
 
-    # -- any-vs-any classes (racing pools, per-node shared budgets) ---------
+    # -- any-vs-any classes (racing pools) ----------------------------------
+    # A CSI attach limit (vol_csi_lim) is not one: it is a per-node budget
+    # like the pod count, two pods meet in it only where they land on one
+    # node, and the pass settles that itself (pass_.py's lim_clash
+    # deferral).  As a class it serialised every claim-carrying pod of a
+    # packed batch: pack_width 1.
     for key, reduce_axis in (
         ("vol_unbound", False),
-        ("vol_csi_lim", False),
         ("dra_claim_unalloc", True),
     ):
         if key not in batch:

@@ -222,18 +222,23 @@ def _commit_chunk(
             inc * pf["vol_dev_rw"].astype(jnp.int32)
         )
     if "vol_csi_ids" in pf:
-        # Distinct-volume accounting (nodevolumelimits/csi.go:219): a volume
-        # counts against the driver limit only when its per-node pod count
-        # crosses 0→1.  Safe to read-before-scatter: volume-using pods are a
-        # conflict class in _conflict_pairs, so at most one commits per chunk.
+        # The attach budget is a per-node count (nodevolumelimits/csi.go:219
+        # counts DISTINCT volumes): a claim of the pod's own (slot id -1) is
+        # one more of csi_used where the pod lands, like num_pods, and
+        # duplicates scatter-accumulate.  A SHARED claim (slot id = its row
+        # of csivol_counts) counts only where its per-node pod count crosses
+        # 0→1.  Safe to read-before-scatter: pods sharing a claim id are a
+        # conflict pair in _conflict_pairs, so at most one of them commits
+        # per chunk.
         ids = pf["vol_csi_ids"]  # (C, S)
-        act = do[:, None] & (ids >= 0)
+        act = do[:, None] & (pf["vol_csi_drv"] >= 0)
+        shared = act & (ids >= 0)
         safe_v = jnp.maximum(ids, 0)
         prev = state.csivol_counts[safe_v, rows[:, None]]  # (C, S)
         new["csivol_counts"] = state.csivol_counts.at[safe_v, rows[:, None]].add(
-            act.astype(jnp.int32)
+            shared.astype(jnp.int32)
         )
-        newly = act & (prev == 0)  # (C, S)
+        newly = act & ((ids < 0) | (prev == 0))  # (C, S)
         drv_oh = (
             pf["vol_csi_drv"][:, :, None] == jnp.arange(state.csi_used.shape[0])[None, None, :]
         ) & newly[:, :, None]  # (C, S, DR)

@@ -91,11 +91,9 @@ class InternTable:
         self.terms = Vocab("terms")  # existing-pod (anti-)affinity terms
         self.devices = Vocab("devices")  # in-tree device-volume ids
         self.drivers = Vocab("drivers")  # CSI driver names
-        # CSI volume unique names (nodevolumelimits/csi.go volumeUniqueName:
-        # bound → driver/volumeHandle; unbound → driver/claim-uid), so a
-        # volume shared by several pods on a node attaches — and counts —
-        # once.
-        self.csivols = Vocab("csivols")
+        # (CSI volumes have no vocabulary: a claim one pod uses is a count,
+        # and the few that pods share hold rows the builder hands out,
+        # snapshot.SnapshotBuilder.csi_rows.)
         self.device_classes = Vocab("device_classes")  # DRA device classes
         self.dra_claims = Vocab("dra_claims")  # DRA claim uids
         self.ports = Vocab("ports")

@@ -821,7 +821,7 @@ def _apply_replay(sched, journal, snap, records, stats) -> None:
                     }
                 )
                 sched._evicted_uids.update(fr.get("evicted_uids", ()))
-            sched.queue.restore_state(st.get("queue", {}))
+            sched.restore_queue(st.get("queue", {}))
             for uid, info in st.get("nominated", {}).items():
                 qp = sched.queue._info.get(uid)
                 if qp is not None and info["node"] in sched.cache.nodes:

@@ -25,7 +25,9 @@ requirement-program encoding NodeAffinity uses, with one extra *group* axis:
 each claim (or bound PV) is an OR-group of terms and the node must satisfy
 every group — evaluated as one broadcast + a segment-style group reduction.
 Device conflicts and attach limits read per-node count tensors maintained by
-the same commit deltas that move resources.
+the same commit deltas that move resources; the attach budget is a per-node,
+per-driver count, and only a claim that several pods share holds a row of
+per-claim, per-node counts beside it (snapshot.SnapshotBuilder).
 """
 
 from __future__ import annotations
@@ -236,14 +238,20 @@ def _vr_active(pod: t.Pod, fctx: FeaturizeContext) -> bool:
 
 
 def _nvl_filter(state, pf, ctx: PassContext):
-    """Attach-limit check by DISTINCT volume (csi.go:219): the pod's volumes
-    already attached to the node (csivol_counts > 0) do not count again."""
-    ids = pf["vol_csi_ids"]  # (S,) engine base features, -1 pad
-    act = ids >= 0
-    present = state.csivol_counts[jnp.maximum(ids, 0)] > 0  # (S, N)
+    """Attach-limit check by DISTINCT volume (csi.go:219): per driver,
+    csi_used + the pod's new volumes <= csi_limit.  A claim of the pod's
+    own (slot id -1) is new wherever the pod goes; a SHARED claim (slot id
+    = its row of csivol_counts) already attached to the node does not
+    count again."""
+    drv = pf["vol_csi_drv"]  # (S,) engine base features, -1 pad
+    ids = pf["vol_csi_ids"]  # (S,) row of a shared claim, -1 for the pod's own
+    act = drv >= 0
+    present = (ids >= 0)[:, None] & (
+        state.csivol_counts[jnp.maximum(ids, 0)] > 0
+    )  # (S, N)
     newv = act[:, None] & ~present  # (S, N) — genuinely new attachments
     dr = state.csi_used.shape[0]
-    drv_oh = (pf["vol_csi_drv"][:, None] == jnp.arange(dr)[None, :]) & act[:, None]
+    drv_oh = (drv[:, None] == jnp.arange(dr)[None, :]) & act[:, None]
     new_cnt = (drv_oh[:, :, None] & newv[:, None, :]).sum(0)  # (DR, N)
     ok = state.csi_used + new_cnt <= state.csi_limit
     return (ok | (new_cnt == 0)).all(0)

@@ -87,7 +87,7 @@ from functools import partial  # noqa: E402
 # np.full/np.zeros defaults pack_victims stages with.
 _VFEAT_PAD = {
     "group": -1, "terms": -1, "port_triples": -1, "port_keys": -1,
-    "vol_dev_ids": -1, "csi_ids": -1, "dra_kid": -1,
+    "vol_dev_ids": -1, "csi_ids": -1, "csi_drv": -1, "dra_kid": -1,
 }
 
 
@@ -318,20 +318,23 @@ def build_preempt_pass(
                     jnp.maximum(di, 0), rows3
                 ].add(-(dm * (dw > 0)))
             if "csi_ids" in vfeat:
-                # CSI attach limits: csivol_counts decrement per reference;
-                # csi_used releases only where the DISTINCT volume's count
-                # crosses to zero (csi.go:219 semantics — two victims
-                # sharing an attached volume free it only together).
+                # CSI attach limits: a victim's own claim (slot id -1)
+                # gives csi_used back with the victim; a SHARED claim's
+                # csivol_counts decrement per reference, and csi_used
+                # releases only where the DISTINCT volume's count crosses
+                # to zero (csi.go:219 semantics — two victims sharing an
+                # attached volume free it only together).
                 ci = vfeat["csi_ids"]  # (N, V, Sc)
                 cd = vfeat["csi_drv"]
-                cm = (mask[:, :, None] & (ci >= 0)).astype(jnp.int32)
+                act = mask[:, :, None] & (cd >= 0)
+                cm = (act & (ci >= 0)).astype(jnp.int32)
                 ci_s = jnp.maximum(ci, 0)
                 new_cv = state.csivol_counts.at[ci_s, rows3].add(-cm)
-                crossed = (cm > 0) & (new_cv[ci_s, rows3] == 0)
+                freed = act & ((ci < 0) | (new_cv[ci_s, rows3] == 0))
                 new["csivol_counts"] = new_cv
                 new["csi_used"] = state.csi_used.at[
                     jnp.maximum(cd, 0), rows3
-                ].add(-crossed.astype(jnp.int32))
+                ].add(-freed.astype(jnp.int32))
             if "dra_kid" in vfeat:
                 # DRA claim references + pool charges: claim counts drop
                 # per FIRST slot (the count-moving one, mirroring
@@ -775,6 +778,8 @@ class PreemptionEvaluator:
             and st["names"] == names
             and st["profile"] is profile
             and st["active"] == active
+            # staged csi_ids are rows of the shared-claim table as it stood
+            and st["csi_epoch"] == builder.csi_epoch
         ):
             st = None
         if st is not None:
@@ -868,7 +873,7 @@ class PreemptionEvaluator:
         if "NodeVolumeLimits" in names:
             sc = _slots("csivols")
             vfeat["csi_ids"] = np.full((n, vu, sc), -1, np.int32)
-            vfeat["csi_drv"] = np.zeros((n, vu, sc), np.int32)
+            vfeat["csi_drv"] = np.full((n, vu, sc), -1, np.int32)
         dra_slot_map: dict[tuple[int, int], list] = {}
         if "DynamicResources" in names:
             # Per-victim claim slots = the pod's own delta slots PLUS a
@@ -919,6 +924,7 @@ class PreemptionEvaluator:
         st_new = (
             dict(
                 n=n, r=schema.R, names=names, profile=profile, active=active,
+                csi_epoch=builder.csi_epoch,
                 vmax=vmax, vu=vu, v=v, A=A, per_node=per_node,
                 gens={rec.row: rec.pods_gen for rec in cache.nodes.values()},
             )
@@ -1105,6 +1111,7 @@ class PreemptionEvaluator:
         vic_pdb, vfeat = A["vic_pdb"], A["vfeat"]
         pdbs, matched_pdbs = A["pdbs"], A["matched_pdbs"]
         dra_slot_map = A["dra_slot_map"]
+        csi_rows = self.sched.builder.csi_rows  # a shared claim's row; its own: -1
         for row, vics in items:
             for j, p in enumerate(vics):
                 pr = cache.pods[p.uid]
@@ -1129,8 +1136,8 @@ class PreemptionEvaluator:
                         vfeat["vol_dev_ids"][row, j, a] = vid
                         vfeat["vol_dev_rw"][row, j, a] = int(bool(rw))
                 if "csi_ids" in vfeat:
-                    for a, (vid, did) in enumerate(pr.delta.get("csivols", ())):
-                        vfeat["csi_ids"][row, j, a] = vid
+                    for a, (cuid, did) in enumerate(pr.delta.get("csivols", ())):
+                        vfeat["csi_ids"][row, j, a] = csi_rows.get(cuid, -1)
                         vfeat["csi_drv"][row, j, a] = did
                 if "dra_kid" in vfeat:
                     for a, (kid, cid, cnt, _un, first) in enumerate(
@@ -1373,16 +1380,16 @@ class PreemptionEvaluator:
     def _sig_ids(self, pods, profile, k: int):
         """Chunk-sharing signatures (first-index representative ids) for
         the dry-run's rank-split, padded to k."""
-        from .engine.features import pod_sig
+        from .engine.features import _claim_key, pod_sig
 
         sig_first: dict = {}
         sigs = np.zeros(k, np.int32)
+        builder = self.sched.builder
         for i, p in enumerate(pods):
             memo = getattr(p, "_featsig", None)
-            if memo is not None:
-                key_ = memo
-            else:
-                key_ = pod_sig(p)
+            # the signature leaves the claims' names out; the key puts back
+            # what featurization reads of them
+            key_ = _claim_key(memo if memo is not None else pod_sig(p), p, builder)
             sigs[i] = sig_first.setdefault(key_, i)
         return sigs, sig_first
 

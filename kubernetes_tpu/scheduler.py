@@ -596,6 +596,12 @@ class TPUScheduler:
             "Main-pass dispatches by domain-table source (carried vs "
             "rebuilt from cluster state).",
         )
+        csi_claims = reg.gauge(
+            "scheduler_csi_claims",
+            "Known CSI claims by how the attach budget holds them: counted "
+            "(one known pod: a per-node count, no row) or shared (several: "
+            "a row of per-claim, per-node counts).",
+        )
         # Heterogeneity attribution (ISSUE 14): armed only when a
         # registered profile ships a throughput matrix — homogeneous
         # deployments pay nothing and export no empty families.
@@ -759,6 +765,9 @@ class TPUScheduler:
             pack_classes.set(m.pack_classes)
             dom_carry.set(m.dom_carry_hits, result="hit")
             dom_carry.set(m.dom_carry_rebuilds, result="rebuild")
+            counted, shared = self.builder.csi_claim_counts()
+            csi_claims.set(counted, kind="counted")
+            csi_claims.set(shared, kind="shared")
             for q, depth in self.queue.depths().items():
                 pending.set(depth, queue=q)
             for state, count in self.node_lifecycle.stats()["states"].items():
@@ -1553,6 +1562,8 @@ class TPUScheduler:
                 pr = self.cache.pods.get(uid)
                 if pr is not None and pr.bound and pr.pod.spec.pod_group:
                     self._debit_gang(pr.pod.spec.pod_group)
+                if pr is not None:
+                    self._note_claims(pr.pod, -1)
         self.cache.remove_node(name)
         # Waiting gang members assumed on the removed node lost their
         # assumption (cache.remove_node vaporized their records): send them
@@ -1560,6 +1571,7 @@ class TPUScheduler:
         if rec is not None and self.permit_waiting:
             for qp, _n, _s, _f in self._drop_permit_waiters(set(rec.pods)):
                 self.queue.requeue_gang_member(qp)
+                self._note_claims(qp.pod, +1)  # pending again, so known again
         # A deleted node leaves the lifecycle/GC tracking maps — its
         # pods vanished with it, so there is nothing left to collect.
         self.node_lifecycle.forget_node(name)
@@ -1572,6 +1584,7 @@ class TPUScheduler:
         before this call (a hint frame's arrival, on the queue's clock)."""
         if not pod.spec.node_name and self._profile_for(pod) is None:
             return  # another scheduler's pod (responsibleForPod)
+        self._note_claims(pod, +1)
         if pod.spec.node_name:
             if pod.uid in self.cache.pods:
                 # Upsert of a known bound pod (watch re-delivery): route
@@ -1614,6 +1627,15 @@ class TPUScheduler:
         domain tensors on device — and fires POD_UPDATE so e.g. a waiting
         anti-affinity pod wakes when the blocking pod's label changes."""
         pr = self.cache.pods.get(pod.uid)
+        was = pr.pod if pr is not None else None
+        if was is None:
+            qp = self.queue._info.get(pod.uid)
+            was = qp.pod if qp is not None else None
+        if was is not None and was.spec.volumes != pod.spec.volumes:
+            # The claims it names changed: the known users follow.
+            self._note_claims(was, -1)
+            if self._profile_for(pod) is not None or pod.spec.node_name:
+                self._note_claims(pod, +1)
         if pr is not None:
             if pod.spec.node_name and pod.spec.node_name != pr.node_name:
                 # The upsert carries a DIFFERENT node: host truth rebound
@@ -1792,6 +1814,12 @@ class TPUScheduler:
                 self.builder.apply_external_claim(nrec.row, cuid, charges, -1)
         self._drain_dra_corrections()
         rec = self.cache.pods.get(uid)
+        gone = rec.pod if rec is not None else None
+        if gone is None:
+            qp_gone = self.queue._info.get(uid)
+            gone = qp_gone.pod if qp_gone is not None else None
+        if gone is not None:
+            self._note_claims(gone, -1)
         if rec is not None:
             # A bound gang member leaving drops its gang below quorum for
             # future Permit checks (ADVICE r1: gang_bound never decremented).
@@ -2591,6 +2619,10 @@ class TPUScheduler:
         from device time for the callers that meter them."""
         from .engine.pass_ import build_eval_pass
 
+        if self._predispatched is None:
+            # the propose path never runs schedule_batch: its claims'
+            # rows settle here (reserved sharers: reserve_proposed)
+            self._settle_csi_claims()
         batch, deltas, active = build_pod_batch(
             [pod], self.builder, profile, 1
         )
@@ -2922,6 +2954,7 @@ class TPUScheduler:
             "gang_reserve", uid=pod.uid, node=node_name, gang=gang
         )
         delta = self.builder.pod_delta_vectors(pod)
+        self._note_claims(pod, +1)  # a foreign pod is known from here on
         self.cache.assume_pod(pod, node_name, device_already=False, delta=delta)
         undos: list = []
         for rp in self._reserve_for(pod):
@@ -2932,6 +2965,7 @@ class TPUScheduler:
                 for rp2, u2 in reversed(undos):
                     rp2.unreserve(u2, self)
                 self.cache.forget_pod(pod.uid)
+                self._note_claims(pod, -1)
                 return False
             undos.append((rp, u))
         self._fleet_reserved[pod.uid] = {
@@ -2951,6 +2985,7 @@ class TPUScheduler:
         for rp, u in reversed(entry["undos"]):
             rp.unreserve(u, self)
         self.cache.forget_pod(uid)
+        self._note_claims(entry["pod"], -1)
 
     def commit_reserved(self, uid: str) -> ScheduleOutcome | None:
         """Phase 2: the binding becomes durable truth — journal the bind
@@ -3316,6 +3351,13 @@ class TPUScheduler:
         self._prefetched = None
         pd = self._predispatched
         self._predispatched = None
+        if pd is None:
+            # Nothing dispatched is uncommitted on the host (the last
+            # batch's group drained with its call): the one point at which
+            # the shared-claim rows may move.  A prefetched batch
+            # featurized under the old rows is refeaturized (csi_epoch is
+            # part of the feature version).
+            self._settle_csi_claims()
         if pd is not None:
             # A device pass dispatched one cycle early (the pipeline's
             # double buffer) — validated or re-dispatched below.  infos
@@ -3452,12 +3494,20 @@ class TPUScheduler:
             self.post_dispatch_hook()
             tr.step("ran post-dispatch hook")
         # Overlap featurize(k+1) with device(k) — the VERDICT r1 host
-        # ceiling.  Gated off when the active ops read mutable host
-        # catalogs (volume/DRA binds bump the feature version every
-        # batch, which would drop the prefetch anyway).
-        if self._prefetch_enabled and not ctx["active"] & {
-            "VolumeBinding", "DynamicResources"
-        }:
+        # ceiling.  Gated off when the active ops read host catalogs that
+        # every batch's binds mutate (DRA allocations bump the feature
+        # version every batch, which would drop the prefetch anyway; a
+        # volume batch bumps it only where a bind changes what a later
+        # pod's featurization reads, and the version check at dispatch
+        # drops the prefetch then), and while a claim's users crossed
+        # between one and several: its row settles with nothing in flight
+        # (_settle_csi_claims), so the next batch is featurized after this
+        # pass has committed.
+        if (
+            self._prefetch_enabled
+            and "DynamicResources" not in ctx["active"]
+            and not self.builder.csi_unsettled
+        ):
             nxt = self._pop_batch()
             if nxt:
                 # Prefetched gang members still count as "coming" for
@@ -3486,6 +3536,57 @@ class TPUScheduler:
             predispatched = self._predispatch_next()
         self._drain_pending(overlapped=predispatched)
         return out
+
+    def _settle_csi_claims(self) -> None:
+        """Let the shared-claim rows follow the claims' known users
+        (builder.settle_csi_claims), under the `pipeline/csi_settle` span.
+        Free when no claim's users crossed between one and several since
+        the last call, which is every batch of a workload whose pods each
+        have claims of their own.
+
+        Called only while no dispatched pass is uncommitted on the host.
+        A pod that names a claim ANOTHER pod of an in-flight pass names
+        arrives while that pass runs (the host does not know the first
+        pod's node yet): the claim stays unsettled, no batch is prefetched
+        behind the pass (_batch_traced_inner), the pass commits as it was
+        featurized, and the next batch starts here: a drain first, then a
+        row filled from where the first pod landed."""
+        b = self.builder
+        if not b.csi_unsettled:
+            return
+        with self.span("pipeline/csi_settle") as sp:
+            promoted, released = b.settle_csi_claims(self._claim_spots)
+            sp.set("promoted", promoted)
+            sp.set("released", released)
+
+    def _claim_spots(self, claim_uid: str, pod_uids):
+        """(node row, driver id), once for every pod of ``pod_uids`` whose
+        delta the host counts hold (bound or assumed: the cache's records)
+        and that names the claim."""
+        for uid in pod_uids:
+            pr = self.cache.pods.get(uid)
+            if pr is None:
+                continue
+            row = self.cache.nodes[pr.node_name].row
+            for cuid, did in pr.delta.get("csivols", ()):
+                if cuid == claim_uid:
+                    yield row, did
+
+    def _note_claims(self, pod: t.Pod, sign: int) -> None:
+        """A pod became known (+1: pending, in flight or bound) or left
+        (-1).  The one place the claims' known users are kept from
+        (builder.csi_users): a claim two known pods reference needs a row
+        of its own."""
+        if pod.spec.volumes:
+            self.builder.note_claim_users(pod, sign)
+
+    def restore_queue(self, state: dict) -> int:
+        """The journal's restore of the pending pods, which enter past
+        add_pod and are known all the same."""
+        n = self.queue.restore_state(state)
+        for qp in self.queue._info.values():
+            self._note_claims(qp.pod, +1)
+        return n
 
     def _pop_batch(self) -> list[QueuedPodInfo]:
         """Pop the next batch, and add to the open record's queue_wait how
@@ -3518,9 +3619,10 @@ class TPUScheduler:
         sample = (
             {} if self.metrics.registry.sample_plugins("featurize") else None
         )
+        info: dict = {}
         batch, deltas, active = build_pod_batch(
             [qp.pod for qp in infos], self.builder, profile, self.batch_size,
-            sample_into=sample,
+            sample_into=sample, info=info,
         )
         if sample:
             for op_name, secs in sample.items():
@@ -3528,6 +3630,10 @@ class TPUScheduler:
         return {
             "batch": batch, "deltas": deltas, "active": active,
             "version": self.builder.feature_version(),
+            # the one cache key the batch's pods share, where every row is
+            # the same row (the signature, with what it leaves out of the
+            # pods' claims put back), else None
+            "uniform_key": info["uniform_key"],
         }
 
     @staticmethod
@@ -3749,16 +3855,12 @@ class TPUScheduler:
         featsig = None
         if chunk > 1 and not self._truncated:
             # Template-batch flag for the pass's all-fail shortcut: every
-            # pod featurization-identical (pass_.py uniform_all).  Pods
-            # without a signature memo (pinned shapes) count as distinct.
-            sigs = {
-                getattr(qp.pod, "_featsig", None) or i
-                for i, qp in enumerate(infos)
-            }
-            uniform = len(sigs) == 1
-            work["batch"]["uniform_all"] = np.bool_(uniform)
-            if uniform:
-                featsig = getattr(infos[0].pod, "_featsig", None)
+            # pod featurization-identical (pass_.py uniform_all), which is
+            # the featurizer's to say (one cache key for the whole batch).
+            # Pods without a signature memo (pinned shapes) count as
+            # distinct, a lone one included.
+            featsig = work.get("uniform_key")
+            work["batch"]["uniform_all"] = np.bool_(featsig is not None)
         batch_d, inv_d, flag = self._pass_inputs(
             work, profile, inv, inv_d, inv_key, featsig
         )

@@ -29,6 +29,7 @@ import numpy as np
 
 from .api import types as t
 from .intern import InternTable
+from .volumes import claim_uids
 
 # Sentinel for "label value is not an integer" (Gt/Lt operators).
 INT_SENTINEL = np.int64(-(2**62))
@@ -66,7 +67,7 @@ class Schema:
     ET: int = 8  # existing-pod (anti-)affinity term rows
     VD: int = 8  # in-tree device-volume vocabulary rows
     DR: int = 8  # CSI driver vocabulary rows
-    CV: int = 8  # CSI volume unique-name vocabulary rows
+    CV: int = 8  # rows for CSI claims that several known pods SHARE (not one a claim)
     DC: int = 4  # DRA device-class vocabulary rows
     CLM: int = 8  # DRA claim vocabulary rows
     P: int = 8  # host-port (proto,ip,port) triple rows
@@ -129,7 +130,9 @@ class ClusterState:
     dev_rw_counts: jax.Array  # (VD, N) i32 — non-read-only uses of device d
     csi_used: jax.Array  # (DR, N) i32 — DISTINCT attached volumes per driver
     csi_limit: jax.Array  # (DR, N) i32 — CSINode allocatable count (default inf)
-    csivol_counts: jax.Array  # (CV, N) i32 — pods on node using CSI volume v
+    # (CV, N) i32 — pods on node using SHARED claim v.  A claim one known
+    # pod uses has no row: it is one of csi_used, nothing more.
+    csivol_counts: jax.Array
     dra_cap: jax.Array  # (DC, N) i32 — devices published per class (ResourceSlices)
     dra_alloc: jax.Array  # (DC, N) i32 — devices consumed by DISTINCT claims
     dra_claim_counts: jax.Array  # (CLM, N) i32 — pods on node reserving claim c
@@ -290,6 +293,29 @@ class SnapshotBuilder:
         # were made for.  Part of the device mirror: dropped wherever the
         # mirror is.
         self.resident: dict = {}
+        # CSI attach budget (NodeVolumeLimits).  The limit is over DISTINCT
+        # volumes a node (nodevolumelimits/csi.go), and a PV carries one
+        # claim_ref, so pods reach one volume only through one claim.  A
+        # claim that ONE known pod references (pending, in flight or bound)
+        # is therefore just one of csi_used[driver, node] while its pod is
+        # there: no table, upload or jit shape grows with such claims.
+        # Only a claim that TWO OR MORE known pods reference holds a row
+        # of csivol_counts, so that a volume already attached to a node is
+        # not counted twice there.  Rows follow the users lazily
+        # (settle_csi_claims), never while a pass is in flight.
+        #   csi_users: claim uid -> its one known pod's uid, or the set of
+        #     them (scheduler._note_claims keeps it; a bare string for the
+        #     one user, so that a claim of a pod's own costs no container);
+        #   csi_rows: shared claim uid -> row; csi_unsettled: claims whose
+        #     row does not follow their users yet; csi_epoch: bumped by
+        #     every promotion and release (feature_version).
+        # Where a claim's applied users are is not kept here: a promotion
+        # is rare and reads it off the cache's pod records.
+        self.csi_users: dict[str, str | set] = {}
+        self.csi_rows: dict[str, int] = {}
+        self._csi_free_rows: list[int] = []
+        self.csi_unsettled: set[str] = set()
+        self.csi_epoch = 0
 
     @property
     def _dirty_all(self) -> bool:
@@ -538,6 +564,7 @@ class SnapshotBuilder:
             self.volumes.epoch,
             self.dra.epoch,
             self.ns_epoch,
+            self.csi_epoch,
         )
 
     def clear_node_row(self, row: int) -> None:
@@ -579,13 +606,16 @@ class SnapshotBuilder:
                     own_terms.append(self.interns.term_id(cat, wt.weight, wt.term, pod.namespace))
         self._ensure(ET=len(self.interns.terms))
         # Volumes: in-tree device uses, CSI volume attachments, PVC refs.
-        # CSI attachments are keyed by volume UNIQUE NAME and deduped within
-        # the pod (nodevolumelimits/csi.go:219 — a claim referenced twice, or
-        # a volume shared with pods already on the node, attaches once; the
-        # presence check against csivol_counts happens at filter/commit time).
+        # CSI attachments are keyed by CLAIM and deduped within the pod
+        # (nodevolumelimits/csi.go:219 — a claim referenced twice, or a
+        # volume shared with pods already on the node, attaches once).  A
+        # claim of the pod's own carries no identity to the device (slot
+        # id -1: one more of csi_used); a shared claim carries its row of
+        # csivol_counts, and the presence check against it happens at
+        # filter/commit time.
         devices: list[tuple[int, bool]] = []
         pvc_uids: list[str] = []
-        csivols: dict[int, int] = {}  # volume id → driver id (dedup by volume)
+        csivols: dict[str, int] = {}  # claim uid → driver id (dedup by claim)
         # Any claim whose driver has a finite attach limit somewhere?  Such
         # pods defer behind same-node chunk-mates (shared per-driver budget).
         vol_csi_lim = False
@@ -618,7 +648,7 @@ class SnapshotBuilder:
                         # pods share a volume only through a shared PVC — and
                         # the claim key is stable across the unbound→bound
                         # transition (the PV name is not).
-                        csivols[self.interns.csivols.id(f"{driver}^{uid}")] = did
+                        csivols.setdefault(uid, did)
                         if (
                             did < self.schema.DR
                             and (self.host["csi_limit"][did] < 2**31 - 1).any()
@@ -627,7 +657,6 @@ class SnapshotBuilder:
         self._ensure(
             VD=len(self.interns.devices),
             DR=len(self.interns.drivers),
-            CV=len(self.interns.csivols),
         )
         # DRA claims, deduped by claim and accounted per DISTINCT claim like
         # CSI volumes: dra_alloc moves only on a claim's 0↔1 reservation
@@ -674,7 +703,10 @@ class SnapshotBuilder:
             "ports": ports,
             "own_terms": own_terms,
             "devices": devices,
-            "csivols": sorted(csivols.items()),
+            # (claim uid, driver id) in the pod's volume order.  The claim's
+            # row, if it has one, is looked up where the delta is applied
+            # (csi_rows), as the device's slot id was where it was featurized.
+            "csivols": list(csivols.items()),
             "pvcs": pvc_uids,
             "vol_unbound": vol_unbound,
             "vol_csi_lim": vol_csi_lim,
@@ -711,16 +743,114 @@ class SnapshotBuilder:
             prev = prev_by_kid[kid]
             if (sign > 0 and prev == 0) or (sign < 0 and prev == 1):
                 h["dra_alloc"][cid, row] += sign * cnt
-        for vid, did in delta.get("csivols", ()):
-            # Distinct-volume accounting: csi_used counts volumes whose
-            # per-node pod count crosses 0↔1, not pod references.
-            prev = h["csivol_counts"][vid, row]
-            h["csivol_counts"][vid, row] = prev + sign
-            if (sign > 0 and prev == 0) or (sign < 0 and prev == 1):
+        for uid, did in delta.get("csivols", ()):
+            rid = self.csi_rows.get(uid)
+            if rid is None:
+                # A claim of the pod's own: one distinct volume, here.
                 h["csi_used"][did, row] += sign
+            else:
+                # Distinct-volume accounting of a shared claim: csi_used
+                # counts it where its per-node pod count crosses 0↔1.
+                prev = h["csivol_counts"][rid, row]
+                h["csivol_counts"][rid, row] = prev + sign
+                if (sign > 0 and prev == 0) or (sign < 0 and prev == 1):
+                    h["csi_used"][did, row] += sign
         self.volumes.adjust_pvc_users(delta.get("pvcs", []), sign)
         if not device_already:
             self._dirty_rows.add(row)
+
+    # -- CSI claims: counted, or shared with a row ---------------------------
+
+    def _csi_mark(self, uid: str) -> None:
+        if isinstance(self.csi_users.get(uid), set) != (uid in self.csi_rows):
+            self.csi_unsettled.add(uid)
+        else:
+            self.csi_unsettled.discard(uid)
+
+    def note_claim_users(self, pod: t.Pod, sign: int) -> None:
+        """A pod the scheduler knows (pending, in flight or bound) came
+        (+1) or went (-1): keep csi_users.  Idempotent by pod uid, and
+        wrong only on the safe side: a user never noted leaves its claim
+        counted (two users on one node then count twice: pessimistic), one
+        never taken back leaves a row in use."""
+        users = self.csi_users
+        for uid in claim_uids(pod):
+            cur = users.get(uid)
+            if sign > 0:
+                if cur is None:
+                    users[uid] = pod.uid
+                    continue  # the common case: nothing to settle
+                if isinstance(cur, set):
+                    cur.add(pod.uid)
+                elif cur != pod.uid:
+                    users[uid] = {cur, pod.uid}
+            elif cur is None:
+                continue
+            elif isinstance(cur, set):
+                cur.discard(pod.uid)
+                if len(cur) == 1:
+                    users[uid] = next(iter(cur))
+            elif cur == pod.uid:
+                del users[uid]
+            self._csi_mark(uid)
+
+    def settle_csi_claims(self, spots) -> tuple[int, int]:
+        """Make csivol_counts' rows follow the claims' users: a claim that
+        two or more known pods reference is PROMOTED into a row, filled
+        from where its applied users are; a claim whose users fell back to
+        one or none is RELEASED and its row recycled.  ``spots(claim uid,
+        pod uids)`` yields (node row, driver id) once for every applied
+        user of the claim (the scheduler reads them off the cache's pod
+        records).  Returns (promoted, released).
+
+        Call only while no dispatched pass is uncommitted on the host (the
+        scheduler's _settle_csi_claims): a pod in flight was featurized
+        under the rows as they stood and will be applied under them as
+        they stand.  A change bumps csi_epoch, so features cached under the
+        old rows (and a prefetched batch) are dropped."""
+        if not self.csi_unsettled:
+            return 0, 0
+        promoted = released = 0
+        h = self.host
+        for uid in sorted(self.csi_unsettled):
+            users, rid = self.csi_users.get(uid), self.csi_rows.get(uid)
+            wants = isinstance(users, set)
+            if wants and rid is None:
+                rid = (
+                    self._csi_free_rows.pop()
+                    if self._csi_free_rows
+                    else len(self.csi_rows)
+                )
+                self._ensure(CV=rid + 1)
+                h = self.host
+                self.csi_rows[uid] = rid
+                for row, did in spots(uid, users):
+                    # Each applied user had charged csi_used by one; the
+                    # claim is ONE volume a node.
+                    if h["csivol_counts"][rid, row]:
+                        h["csi_used"][did, row] -= 1
+                    h["csivol_counts"][rid, row] += 1
+                    self._dirty_rows.add(row)
+                promoted += 1
+            elif not wants and rid is not None:
+                for row in np.nonzero(h["csivol_counts"][rid])[0]:
+                    # At most one applied user is left, and it goes on
+                    # charging csi_used by one.
+                    h["csivol_counts"][rid, row] = 0
+                    self._dirty_rows.add(int(row))
+                del self.csi_rows[uid]
+                self._csi_free_rows.append(rid)
+                released += 1
+        self.csi_unsettled.clear()
+        if promoted or released:
+            self.csi_epoch += 1
+        return promoted, released
+
+    def csi_claim_counts(self) -> tuple[int, int]:
+        """(counted, shared): known claims the budget holds as a per-node
+        count, and claims that hold a row."""
+        shared = len(self.csi_rows)
+        return max(len(self.csi_users) - shared, 0), shared
 
     # -- device mirror ---------------------------------------------------------
 

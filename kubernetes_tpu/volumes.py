@@ -27,6 +27,13 @@ REGION_KEYS = (
 NO_PROVISIONER = "kubernetes.io/no-provisioner"
 
 
+def claim_uids(pod: t.Pod) -> list[str]:
+    """The uid (namespace/name) of the claim behind each of the pod's
+    volumes that names one, in volume order, repeats included."""
+    ns = pod.namespace
+    return [f"{ns}/{v.pvc}" for v in pod.spec.volumes if v.pvc]
+
+
 @dataclass
 class VolumeCatalog:
     pvs: dict[str, t.PersistentVolume] = field(default_factory=dict)
@@ -99,10 +106,41 @@ class VolumeCatalog:
         self.epoch += 1
 
     def adjust_pvc_users(self, pvc_uids: list[str], delta: int) -> None:
+        """A pod using these claims was assumed (+1) or left (-1).  What
+        featurization reads of the count is whether a ReadWriteOncePod
+        claim has a user (VolumeRestrictions), so only such a claim's (or
+        an unknown one's) count is a catalog mutation: a batch of pods
+        with claims of other modes commits without dropping every cached
+        feature row behind it."""
         for uid in pvc_uids:
-            self.pvc_users[uid] = self.pvc_users.get(uid, 0) + delta
-        if pvc_uids:
-            self.epoch += 1
+            left = self.pvc_users.get(uid, 0) + delta
+            if left:
+                self.pvc_users[uid] = left
+            else:
+                self.pvc_users.pop(uid, None)
+            pvc = self.pvcs.get(uid)
+            if pvc is None or t.RWOP in pvc.access_modes:
+                self.epoch += 1
+
+    def claim_featsig(self, uid: str) -> tuple | None:
+        """What featurization reads of a claim apart from its name, where
+        that is the same for every claim like it, else None (the claim is
+        featurized by itself).  Pods that differ only in the names of such
+        claims share one featurization (engine/features.py): a bound claim
+        whose volume has no node affinity and no zone labels gives every
+        volume op the same answer whatever it is called."""
+        pvc = self.pvcs.get(uid)
+        if pvc is None:
+            return ("missing",)
+        if not pvc.volume_name:
+            return None  # candidates and provisioner topology: its own
+        pv = self.pvs.get(pvc.volume_name)
+        if pv is None:
+            return ("lost",)
+        if (pv.node_affinity is not None and pv.node_affinity.terms) or pv.labels:
+            return None  # PV topology compiles into the pod's programs
+        busy = t.RWOP in pvc.access_modes and self.pvc_users.get(uid, 0) > 0
+        return ("bound", pv.csi_driver, busy)
 
     # -- pod classification --------------------------------------------------
 

@@ -509,8 +509,8 @@ class FullOracleScheduler:
     ) -> list[Decision]:
         """``prefetch`` mirrors the engine's featurize-overlap: when on,
         this batch's preemption requeues land in batch k+2.  The engine
-        gates prefetch OFF for batches whose active ops read mutable host
-        catalogs (VolumeBinding/DynamicResources — scheduler.py
+        gates prefetch OFF for batches whose active ops read a host catalog
+        every batch mutates (DynamicResources; scheduler.py
         _batch_traced), so full-surface fixtures run both sides with
         prefetch=False (and the engine pinned off) for a deterministic
         alignment."""
