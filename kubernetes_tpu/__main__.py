@@ -394,10 +394,13 @@ def cmd_serve(args) -> int:
     # being terminated.  SIGKILL is the chaos harness's business.
     sched.flight.install_sigterm()
     # The collector's pauses, counted from here on
-    # (scheduler_gc_collections_total, scheduler_gc_pause_seconds_total).
+    # (scheduler_gc_collections_total, scheduler_gc_pause_seconds_total),
+    # and its old generation in the server's hands: frozen at every batch
+    # boundary, swept at the checkpoint (framework/tracing.py).
     from .framework.tracing import PROCESS
 
     PROCESS.hook_gc()
+    PROCESS.heap_armed = True
     try:
         srv.serve_forever()
     except KeyboardInterrupt:

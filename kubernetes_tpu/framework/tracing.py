@@ -30,6 +30,7 @@ the sidecar")."""
 
 from __future__ import annotations
 
+import gc
 import logging
 import os
 import time
@@ -414,6 +415,18 @@ NULL_SINK = SpanSink()
 # process, not only the pass variants the scheduler holds), and the
 # collector's pauses.  Totals are the process's; a scheduler's registry
 # exports them at scrape time.
+#
+# The collector's old generation is the process's too.  A full collection
+# walks every tracked object, and a scheduler's store only grows, so the
+# interpreter's own schedule spends a quarter of a backlog's window walking
+# pods that nothing will free.  `serve` (and nothing else: the collector is
+# process-global, a library user keeps the interpreter's defaults) arms the
+# policy below: every batch boundary that bound a pod runs a young
+# collection and freezes what is left, so automatic collections walk one
+# batch's worth; the full collection runs where the server already stops
+# to walk the whole store, at the checkpoint.  Frozen objects are still
+# freed by reference count; a cycle frozen before it became garbage waits
+# for the next checkpoint.
 
 # Fires around compile_or_get_cached: a load from the persistent cache
 # counts like a build, with the seconds it took.
@@ -427,6 +440,9 @@ class ProcessCounters:
         self.gc_collections = [0, 0, 0]
         self.gc_pause_s = 0.0
         self.gc_hooked = False
+        self.heap_armed = False
+        self.gc_freezes = 0
+        self.gc_sweep_reclaimed = 0  # unreachable objects the checkpoints' full collections found
         self._compile_hooked = False
         self._gc_t0 = 0.0
 
@@ -452,10 +468,26 @@ class ProcessCounters:
 
     def hook_gc(self) -> None:
         if not self.gc_hooked:
-            import gc
-
             gc.callbacks.append(self._on_gc)
             self.gc_hooked = True
+
+    def settle_heap(self) -> None:
+        """A batch boundary: what the batch made and dropped in a cycle (an
+        exception and its traceback, a closure) is reclaimed young, what
+        survives (the store, the queue, tickets in flight) leaves the
+        collector's walk.  ``gc.freeze`` is a list splice, whatever the
+        heap holds."""
+        gc.collect(1)
+        gc.freeze()
+        self.gc_freezes += 1
+
+    def sweep_heap(self) -> None:
+        """A checkpoint: the one full collection, so cyclic garbage that
+        was frozen lives one checkpoint's records at most."""
+        gc.unfreeze()
+        self.gc_sweep_reclaimed += gc.collect()
+        gc.freeze()
+        self.gc_freezes += 1
 
 
 PROCESS = ProcessCounters()

@@ -10,6 +10,7 @@ unschedulable pods back to the queue."""
 
 from __future__ import annotations
 
+import gc
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -713,8 +714,8 @@ class TPUScheduler:
             "Device allocator stats when the backend reports them.",
         )
         # Process counters (framework/tracing.PROCESS): every XLA program
-        # of the process, and the collector's pauses once `serve` hooked
-        # gc.callbacks.
+        # of the process, the collector's pauses once `serve` hooked
+        # gc.callbacks, and the frozen heap once `serve` armed it.
         PROCESS.hook_compiles()
         jax_compiles = reg.counter(
             "scheduler_jax_compiles_total",
@@ -734,6 +735,20 @@ class TPUScheduler:
             "Seconds the serving process spent inside collector runs.",
         )
 
+        gc_freezes = reg.counter(
+            "scheduler_gc_freezes_total",
+            "Times the serving process froze its heap: batch boundaries "
+            "that bound a pod, and checkpoints.",
+        )
+        gc_frozen = reg.gauge(
+            "scheduler_gc_frozen_objects",
+            "Objects outside the collector's walk (gc.get_freeze_count()).",
+        )
+        gc_reclaimed = reg.counter(
+            "scheduler_gc_sweep_reclaimed_total",
+            "Unreachable objects found by the checkpoints' full collections.",
+        )
+
         def collect(_reg) -> None:
             m = self.metrics
             jax_compiles.set(PROCESS.compiles)
@@ -742,6 +757,10 @@ class TPUScheduler:
                 for gen, n in enumerate(PROCESS.gc_collections):
                     gc_collections.set(n, generation=str(gen))
                 gc_pause.set(PROCESS.gc_pause_s)
+            if PROCESS.heap_armed:
+                gc_freezes.set(PROCESS.gc_freezes)
+                gc_frozen.set(gc.get_freeze_count())
+                gc_reclaimed.set(PROCESS.gc_sweep_reclaimed)
             # The reference's partitioning label set {scheduled,
             # unschedulable, error} (metrics.go:138): the cells sum to the
             # attempt total, so sum(rate(...)) dashboards stay honest.
@@ -918,6 +937,11 @@ class TPUScheduler:
             with self.span("snapshot/collect"):
                 state = journal_mod.scheduler_state(self)
             j.snapshot(state)
+            if PROCESS.heap_armed:
+                # The server stands still to walk the whole store anyway:
+                # the one place a full collection runs (tracing.py).
+                with self.span("snapshot/heap_sweep"):
+                    PROCESS.sweep_heap()
         return True
 
     def _note_slow_span(self, tr: Trace) -> None:
@@ -3303,6 +3327,9 @@ class TPUScheduler:
             # free when journaling is off or the log hasn't grown, and
             # the `pipeline/snapshot` span opens behind it.
             self.maybe_snapshot()
+            if PROCESS.heap_armed and acc["scheduled"]:
+                with self.span("pipeline/heap_settle"):
+                    PROCESS.settle_heap()
         finally:
             self._flight_acc = None
             wall = self.spans.close()

@@ -36,3 +36,27 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "faults: fault-injection matrix tests"
     )
+
+
+import gc  # noqa: E402
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def armed_heap():
+    """What `cmd_serve` arms (framework/tracing.py: the heap frozen at every
+    batch boundary that bound a pod, swept at the checkpoint), undone on
+    exit: the tests and files that run after see the interpreter's
+    collector."""
+    from kubernetes_tpu.framework.tracing import PROCESS
+
+    gc.unfreeze()  # an interpreter may start with a few hundred objects frozen
+    gc.collect()
+    PROCESS.heap_armed = True
+    try:
+        yield PROCESS
+    finally:
+        PROCESS.heap_armed = False
+        gc.unfreeze()
+        gc.enable()

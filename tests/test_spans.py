@@ -11,6 +11,7 @@ import os
 import re
 import tempfile
 import threading
+import weakref
 from collections import Counter
 
 import jax
@@ -393,6 +394,92 @@ def test_gc_counters_move_with_a_collection():
     text = s.metrics.registry.render_text()
     assert f'scheduler_gc_collections_total{{generation="2"}} {PROCESS.gc_collections[2]}' in text
     assert "scheduler_gc_pause_seconds_total " in text
+
+
+# -- the frozen heap: `serve`'s policy (framework/tracing.py; the `armed_heap`
+# fixture is conftest's) --------------------------------------------------------
+
+
+class _Knot:
+    """Garbage only the cycle collector can free."""
+
+    def __init__(self):
+        self.me = self
+
+
+def test_a_scheduler_built_in_process_never_freezes_the_heap(tmp_path):
+    frozen = gc.get_freeze_count()
+    s = _sched(tmp_path)
+    s.snapshot_every_records = 1  # a checkpoint behind every batch
+    s.add_node(_node("n0"))
+    s.add_pod(_pod("p0"))
+    (out,) = s.schedule_all_pending()
+    assert out.node_name == "n0" and s.journal.snapshots == 1
+    assert gc.get_freeze_count() == frozen and not PROCESS.heap_armed
+    names = {sp[0] for r in s.flight.records() if r["kind"] == "batch" for sp in r["spans"]}
+    assert not names & {"pipeline/heap_settle", "snapshot/heap_sweep"}
+    assert "scheduler_gc_frozen_objects " not in s.metrics.registry.render_text()
+
+
+def test_armed_boundary_freezes_after_a_bind_and_not_after_an_empty_poll(armed_heap):
+    s = _sched()
+    s.add_node(_node("n0"))
+    s.add_pod(_pod("p0"))
+    n0 = armed_heap.gc_freezes
+    (out,) = s.schedule_batch()
+    assert out.node_name == "n0"
+    assert armed_heap.gc_freezes == n0 + 1
+    frozen = gc.get_freeze_count()
+    assert frozen > 0
+    rec = s.flight.records()[-1]
+    (sp,) = _by_name(rec)["pipeline/heap_settle"]
+    assert sp[3] == -1  # top level: idle seconds are booked to it by name
+    assert s.schedule_batch() == []  # an empty poll
+    s.add_pod(_pod("too-big", cpu="64"))  # a batch that binds nothing
+    (out,) = s.schedule_batch()
+    assert not out.node_name
+    assert armed_heap.gc_freezes == n0 + 1 and gc.get_freeze_count() <= frozen
+
+
+def test_a_cycle_made_and_dropped_inside_a_batch_is_dead_at_the_boundary(armed_heap, monkeypatch):
+    s = _sched()
+    s.add_node(_node("n0"))
+    s.add_pod(_pod("p0"))
+    inner, knots = s._schedule_batch_inner, []
+
+    def knotted():
+        knots.append(weakref.ref(_Knot()))
+        return inner()
+
+    monkeypatch.setattr(s, "_schedule_batch_inner", knotted)
+    gc.disable()  # only the boundary's own young collection can do it
+    s.schedule_batch()
+    assert gc.get_freeze_count() > 0 and knots[0]() is None
+
+
+def test_a_checkpoint_falling_due_sweeps_the_frozen_heap_and_counts_it(armed_heap, tmp_path):
+    s = _sched(tmp_path)
+    s.snapshot_every_records = 1  # a checkpoint behind every batch
+    s.add_node(_node("n0"))
+    knot = weakref.ref(_Knot())
+    gc.freeze()  # garbage that was frozen before it died: no collection sees it
+    gc.collect()
+    assert knot() is not None
+    s.add_pod(_pod("p0"))
+    r0, f0 = armed_heap.gc_sweep_reclaimed, armed_heap.gc_freezes
+    s.schedule_batch()
+    assert s.journal.snapshots == 1
+    assert knot() is None and armed_heap.gc_sweep_reclaimed > r0
+    assert armed_heap.gc_freezes == f0 + 2  # the sweep's, then the boundary's
+    assert gc.get_freeze_count() > 0  # the live heap, frozen again
+    spans = s.flight.records()[-1]["spans"]
+    (sweep,) = [sp for sp in spans if sp[0] == "snapshot/heap_sweep"]
+    assert spans[sweep[3]][0] == "pipeline/snapshot"
+    text = s.metrics.registry.render_text()
+    assert f"scheduler_gc_freezes_total {armed_heap.gc_freezes}" in text
+    assert f"scheduler_gc_sweep_reclaimed_total {armed_heap.gc_sweep_reclaimed}" in text
+    frozen = float(re.search(r"^scheduler_gc_frozen_objects (\S+)$", text, re.M).group(1))
+    assert frozen > 0
 
 
 def test_full_ring_of_span_records_stays_far_under_the_frame_limit(tmp_path):
