@@ -42,6 +42,8 @@ from .metrics import Histogram, _labels_key
 
 logger = logging.getLogger("kubernetes_tpu")
 
+_TRACING = TraceAnnotation.is_enabled  # a profiler session is recording
+
 
 def new_id(nbytes: int = 8) -> str:
     """Random lowercase-hex id (the W3C traceparent shape, truncated)."""
@@ -292,14 +294,21 @@ class Span:
             self._rec = rec
         else:
             self._rec = None
-        self._ann = TraceAnnotation("sched/" + self.name, batch=sink.bid, **self._kw)
-        self._ann.__enter__()
+        # No profiler session: no annotation (it would record nothing; the
+        # check is a tenth of its cost, and spans fire once a frame).
+        ann = self._ann = (
+            TraceAnnotation("sched/" + self.name, batch=sink.bid, **self._kw)
+            if _TRACING() else None
+        )
+        if ann is not None:
+            ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
         t1 = self.t1 = time.perf_counter()
-        self._ann.__exit__(et, ev, tb)
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
         dur = self.dur_s = t1 - self.t0
         sink = self._sink
         rec = self._rec

@@ -270,14 +270,16 @@ class Journal:
             if c is not None:
                 self._crash_points(c, buf, grouped)
             blob = b"".join(buf)
-            t0 = time.perf_counter()
             try:
-                self._f.write(blob)
-                self._f.flush()
+                # inside a drain: a sibling of `drain/journal_append` and
+                # `drain/journal_fsync` under `pipeline/drain`
+                with self.spans.span("drain/journal_write", label="") as sp:
+                    self._f.write(blob)
+                    self._f.flush()
             except BaseException:
                 self._discard_partial_write()
                 raise
-            self.append_latency.observe(time.perf_counter() - t0)
+            self.append_latency.observe(sp.dur_s)
         except BaseException:
             self.seq -= len(buf)
             raise

@@ -18,6 +18,7 @@ import socket
 import socketserver
 import struct
 import threading
+import time
 
 from ..api import serialize
 from ..scheduler import ScheduleOutcome, TPUScheduler
@@ -80,10 +81,20 @@ def read_frame_resync(sock: socket.socket) -> pb.Envelope | None:
     can answer with an error response instead of severing the connection
     (one bad message must not drop its healthy sibling requests).  Only a
     length too absurd to discard is unrecoverable."""
+    n = _frame_length(sock)
+    return None if n is None else _frame_body(sock, n)
+
+
+def _frame_length(sock: socket.socket) -> int | None:
+    """The frame's 4-byte header (None at EOF): on a served connection,
+    the wait for the client."""
     header = _read_exact(sock, _LEN.size)
-    if header is None:
-        return None
-    (n,) = _LEN.unpack(header)
+    return None if header is None else _LEN.unpack(header)[0]
+
+
+def _frame_body(sock: socket.socket, n: int) -> pb.Envelope | None:
+    """The rest of ``read_frame_resync``: the payload of a frame whose
+    header said ``n`` bytes, and its parse."""
     if n > MAX_FRAME:
         if n > MAX_DISCARD:
             raise FrameError(
@@ -105,6 +116,58 @@ def read_frame_resync(sock: socket.socket) -> pb.Envelope | None:
     except Exception as exc:  # framing intact, payload garbage
         raise FrameError(f"unparseable frame: {exc}", recoverable=True)
     return env
+
+
+# A connection that has carried one of these is a served one: its reads are
+# timed (`wire/await`, `wire/read`).  A subscribed push stream's read waits
+# for EOF all its life and a scrape-only connection waits on its scraper, so
+# neither opens them: an annotation always open on another thread would
+# take the profiler's idle seconds over.
+SERVED_KINDS = frozenset({"schedule", "add", "remove"})
+
+
+class Ingest:
+    """What the served path waits for and what an object costs it, summed
+    as plain floats under the dispatch lock and written into the registry
+    at scrape time (the speculation counters' idiom: the hot path pays an
+    add, not a labelled counter update).  One a server: ``SidecarServer``
+    holds it."""
+
+    def __init__(self, registry) -> None:
+        self.await_s = 0.0  # `wire/await` on served connections
+        self.add_s = {"decode": 0.0, "scope": 0.0, "apply": 0.0}
+        self.added: dict[str, int] = {}
+        awaited = registry.counter(
+            "scheduler_wire_await_seconds_total",
+            "Seconds served connections waited for the client's next "
+            "frame header (span wire/await).",
+        )
+        add_s = registry.counter(
+            "scheduler_object_add_seconds_total",
+            "Seconds of AddObject frames by stage: decode, the speculative "
+            "frontend's scope (note_add), apply to the store.",
+        )
+        added = registry.counter(
+            "scheduler_objects_added_total",
+            "Cluster objects applied from AddObject frames, by kind (hints "
+            "are not objects).",
+        )
+
+        def collect(_reg) -> None:
+            awaited.set(self.await_s)
+            for stage, v in self.add_s.items():
+                add_s.set(v, stage=stage)
+            for kind, n in self.added.items():
+                added.set(float(n), kind=kind)
+
+        registry.add_collector(collect)
+
+    def note_add(self, kind: str, decode: float, scope: float, apply: float) -> None:
+        s = self.add_s
+        s["decode"] += decode
+        s["scope"] += scope
+        s["apply"] += apply
+        self.added[kind] = self.added.get(kind, 0) + 1
 
 
 class SidecarServer:
@@ -175,6 +238,7 @@ class SidecarServer:
         sched = self.scheduler
         front = self.frontend
         span = sched.span
+        self.ingest = ingest = Ingest(sched.metrics.registry)
         # The scheduler is a sequential state machine; connections are
         # threaded but dispatch is serialized (concurrency belongs to the
         # host side).
@@ -206,15 +270,31 @@ class SidecarServer:
                 finally:
                     conns.discard(self.request)
 
+            def _read_served(self):
+                """A served connection's read: the wait for the header,
+                then the payload and its parse, each a span (annotations
+                only: they end outside the dispatch lock).  Returns the
+                frame and the seconds waited."""
+                with span("wire/await", label="") as aw:
+                    n = _frame_length(self.request)
+                if n is None:
+                    return None, aw.dur_s
+                with span("wire/read", label=""):
+                    return _frame_body(self.request, n), aw.dur_s
+
             def _serve_frames(self) -> None:
-                subscribed = False
+                subscribed = served = False
                 malformed = sched.metrics.registry.counter(
                     "sidecar_malformed_frames_total",
                     "Client frames rejected as oversized or unparseable.",
                 )
                 while True:
+                    waited = 0.0
                     try:
-                        env = read_frame_resync(self.request)
+                        if served and not subscribed:
+                            env, waited = self._read_served()
+                        else:
+                            env = read_frame_resync(self.request)
                     except TimeoutError:
                         # Subscribed sockets carry a write timeout (push
                         # backpressure bound) which applies to this idle
@@ -247,6 +327,8 @@ class SidecarServer:
                         # the connection — the protocol violation is the
                         # client's.
                         return
+                    kind = env.WhichOneof("msg") or ""
+                    served = served or kind in SERVED_KINDS
                     out = pb.Envelope(seq=env.seq)
                     responded = False
                     # The wire's three boundaries: waiting for the
@@ -256,14 +338,19 @@ class SidecarServer:
                     with span("wire/lock_wait"):
                         lock.acquire()
                     try:
-                        with span("wire/dispatch", kind=env.WhichOneof("msg") or ""):
+                        with span("wire/dispatch", kind=kind):
                             responded = _dispatch(
                                 sched, env, out, front, self.request,
-                                health_extra,
+                                health_extra, ingest=ingest,
                             )
                     except Exception as exc:  # surface, don't kill the server
                         out.response.error = f"{type(exc).__name__}: {exc}"
                     finally:
+                        # after the dispatch: a scrape holds the waits
+                        # before the frames ahead of it, not its own (two
+                        # scrapes around a window leave out the client's
+                        # time after it, a profiler's stop among it)
+                        ingest.await_s += waited
                         lock.release()
                     if responded:
                         subscribed = True
@@ -342,6 +429,8 @@ def _dispatch(
     front=None,
     conn=None,
     health_extra: dict | None = None,
+    *,
+    ingest: Ingest,
 ) -> bool:
     """Handle one frame.  Returns True when the response was already
     written inside the dispatch lock (the subscribe handshake — its ack
@@ -468,21 +557,7 @@ def _dispatch(
                 front.add_hint_blob(env.add.object_json)
             out.response.SetInParent()
             return
-        if env.add.kind == "NamespaceLabels":
-            # {"namespace": ..., "labels": {...}} — the namespace informer
-            # feeding affinity namespaceSelector matching.
-            import json
-
-            data = json.loads(env.add.object_json)
-            if front is not None:
-                front.note_add("NamespaceLabels", data)
-            sched.builder.set_namespace_labels(data["namespace"], data["labels"])
-            out.response.SetInParent()
-            return
-        obj = serialize.from_json(env.add.kind, env.add.object_json)
-        if front is not None:
-            front.note_add(env.add.kind, obj)
-        getattr(sched, serialize.KINDS[env.add.kind][1])(obj)
+        _add_object(sched, env.add.kind, env.add.object_json, front, ingest)
         out.response.SetInParent()
     elif kind == "remove":
         if front is not None:
@@ -558,6 +633,31 @@ def _dispatch(
             fill_result(out.response.results.add(), o)
     else:
         raise ValueError(f"unhandled message {kind}")
+
+
+def _add_object(sched, obj_kind: str, raw: bytes, front, ingest) -> None:
+    """One cluster object in, in three stages: decoded, the speculative
+    frontend told which cached decisions survive it, applied to the store.
+    `objects/add` spans them (an annotation: once an object, no histogram)
+    and its own two clock readings bound the first and the last stage."""
+    with sched.span("objects/add", label="", kind=obj_kind) as sp:
+        if obj_kind == "NamespaceLabels":
+            # {"namespace": ..., "labels": {...}} — the namespace informer
+            # feeding affinity namespaceSelector matching.
+            import json
+
+            obj = json.loads(raw)
+        else:
+            obj = serialize.from_json(obj_kind, raw)
+        t1 = time.perf_counter()
+        if front is not None:
+            front.note_add(obj_kind, obj)
+        t2 = time.perf_counter()
+        if obj_kind == "NamespaceLabels":
+            sched.builder.set_namespace_labels(obj["namespace"], obj["labels"])
+        else:
+            getattr(sched, serialize.KINDS[obj_kind][1])(obj)
+    ingest.note_add(obj_kind, t1 - sp.t0, t2 - t1, sp.t1 - t2)
 
 
 def fill_result(r: pb.PodResult, o) -> pb.PodResult:

@@ -798,39 +798,57 @@ class SpeculativeFrontend:
     def _admit_hints(self, budget: int) -> None:
         if budget <= 0:
             return
+        if len(self.hints) < budget:
+            # Top up from the deferred blobs — only as many pods as this
+            # admission can use (the incremental-parse contract).  Its
+            # time is `hints/decode`'s, so it runs before admission opens.
+            self._parse_blobs(budget - len(self.hints))
         with self.sched.span("hints/admit"):
             self._admit_hints_timed(budget)
 
     def _admit_hints_timed(self, budget: int) -> None:
-        if len(self.hints) < budget:
-            # Top up from the deferred blobs — only as many pods as this
-            # admission can use (the incremental-parse contract).
-            self._parse_blobs(budget - len(self.hints))
+        """Three children, one interval each an admission: the priority
+        sort of the pool, the stale-hint filter with the pods built from
+        a dict (the decode `hints/decode` did not reach), the enqueue."""
         if not self.hints:
             return
+        span = self.sched.span
         # Both in-flight sets: the prefetched NEXT batch and the batch
         # currently dispatching (post_dispatch_hook runs inside it) —
         # re-admitting a member of either would double-commit it.
         in_flight = self._prefetched_uids() | self.sched._inflight_uids
         # Admit in QueueSort order (priority desc, arrival order) — the
         # host activeQ's comparator, so speculation follows its pop order.
-        order = sorted(
-            self.hints.items(), key=lambda kv: -self._hint_priority(kv[1][0])
-        )[:budget]
-        for uid, (obj, held) in order:
-            self.hints.pop(uid, None)
-            if (
-                uid in self.sched.cache.pods
-                or uid in self.cached
-                or uid in self.delivered
-                or uid in in_flight
-            ):
-                # Stale hint: the pod was meanwhile scheduled from the
-                # queue or is mid-flight in the prefetched batch (it rode
-                # in via a plain informer add too).  Re-admitting would
-                # double-commit it.
-                continue
-            self.sched.add_pod(self._hint_pod(obj), held_at=held)
+        with span("admit/sort", label="", pool=len(self.hints)):
+            order = sorted(
+                self.hints.items(), key=lambda kv: -self._hint_priority(kv[1][0])
+            )[:budget]
+        admit = []
+        try:
+            with span("admit/build", label="") as sp:
+                built = 0
+                for uid, (obj, held) in order:
+                    self.hints.pop(uid, None)
+                    if (
+                        uid in self.sched.cache.pods
+                        or uid in self.cached
+                        or uid in self.delivered
+                        or uid in in_flight
+                    ):
+                        # Stale hint: the pod was meanwhile scheduled from
+                        # the queue or is mid-flight in the prefetched batch
+                        # (it rode in via a plain informer add too).
+                        # Re-admitting would double-commit it.
+                        continue
+                    built += isinstance(obj, dict)
+                    admit.append((self._hint_pod(obj), held))
+                sp.set("pods", built)
+        finally:
+            # what was built is enqueued even where a later build raised,
+            # as the one loop this was did
+            with span("admit/enqueue", label=""):
+                for pod, held in admit:
+                    self.sched.add_pod(pod, held_at=held)
 
     def _run_batch(self, requested: t.Pod) -> None:
         _, held = self.hints.pop(requested.uid, (None, 0.0))

@@ -614,3 +614,235 @@ def test_named_scopes_are_in_the_lowered_pass():
                             lowered["debug"]))
     assert {"pass/eval", "pass/conflict", "pass/commit", "pass/eval/NodeResourcesFit"} <= scopes
     assert "pass/eval" not in lowered["plain"]  # metadata only: the program is the same text
+
+
+# -- PR 38: what the served path does with the device idle --------------------
+
+
+def _hinted(n, prefix="h", priority=lambda i: 0):
+    from kubernetes_tpu.api import serialize
+
+    pods = []
+    for i in range(n):
+        p = _pod(f"{prefix}{i}")
+        p.spec.priority = priority(i)
+        pods.append(p)
+    return pods, json.dumps([serialize.to_dict(p) for p in pods]).encode()
+
+
+@pytest.fixture(scope="module")
+def admitted_record(tmp_path_factory):
+    """A batch whose dispatch admitted hints (`_on_dispatched`) and whose
+    drain wrote a journal group."""
+    from kubernetes_tpu.sidecar.speculate import SpeculativeFrontend
+
+    s = _sched(str(tmp_path_factory.mktemp("j")))
+    for i in range(3):
+        s.add_node(_node(f"n{i}"))
+    f = SpeculativeFrontend(s)
+    pods, blob = _hinted(24)
+    f.add_hint_blob(blob)
+    f._run_batch(pods[0])
+    recs = [r for r in s.flight.records() if r["kind"] == "batch"]
+    return next(r for r in recs if any(sp[0] == "admit/build" for sp in r["spans"]))
+
+
+@pytest.mark.parametrize("name, parent", [
+    ("admit/sort", "hints/admit"), ("admit/build", "hints/admit"),
+    ("admit/enqueue", "hints/admit"), ("drain/journal_write", "pipeline/drain"),
+])
+def test_each_new_span_sits_under_its_parent_in_a_batch_record(admitted_record, name, parent):
+    rec = admitted_record
+    (sp,) = _by_name(rec)[name]
+    assert rec["spans"][sp[3]][0] == parent
+    if parent == "hints/admit":
+        # one interval an admission, in the order the admission runs
+        kids = [s[0] for s in rec["spans"] if s[3] == sp[3]]
+        assert kids == ["admit/sort", "admit/build", "admit/enqueue"]
+        if name == "admit/build":
+            # under a pass `hints/decode` built them first; only what it
+            # did not reach is built here (the miss's own admission)
+            assert sp[4] == {"pods": 0}
+    else:
+        # the group's one write + flush, between the append and the fsync
+        (append,) = _by_name(rec)["drain/journal_append"]
+        (fsync,) = _by_name(rec)["drain/journal_fsync"]
+        assert append[3] == sp[3] == fsync[3]
+        assert append[1] + append[2] <= sp[1] + 1 and sp[1] + sp[2] <= fsync[1] + 1
+        assert rec["journal"]["append_s"] == pytest.approx(sp[2] * 1e-6, abs=3e-6)
+
+
+def test_the_top_up_parse_runs_before_admission_opens():
+    from kubernetes_tpu.sidecar.speculate import SpeculativeFrontend
+
+    s = _sched()
+    f = SpeculativeFrontend(s)
+    _, blob = _hinted(6)
+    f.add_hint_blob(blob)
+    acc = {"phases": {}}
+    s.spans.open(acc)
+    f._admit_hints(4)  # the pool is empty: the top-up parses the blob
+    s.spans.close()
+    names = [sp[0] for sp in acc["spans"]]
+    assert names == ["hints/decode", "hints/admit", "admit/sort", "admit/build", "admit/enqueue"]
+    admit = names.index("hints/admit")
+    assert acc["spans"][0][3] == -1  # a sibling before admission, not its child
+    assert not [sp for sp in acc["spans"] if sp[3] == admit and sp[0] == "hints/decode"]
+    text = s.metrics.registry.render_text()
+    assert 'scheduler_phase_duration_seconds_count{phase="hints/admit"} 1' in text
+    assert 'scheduler_phase_duration_seconds_count{phase="hint_decode"} 1' in text
+    assert 'phase="admit/' not in text  # the children are annotations and record entries only
+
+
+def _admit_as_the_parent_did(f, budget):
+    """PR 37's `_admit_hints_timed`, one loop that filters, builds and
+    enqueues each pod in turn: the order the two-pass admission must keep."""
+    if len(f.hints) < budget:
+        f._parse_blobs(budget - len(f.hints))
+    in_flight = f._prefetched_uids() | f.sched._inflight_uids
+    order = sorted(f.hints.items(), key=lambda kv: -f._hint_priority(kv[1][0]))[:budget]
+    for uid, (obj, held) in order:
+        f.hints.pop(uid, None)
+        if uid in f.sched.cache.pods or uid in f.cached or uid in f.delivered or uid in in_flight:
+            continue
+        f.sched.add_pod(f._hint_pod(obj), held_at=held)
+
+
+def test_the_two_pass_admission_queues_what_the_one_loop_did_in_its_order():
+    from kubernetes_tpu.sidecar.speculate import SpeculativeFrontend
+
+    def frontend():
+        now = [1.0]
+        s = _sched(batch_size=64)
+        s.queue._clock = lambda: now[0]
+        s.add_node(_node("n0", cpu="64"))
+        f = SpeculativeFrontend(s)
+        pods, blob = _hinted(30, priority=lambda i: i % 3)
+        f.add_hint_blob(blob)  # held from 1.0
+        now[0] = 2.0
+        f._parse_blobs(20)
+        f._build_hints(7)  # some built, some still dicts
+        f.add_hint(_pod("late"))  # held from 2.0
+        # stale hints of each kind: bound in the mirror, cached, delivered
+        bound = _pod("h3")
+        bound.spec.node_name = "n0"
+        s.add_pod(bound)
+        f.hints[pods[3].uid] = (pods[3], 1.0)
+        f.cached[pods[4].uid] = None
+        f.delivered[pods[5].uid] = "n0"
+        return s, f
+
+    got, want = frontend(), frontend()
+    for budget in (9, 40):
+        got[1]._admit_hints(budget)
+        _admit_as_the_parent_did(want[1], budget)
+        assert list(got[1].hints) == list(want[1].hints)
+    queued = [[(qp.pod.uid, qp.held_at) for qp in s.queue.pop_batch(64)] for s, _ in (got, want)]
+    assert queued[0] == queued[1] and len(queued[0]) == 28  # 30 + late - the three stale
+
+
+class _Recorder:
+    """A stand-in for jax.profiler.TraceAnnotation: who opened what."""
+
+    seen: list = []
+
+    def __init__(self, name, **kw):
+        self.entry = (name[len("sched/"):], threading.get_ident(), kw)
+
+    def __enter__(self):
+        _Recorder.seen.append(self.entry)
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_the_wait_for_the_client_is_timed_on_served_connections_only(monkeypatch):
+    from kubernetes_tpu.framework import tracing
+
+    monkeypatch.setattr(tracing, "TraceAnnotation", _Recorder)
+    monkeypatch.setattr(tracing, "_TRACING", lambda: True)
+    _Recorder.seen = []
+    path = tempfile.mktemp(suffix=".sock")
+    sched = _sched()
+    srv = SidecarServer(path, scheduler=sched, speculate=True)
+    srv.serve_background()
+    served, sub, scrape = SidecarClient(path), SidecarClient(path), SidecarClient(path)
+    try:
+        sub.subscribe()
+        for i in range(2):
+            served.add("Node", _node(f"n{i}"))
+        pods = [_pod(f"p{i}") for i in range(3)]
+        served.add_pending_batch(pods)
+        assert served.schedule([pods[0]], drain=False)[0].node_name
+        scrape.metrics()
+        scrape.flight()
+        text = served.metrics()
+    finally:
+        for c in (served, sub, scrape):
+            c.close()
+        srv.close()
+    kinds, timed = {}, {}
+    for name, tid, kw in list(_Recorder.seen):
+        if name == "wire/dispatch":
+            kinds.setdefault(tid, set()).add(kw["kind"])
+        elif name in ("wire/await", "wire/read"):
+            timed.setdefault(tid, set()).add(name)
+    by_role = {frozenset(k): tid for tid, k in kinds.items()}
+    assert set(by_role) == {frozenset({"subscribe"}), frozenset({"metrics", "flight"}),
+                            frozenset({"add", "schedule", "metrics"})}
+    # the first frame tells a served connection apart; every read after it is timed
+    assert timed == {by_role[frozenset({"add", "schedule", "metrics"})]: {"wire/await", "wire/read"}}
+    waited = float(re.search(r"^scheduler_wire_await_seconds_total (\S+)$", text, re.M).group(1))
+    assert waited > 0
+
+
+def _scrape(client):
+    out = {}
+    for line in client.metrics().splitlines():
+        if line.startswith(("scheduler_object", "scheduler_objects_")):
+            key, _, val = line.rpartition(" ")
+            out[key] = float(val)
+    return out
+
+
+def test_each_object_added_counts_once_with_its_three_stages_and_a_hint_never():
+    from kubernetes_tpu.api.wrappers import make_pv, make_pvc
+
+    path = tempfile.mktemp(suffix=".sock")
+    sched = _sched()
+    srv = SidecarServer(path, scheduler=sched, speculate=True)
+    srv.serve_background()
+    client = SidecarClient(path)
+    stages = [f'scheduler_object_add_seconds_total{{stage="{s}"}}' for s in ("decode", "scope", "apply")]
+    added = 'scheduler_objects_added_total{kind="%s"}'
+    try:
+        before = _scrape(client)
+        assert not [k for k in before if k.startswith("scheduler_objects_added_total")]
+        steps = [
+            ("Node", lambda: client.add("Node", _node("n0"))),
+            ("PersistentVolumeClaim", lambda: client.add("PersistentVolumeClaim", make_pvc("c0"))),
+            ("PersistentVolume", lambda: client.add("PersistentVolume", make_pv("v0"))),
+            ("NamespaceLabels", lambda: client.set_namespace_labels("ns", {"a": "b"})),
+            ("Node", lambda: client.add("Node", _node("n1"))),
+            (None, lambda: client.add_pending_batch([_pod("h0"), _pod("h1")])),
+            (None, lambda: client.add("PendingPod", _pod("h2"))),
+        ]
+        for kind, send in steps:
+            send()
+            after = _scrape(client)
+            moved = {k: after.get(k, 0.0) - before.get(k, 0.0)
+                     for k in set(after) | set(before) if after.get(k, 0.0) != before.get(k, 0.0)}
+            if kind is None:  # hints are not objects
+                assert not moved, moved
+            else:
+                assert {k: v for k, v in moved.items() if "added" in k} == {added % kind: 1.0}
+                assert all(moved.get(k, 0.0) > 0 for k in stages), moved
+            before = after
+        client.remove("Node", "n1")
+        assert _scrape(client) == before  # a remove is not an add
+        with pytest.raises(RuntimeError):
+            client.remove("Nonsense", "x")
+        assert not [k for k in _scrape(client) if "Nonsense" in k]
+    finally:
+        client.close()
+        srv.close()
