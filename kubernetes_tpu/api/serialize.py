@@ -153,6 +153,17 @@ def pod_from_data(data: dict) -> t.Pod:
     return pod
 
 
+def pod_to_data(pod: t.Pod) -> dict:
+    """A Pod as plain JSON data, through the generated dumper: the same
+    bytes as ``to_dict`` once encoded, without its reflective walk.  The
+    write-ahead records encode their pod with it.
+
+    For immediate encoding only: the result shares the pod's tuples and
+    its dicts of primitives (labels, requests), so a caller must neither
+    keep it nor mutate it.  A caller that does takes ``to_dict``."""
+    return _codegen().dumper(t.Pod)(pod)
+
+
 def node_from_json(raw: bytes | str) -> t.Node:
     return build(t.Node, json.loads(raw))
 

@@ -550,7 +550,7 @@ class TPUScheduler:
         # the profiler's trace all read the same interval.
         self.spans = SpanSink(self._phase_hist)
         self.span = self.spans.span
-        self._serialize_s = 0.0  # serialize.to_dict seconds of the open drain
+        self._serialize_s = 0.0  # serialize.pod_to_data seconds of the open drain
         # The tpulint-clean companion of the upstream-parity
         # plugin_execution_duration_seconds exposition: same sampled
         # observations, scheduler_-prefixed family.
@@ -906,7 +906,7 @@ class TPUScheduler:
             from .api import serialize
 
             t0 = time.perf_counter()
-            pod_d = serialize.to_dict(pod)
+            pod_d = serialize.pod_to_data(pod)
             self._serialize_s += time.perf_counter() - t0
             data = {"uid": pod.uid, "node": node_name, "pod": pod_d}
             # Decision provenance rides the WAL: the device tie-break
@@ -1450,7 +1450,7 @@ class TPUScheduler:
         self._journal_append(
             "evict",
             uid=uid,
-            pod=serialize.to_dict(requeued),
+            pod=serialize.pod_to_data(requeued),
             reason=reason,
             ts=self._now(),
         )
@@ -4136,7 +4136,7 @@ class TPUScheduler:
                 {
                     "uid": qp.pod.uid,
                     "attempts": qp.attempts,
-                    "pod": serialize.to_dict(qp.pod),
+                    "pod": serialize.pod_to_data(qp.pod),
                 },
             )
         self.queue.quarantine(qp)

@@ -47,7 +47,7 @@ def bind_record(i: int) -> tuple:
     pod = serialize.pod_from_data(
         json.loads(pod_template().replace("{name}", f"pod-{i}"))
     )
-    data = {"uid": pod.uid, "node": f"node-{i % 5000}", "pod": serialize.to_dict(pod)}
+    data = {"uid": pod.uid, "node": f"node-{i % 5000}", "pod": serialize.pod_to_data(pod)}
     return pod, data
 
 
@@ -93,6 +93,7 @@ def main() -> None:
             "getsize_path": per_call_us(lambda: os.path.getsize(wal), n),
             "fstat_fd": per_call_us(lambda: os.fstat(fd).st_size, n),
             "read_epoch_lease": per_call_us(lambda: read_epoch(lease_path), n),
+            "pod_to_data_pod": per_call_us(lambda: serialize.pod_to_data(pod), n),
             "to_dict_pod": per_call_us(lambda: serialize.to_dict(pod), n),
             "json_dumps_record": per_call_us(
                 lambda: json.dumps(rec, separators=(",", ":")).encode(), n

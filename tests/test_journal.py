@@ -15,6 +15,7 @@ import zlib
 import pytest
 
 from kubernetes_tpu.api import serialize
+from kubernetes_tpu.api import types as t
 from kubernetes_tpu.api.wrappers import make_node, make_pod
 from kubernetes_tpu.faults import FaultPlan
 from kubernetes_tpu.framework.config import fit_only_profile
@@ -983,6 +984,109 @@ def test_group_bytes_identical_to_per_record_appends(tmp_path):
     assert seqs_grouped == seqs_one == list(range(1, 8))
     assert wal_bytes(tmp_path / "grouped") == wal_bytes(tmp_path / "one")
     assert (one.writes, grouped.writes) == (7, 1)
+
+
+# -- a record's pod: the generated dumper, byte for byte the reflective walk --
+
+_CONFIGS = os.path.join(REPO, "perfbench", "configs")
+
+
+def _config(config: str) -> dict:
+    with open(os.path.join(_CONFIGS, f"{config}.json")) as f:
+        return json.load(f)
+
+
+_TEMPLATE_CASES = [
+    f"{config}.{which}"
+    for config in sorted(n[: -len(".json")] for n in os.listdir(_CONFIGS) if n.endswith(".json"))
+    for which in ("template", "initial_template")
+    if which in _config(config)["pod"]
+]
+
+
+def _template_pod(config: str, which: str):
+    """The configuration's pod template filled as the benchmark fills it
+    (``perfbench.objects.Pods``: name, namespace, cycles), parsed as the
+    sidecar parses a hint."""
+    from perfbench import objects
+
+    pods = objects.Pods(_config(config), seed=3900000011, count=2, initial=1)
+    return serialize.pod_from_json(pods.jsons[0 if which == "initial_template" else 1])
+
+
+def _rich_pods():
+    """Pods of the builders that reach every kind of field a record holds."""
+    affinity = (
+        make_pod("aff", "team-a").label("color", "blue").req({"cpu": "1", "memory": "1Gi"})
+        .node_affinity_in("zone", ["z1", "z2"]).preferred_node_affinity_in("disk", ["ssd"], 3)
+        .pod_affinity_in("color", ["blue"], "zone")
+        .pod_anti_affinity_in("app", ["db"], "kubernetes.io/hostname")
+        .preferred_pod_affinity_in("tier", ["web"], "zone", weight=5, anti=True)
+        .ns_selector_pod_affinity_in("color", ["red"], "zone", "env", ["prod"])
+        .obj()
+    )
+    spread = (
+        make_pod("spread").label("color", "blue").req({"cpu": "100m"})
+        .spread_constraint(5, "zone", t.DO_NOT_SCHEDULE, "color", ["blue"], min_domains=2,
+                           match_label_keys=("pod-template-hash",))
+        .spread_constraint(1, "kubernetes.io/hostname", t.SCHEDULE_ANYWAY, "color", ["blue"])
+        .obj()
+    )
+    tolerations = (
+        make_pod("tol").req({"cpu": "1"}).toleration("dedicated", t.TOLERATION_OP_EQUAL, "gpu", "NoSchedule")
+        .toleration("node.kubernetes.io/unreachable", t.TOLERATION_OP_EXISTS, "", "NoExecute", seconds=30.0)
+        .host_port(8080).container_image("busybox:1.36", "pause:3.9").init_req({"cpu": "2"})
+        .overhead({"memory": "64Mi"}).node_selector({"pool": "a"}).scheduling_gate("gate")
+        .obj()
+    )
+    volumes = (
+        make_pod("vol", "ns-2").req({"cpu": "1"}).pvc_volume("claim-vol").device_volume("ebs-1", read_only=True)
+        .resource_claim("gpu-claim").resource_claim("nic-claim").pod_group("gang-a").priority(7)
+        .obj()
+    )
+    nominated = make_pod("nominated").req({"cpu": "1"}).priority(100).obj()
+    nominated.status.nominated_node_name = "n-3"
+    bound = make_pod("bound").req({"cpu": "1"}).node("n-1").start_time(12.5).obj()
+    return {"affinity": affinity, "spread": spread, "tolerations": tolerations,
+            "volumes": volumes, "nominated": nominated, "bound": bound}
+
+
+def _bind_record(encode, p) -> bytes:
+    """One bind record's payload as ``Journal.append`` encodes it, the
+    pod's data made by ``encode``."""
+    data = {"uid": p.uid, "node": "n-1", "pod": encode(p), "tie": 3}
+    return json.dumps({"e": 1, "q": 1, "t": "bind", "d": data}, separators=(",", ":")).encode()
+
+
+@pytest.mark.parametrize(
+    "case",
+    _TEMPLATE_CASES + sorted(_rich_pods()),
+)
+def test_bind_record_bytes_identical_to_reflective_encoding(case):
+    """A write-ahead record's pod goes through ``serialize.pod_to_data``
+    (the generated dumper); its bytes are what ``serialize.to_dict`` gave
+    for the same pod, so recovery and every reader of the log see no
+    change."""
+    if "." in case:
+        p = _template_pod(*case.split("."))
+    else:
+        p = _rich_pods()[case]
+    assert _bind_record(serialize.pod_to_data, p) == _bind_record(serialize.to_dict, p)
+
+
+def test_records_encoded_by_the_dumper_replay_to_the_same_pods(tmp_path):
+    """A group of bind records written through ``Journal`` with the
+    dumper's pods replays, through ``serialize.pod_from_data``, to pods
+    equal to the ones written."""
+    originals = list(_rich_pods().values())
+    j = Journal(str(tmp_path), epoch=1)
+    with j.group():
+        for p in originals:
+            j.append("bind", {"uid": p.uid, "node": "n-1", "pod": serialize.pod_to_data(p)})
+    _, recs, _ = Journal(str(tmp_path), epoch=2).replay()
+    back = [serialize.pod_from_data(r["d"]["pod"]) for r in recs]
+    assert [r["d"]["uid"] for r in recs] == [p.uid for p in originals]
+    assert back == originals
 
 
 _CRASH_CHILD = """

@@ -335,7 +335,7 @@ def _journaled_sched(td, pods=8, **journal_kw):
     return s, journal
 
 
-@pytest.mark.parametrize("fault", ["to_dict", "write"])
+@pytest.mark.parametrize("fault", ["pod_to_data", "write"])
 def test_failed_group_leaves_no_partial_group_and_resumes_once(fault, monkeypatch):
     """An exception inside the group's block (a pod that will not
     serialise) or an OSError from the group's one write leaves NO record
@@ -349,17 +349,17 @@ def test_failed_group_leaves_no_partial_group_and_resumes_once(fault, monkeypatc
         s, journal = _journaled_sched(td)
         events = []
         seen = {}
-        if fault == "to_dict":
-            real_to_dict = serialize.to_dict
+        if fault == "pod_to_data":
+            real_pod_to_data = serialize.pod_to_data
             state = {"calls": 0}
 
-            def poisoned(obj):
+            def poisoned(pod):
                 state["calls"] += 1
                 if state["calls"] == 3:
                     raise ValueError("pod will not serialise")
-                return real_to_dict(obj)
+                return real_pod_to_data(pod)
 
-            monkeypatch.setattr(serialize, "to_dict", poisoned)
+            monkeypatch.setattr(serialize, "pod_to_data", poisoned)
         else:
             journal._f = HalfWriteThenRaise(journal._f)
         real_drain = pipeline.drain_commit
