@@ -42,26 +42,50 @@ def prom(text: str) -> dict[str, float]:
     return out
 
 
-def cluster_pod_capacity(config: dict) -> int:
+def _requests(template: dict) -> dict:
+    return {k: sum(int(c.get("requests", {}).get(k, 0)) for c in template["spec"]["containers"])
+            for k in ("cpu", "memory")}
+
+
+def cluster_pod_capacity(config: dict, churn_pods: int = 0) -> int:
     """Pods of the configuration's templates the cluster can hold, by the
     arithmetic its file states: per node the least of cpu, memory and the
     pod limit, each resource at the larger request of the initial and the
     measured pods' template, and where the file states one a further cap a
     node (``capacity.pods_per_node_max``: an attach limit at one volume a
     pod, say), so that the plan never prebuilds pods the cluster has no
-    room for."""
+    room for.  Where the configuration recycles its measured pods
+    (``pod.recycle``) the room is that of the measured pods beside the
+    initial pods' load as the row places it (``initial_pods`` over the
+    nodes a node, rounded up, at the initial template's request), less what
+    ``churn_pods`` of the churn's template take over the run (each at most
+    one node's room): what one backlog has to fit into."""
     alloc = config["cluster"]["node_template"]["status"]["allocatable"]
     pod = config["pod"]
+    nodes = int(config["cluster"]["nodes"])
+    cap = (config.get("capacity") or {}).get("pods_per_node_max")
+    if pod.get("recycle"):
+        load = -(-int(config["initial_pods"]) // nodes)
+        held = _requests(pod.get("initial_template", pod["template"]))
+        req = _requests(pod["template"])
+        per_node = min([int(alloc["pods"]) - load]
+                       + [(int(alloc[k]) - load * held[k]) // v for k, v in req.items() if v])
+        if cap is not None:
+            per_node = min(per_node, int(cap) - load)
+        per_node = max(per_node, 0)
+        each = 0
+        if pod.get("churn"):
+            churn = _requests(pod["churn"]["template"])
+            each = min(per_node, max(-(-churn[k] // v) for k, v in req.items() if v))
+        return max(per_node * nodes - churn_pods * each, 0)
     req = {"cpu": 0, "memory": 0}
     for template in (pod["template"], pod.get("initial_template", pod["template"])):
-        for k in req:
-            req[k] = max(req[k], sum(int(c.get("requests", {}).get(k, 0))
-                                     for c in template["spec"]["containers"]))
+        for k, v in _requests(template).items():
+            req[k] = max(req[k], v)
     per_node = min([int(alloc["pods"])] + [int(alloc[k]) // v for k, v in req.items() if v])
-    cap = (config.get("capacity") or {}).get("pods_per_node_max")
     if cap is not None:
         per_node = min(per_node, int(cap))
-    return per_node * int(config["cluster"]["nodes"])
+    return per_node * nodes
 
 
 def bind_echo(config: dict) -> bool:
@@ -74,6 +98,33 @@ def bind_echo(config: dict) -> bool:
     return stated is not None
 
 
+def recycles(config: dict) -> bool:
+    """Whether the configuration removes a backlog's bound measured pods
+    before the next (``pod.recycle``: ``"measured"``); anything else it may
+    say is refused by name."""
+    stated = config["pod"].get("recycle")
+    if stated not in (None, "measured"):
+        raise SystemExit(f"perfbench: pod.recycle: 'measured' or absent, not {stated!r}")
+    return stated is not None
+
+
+# The set-up's share of an unlimited churn's prebuilt pods: its warm-up
+# takes seconds on a warm machine and minutes where the run compiles.
+CHURN_SETUP_S = 300.0
+
+
+def churn_count(config: dict, seconds: float) -> int:
+    """The churn pods a run prebuilds: the op's number, or where that is 0
+    (unlimited) as many as fall due in the set-up's allowance and the
+    window.  0 where the configuration has no churn."""
+    churn = config["pod"].get("churn")
+    if not churn:
+        return 0
+    if int(churn.get("number", 0)):
+        return int(churn["number"])
+    return int((seconds + CHURN_SETUP_S) * 1000.0 / float(churn["interval_ms"])) + 1
+
+
 def pods_needed(config: dict, mix: dict, seconds: float, n_open: int) -> dict:
     s = config["serve"]
     w = mix["warmup"]
@@ -84,8 +135,16 @@ def pods_needed(config: dict, mix: dict, seconds: float, n_open: int) -> dict:
         backlog = traffic.resolve(mix["backlog_pods"], config)
         est = (seconds + float(mix["prebuild_seconds_margin"])) * float(mix["prebuild_pods_per_s"])
         plan["backlog"] = backlog
-        room = cluster_pod_capacity(config) - initial - second
-        plan["window"] = min(int(est // backlog) + 2, room // backlog) * backlog
+        if recycles(config):
+            # one backlog (or the warm-up) at a time beside the initial pods
+            room = cluster_pod_capacity(config, churn_count(config, seconds))
+            if room < max(second, backlog):
+                raise loops.ClusterFull(f"room for {room} measured pods beside the initial pods and the churn, "
+                                        f"not for a backlog of {backlog} or a warm-up of {second}")
+            plan["window"] = (int(est // backlog) + 2) * backlog
+        else:
+            room = cluster_pod_capacity(config) - initial - second
+            plan["window"] = min(int(est // backlog) + 2, room // backlog) * backlog
     else:
         plan["window"] = n_open
     return plan
@@ -132,6 +191,13 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
     if mix["loop"] == "open" and (config["pod"].get("companions") or bind_echo(config)):
         raise SystemExit(f"perfbench: {cell['name']}: an open mix on a configuration whose pods have companions "
                          "or a bind echo is refused: only the closed loop sends them, until a cell needs more")
+    removes = bool(config["pod"].get("churn")) or recycles(config)
+    if removes and mix["loop"] == "open":
+        raise SystemExit(f"perfbench: {cell['name']}: an open mix on a configuration with a churn or a recycle "
+                         "is refused: only the closed loop drives them, until a cell needs more")
+    if removes and not getattr(correct.load_reference(config["reference"]), "PREEMPTION", False):
+        raise SystemExit(f"perfbench: {cell['name']}: a churn or a recycle removes pods, and the reference "
+                         f"{config['reference']!r} does not define PREEMPTION: it could not release them")
     out = os.path.join(out_root or os.path.join(root, ".perfbench_out"), cell["name"])
     shutil.rmtree(out, ignore_errors=True)
     os.makedirs(os.path.join(out, "flight"))
@@ -166,6 +232,13 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
         pods = objects.Pods(config, seed, total, plan["initial"])
         pod_json_by_uid = dict(zip(pods.uids, pods.jsons))
         companions = objects.Companions(config, nodes, pods, plan["initial"])
+        churn = removals = None
+        if config["pod"].get("churn"):
+            churn = loops.ChurnState(objects.Churn(config, seed, churn_count(config, seconds)),
+                                     int(config["pod"]["churn"].get("number", 0)))
+            pod_json_by_uid.update(zip(churn.pods.uids, churn.pods.jsons))
+        if removes:
+            removals = loops.Removals(objects.removal_frames)
 
         def hint_frame(a: int, z: int) -> bytes:
             return wire.pending_pods_frame(pods.jsons[a:z])
@@ -203,20 +276,37 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
 
         def take(w: loops.Window) -> None:
             for k, node in enumerate(w.nodes):
-                asked[pods.uids[w.first + k]] = node
+                if node is not None:  # None: deleted before it was asked for
+                    asked[pods.uids[w.first + k]] = node
 
         # Set-up traffic: the configuration's initial pods, then a warm-up
         # that uses every program of the window twice, over the same calls.
+        # A churn starts with the warm-up, as upstream's churn op runs after
+        # the initial pods and before the measured op; where the
+        # configuration recycles, the warm-up's pods leave before the window.
         cursor = 0
         sent_setup = {"objects": 0, "s": 0.0, "echoes": 0}
-        for (a, z), group_companions in zip(setup_groups, setup_companions):
+        churned = {"churn_pods": 0, "nominations": 0, "victims_removed": 0, "preempt_retries": 0,
+                   "recycle_objects": 0, "recycle_s": 0.0}
+        for g, ((a, z), group_companions) in enumerate(zip(setup_groups, setup_companions)):
             if z > a:
                 ws = loops.closed_loop(conn, r.push, pods, [hint_frame(a, z)], a, z - a, float("inf"),
-                                       max_backlogs=1, companions=group_companions, echo=echo)
+                                       max_backlogs=1, companions=group_companions, echo=echo,
+                                       churn=churn if g else None, removals=removals)
                 take(ws)
                 sent_setup["objects"] += ws.companion_objects
                 sent_setup["s"] += ws.companion_s
                 sent_setup["echoes"] += ws.echo_objects
+                for key in ("churn_pods", "nominations", "victims_removed", "preempt_retries"):
+                    churned[key] += getattr(ws, key)
+                if g and recycles(config):
+                    t0 = time.monotonic()
+                    kept = [pods.uids[ws.first + k] for k, n in enumerate(ws.nodes)
+                            if n and pods.uids[ws.first + k] not in removals.gone]
+                    if kept:
+                        removals.send(conn, r.push, kept)
+                    churned["recycle_objects"] += len(kept)
+                    churned["recycle_s"] += time.monotonic() - t0
                 cursor = z
         if warm_offsets:
             take(loops.open_loop(conn, r.push, pods, cursor, warm_offsets, hint_frame,
@@ -260,7 +350,8 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
         if mix["loop"] == "closed":
             w = loops.closed_loop(conn, r.push, pods, window_hint_frames, first_window,
                                   plan["backlog"], seconds, on_boundary=on_boundary,
-                                  companions=window_companions, echo=echo)
+                                  companions=window_companions, echo=echo,
+                                  churn=churn, removals=removals, recycle=recycles(config))
         else:
             w = loops.open_loop(conn, r.push, pods, first_window, offsets, hint_frame,
                                 float(mix["hint_flush_delay_s"]), on_boundary=on_boundary)
@@ -279,7 +370,10 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
         # readers see the batches closed before the stop began.
         before_stop = closed_before(records, marks.get("stop"))
         r.push.drain()
-        measured = set(pods.uids[w.first: w.first + w.asked])
+        measured = {pods.uids[w.first + k] for k, n in enumerate(w.nodes) if n is not None}
+        if churn is not None:
+            for j, (node, _due, _at) in enumerate(churn.answers):
+                asked[churn.pods.uids[j]] = node
         r.conn.close()
         r.conn = None
         rc = r.srv.stop(signal.SIGTERM, grace_s=60.0)
@@ -325,7 +419,11 @@ def run(root: str, cell: dict, config: dict, mix: dict, seed: int, seconds: floa
             "companions": {"of_nodes": companions.node_objects, "setup": sent_setup["objects"],
                            "setup_s": sent_setup["s"], "setup_echoes": sent_setup["echoes"]},
             "seconds": seconds, "push": {"frames": r.push.frames, "invalidations": r.push.invalidations,
-                                         "decided": len(commit_order)},
+                                         "decided": sum(1 for e in commit_order if len(e) == 2)},
+            "churn": None if churn is None else dict(
+                churned, prebuilt=len(churn.pods), asked=churn.next, exhausted=churn.exhausted),
+            "recycle": {"setup": churned["recycle_objects"], "setup_s": churned["recycle_s"]}
+            if recycles(config) else None,
         }
     finally:
         r.close()

@@ -16,6 +16,19 @@ was.  What such a reference holds the answers to (an attach limit, a claim
 bound to a volume that exists) it adds to ``over_capacity`` and
 ``infeasible``, as its configuration's guarantees state.
 
+A reference module that defines ``PREEMPTION = True`` is told, in the
+sidecar's own order, of more than binds (``wire.PushMap.order``): a
+preemption verdict through ``replay.nominate(uid, node, victims, facts)``,
+which recomputes the nomination plainly and says whether the node and the
+victim set are the ones it would choose (``preemption_mismatch`` counts
+those that are not: exact, limit 0, printed only where the reference
+preempts); a pod the host deleted, and a decision the sidecar took back
+before it was delivered, through ``replay.remove(uid)``, which releases
+what the pod held.  A pod that was answered and later deleted is lost to
+the journal only if ``recover`` brings it back.  A module without the name
+is called exactly as before, and a run whose configuration removes pods
+refuses it at the start (cell.run).
+
 Each number has a limit of its own; exact comparisons have the limit 0,
 ``score_gap_mean`` has the limit the configuration file carries, set from
 chip readings as PERF.md records.
@@ -25,7 +38,7 @@ from __future__ import annotations
 
 import os
 
-from . import spec
+from . import spec, wire
 
 
 def load_reference(name: str):
@@ -67,9 +80,28 @@ def compare(config: dict, node_jsons, node_names, pod_json_by_uid: dict,
     ref = load_reference(config["reference"])
     cluster, facts = stand_up(ref, node_jsons, node_names, companions)
     replay = ref.Replay(cluster)
+    preempts = bool(getattr(ref, "PREEMPTION", False))
     seen: dict[str, str] = {}
-    conflicts = 0
-    for uid, node in commit_order:
+    removed: set = set()
+    conflicts = mismatched = rolled_back = 0
+    for entry in commit_order:
+        uid, node = entry[0], entry[1]
+        if len(entry) == 3:  # an event beside the decisions (wire.PushMap.order)
+            if not preempts:
+                continue  # taken back and decided again: counted as it always was
+            what = entry[2]
+            if what in (wire.ROLLED_BACK, wire.REMOVED) and uid not in removed:
+                replay.remove(uid)  # what it held, a node or a nominated node's room, is free again
+            if what == wire.REMOVED:
+                removed.add(uid)
+            elif what == wire.ROLLED_BACK:
+                if uid in seen and uid not in removed:
+                    del seen[uid]  # it is decided again after this
+                    rolled_back += 1
+            else:
+                raw = pod_json_by_uid.get(uid)
+                mismatched += raw is None or not replay.nominate(uid, node, what, facts(uid, raw))
+            continue
         if not node:
             continue  # an unschedulable verdict binds nothing
         if uid in seen:
@@ -82,14 +114,17 @@ def compare(config: dict, node_jsons, node_names, pod_json_by_uid: dict,
             continue
         replay.step(uid, node, facts(uid, raw), uid in measured)
     unanswered = sum(1 for node in asked.values() if not node)
-    conflicts += sum(1 for uid, node in asked.items() if node and seen.get(uid, node) != node)
+    conflicts += sum(1 for uid, node in asked.items()
+                     if node and uid not in removed and seen.get(uid, node) != node)
     never_committed = sum(1 for uid, node in asked.items() if node and uid not in seen)
     if callable(recovered):
         recovered = recovered()
     if recovered is None:
         lost = len(asked)
     else:
-        lost = sum(1 for uid, node in asked.items() if node and recovered.get(uid) != node)
+        # a pod answered and later deleted is lost only if recovery brings it back
+        lost = sum(1 for uid, node in asked.items()
+                   if node and (recovered.get(uid) is not None if uid in removed else recovered.get(uid) != node))
     gaps = replay.gaps
     limits = config.get("correct", {})
     out = {
@@ -98,15 +133,19 @@ def compare(config: dict, node_jsons, node_names, pod_json_by_uid: dict,
         "answer_conflicts": {"value": conflicts + never_committed + replay.unknown_node, "limit": 0},
         "over_capacity_nodes": {"value": cluster.over_capacity(), "limit": 0},
         "infeasible": {"value": replay.infeasible, "limit": 0},
-        "score_gap_mean": {
-            "value": (sum(gaps) / len(gaps)) if gaps else float("inf"),
-            "limit": float(limits.get("score_gap_mean_limit", 0.0)),
-        },
+    }
+    if preempts:
+        out["preemption_mismatch"] = {"value": mismatched, "limit": 0}
+    out["score_gap_mean"] = {
+        "value": (sum(gaps) / len(gaps)) if gaps else float("inf"),
+        "limit": float(limits.get("score_gap_mean_limit", 0.0)),
     }
     info = {
         "compared": len(gaps), "score_gap_max": max(gaps) if gaps else None,
         "replayed": len(seen), "infeasible_examples": replay.examples,
     }
+    if preempts:
+        info.update(removed=len(removed), rolled_back=rolled_back)
     return {"numbers": out, "info": info}
 
 

@@ -37,6 +37,29 @@ The window rules live here and nowhere else:
           its pods; a system so fast that they are all answered before
           ``seconds`` closes its window there, early, and the window says
           so (``short``): the result line carries it, not a traceback.
+          Where the configuration gives the row's churn (``pod.churn``,
+          objects.Churn), it starts with the set-up's warm-up (upstream's
+          churn op runs before the measured op and through it, and the
+          preemption pass compiles there) and again at the window's
+          opening: the next churn pod falls due where a loop starts and
+          each after it one interval later, so the window's schedule does
+          not depend on how long the set-up took; at every point
+          where the loop would ask for its next pod, one churn pod that is
+          due is asked first, as the host's activeQ pops by priority: a
+          one-pod hint frame, then the pod from the map or over the wire.
+          A preemption verdict is acted on as the plugin's PostFilter
+          does: one RemoveObject a victim, in the answer's order (the
+          API DELETEs come back through the informer), and once they are
+          acknowledged the pod is asked for again, with its nominated node
+          in its status; it counts as unanswered if it has not bound after
+          PREEMPT_ASKS asks.  ``pods_per_s`` counts the measured pods only,
+          as upstream's collector counts the op with collectMetrics.
+          Where the configuration says so (``pod.recycle``: "measured"),
+          before each backlog after the first the previous backlog's
+          measured pods still bound are removed as pipelined RemoveObject
+          frames, and the seconds that takes are taken out of the window's
+          length: the harness fitting many backlogs into a cluster of the
+          row's own size, not the row's work.
   open    pods fall due on a schedule drawn before the window and are
           asked for in arrival order at their due time or when the loop
           is free, whichever is later.  The window closes when the last
@@ -51,9 +74,65 @@ import time
 from dataclasses import dataclass, field
 
 
+# A churn pod is asked for at most this many times: the first ask, the ask
+# after its victims are gone (the nominated node's room is held for it, so
+# this one binds), and two more for a nomination that had to be acted on
+# again because the room was taken in between; upstream requeues the pod on
+# each victim's delete, so it too asks again until the pod binds.
+PREEMPT_ASKS = 4
+
+
 class ClusterFull(RuntimeError):
     """The run has no prebuilt pods for a single backlog or for its
     arrivals: the plan outgrew the cluster's stated capacity."""
+
+
+class Removals:
+    """The pods the host deletes, over the set-up's loops and the window's:
+    ``frames(uids)`` makes their RemoveObject frames and their count,
+    ``gone`` holds every pod deleted so far (a victim the loop has not yet
+    asked for is not asked for: the informer's delete takes a pending pod
+    out of the host's queue)."""
+
+    def __init__(self, frames):
+        self.frames = frames
+        self.gone: set = set()
+
+    def send(self, conn, push, uids) -> None:
+        """Pipelined, acknowledged; in the push map's order after what the
+        sidecar pushed before them, and before the invalidations they cause."""
+        push.drain()
+        push.note_removed(uids)
+        conn.call_many(*self.frames(uids))
+        self.gone.update(uids)
+        push.drain()
+
+
+class ChurnState:
+    """The row's churn over the set-up's warm-up and the window: from each
+    ``start`` the next pod of ``pods`` (objects.Churn) falls due at once
+    and each after it one interval later; at most ``number`` of them
+    (every prebuilt pod where it is 0).  ``answers[j]`` = (node, "" where
+    it never bound; due; answered at)."""
+
+    def __init__(self, pods, number: int):
+        self.pods = pods
+        self.limit = min(int(number), len(pods)) if number else len(pods)
+        self.unlimited = not number
+        self.t0 = None
+        self.next = 0
+        self.answers: list = []
+
+    def start(self, now: float) -> None:
+        self.t0 = now - self.next * self.pods.interval_s
+
+    def due(self, now: float) -> bool:
+        return self.t0 is not None and self.next < self.limit and now >= self.t0 + self.next * self.pods.interval_s
+
+    @property
+    def exhausted(self) -> bool:
+        """An unlimited churn that used every prebuilt pod."""
+        return self.unlimited and self.next >= len(self.pods)
 
 
 @dataclass
@@ -71,6 +150,13 @@ class Window:
     companion_s: float = 0.0  # ... and the time their pipelined adds took
     echo_objects: int = 0  # closed loop, where the configuration states them: bind echoes posted inside the window
     echo_s: float = 0.0  # ... and the loop's own time in making and posting them
+    churn_pods: int = 0  # where the configuration has a churn: its pods asked for inside the window
+    nominations: int = 0  # ... preemption verdicts acted on
+    victims_removed: int = 0  # ... RemoveObject frames sent for their victims
+    preempt_retries: int = 0  # ... asks of a churn pod after its first
+    churn_due_to_bound_s: list = field(default_factory=list)  # ... each one's seconds from due to bound (None: never)
+    recycle_objects: int = 0  # where the configuration recycles: bound measured pods removed between backlogs
+    recycle_s: float = 0.0  # ... and the seconds that took, out of the window's length
     first: int = 0  # index of the first pod of the window
     nodes: list = field(default_factory=list)  # node per asked pod, in order
     answer_t: list = field(default_factory=list)  # clock at each answer
@@ -81,12 +167,14 @@ class Window:
 
     @property
     def seconds(self) -> float:
-        return self.t_close - self.t_open
+        return self.t_close - self.t_open - self.recycle_s
 
 
 def closed_loop(conn, push, pods, hint_frames, first: int, backlog: int,
                 seconds: float, clock=time.perf_counter, on_boundary=None,
-                max_backlogs: int | None = None, companions=None, echo=None) -> Window:
+                max_backlogs: int | None = None, companions=None, echo=None,
+                churn: ChurnState | None = None, removals: Removals | None = None,
+                recycle: bool = False) -> Window:
     """Backlogs of ``backlog`` pods from ``pods[first:]`` until the window
     rule closes it.  ``hint_frames[b]`` is the prebuilt PendingPods frame
     of the b-th backlog, ``companions[b]`` (where given) the prebuilt
@@ -97,25 +185,38 @@ def closed_loop(conn, push, pods, hint_frames, first: int, backlog: int,
     batch boundary, just before the wire call that starts a batch;
     ``first`` says that the call is the first after its backlog's hint
     frame, when nothing of the backlog is on the device yet (the traced
-    run starts its slice there)."""
+    run starts its slice there; a churn pod's ask is such a call too).
+    ``churn`` (where given) starts again with this loop, and ``removals`` deletes its
+    victims and, with ``recycle``, the previous backlog's bound pods.  A
+    pod deleted before it was asked for has ``None`` for its node."""
     w = Window(first=first)
     uids, frames = pods.uids, pods.frames
     nodes, answer_t = w.nodes, w.answer_t
     pop, drain = push.pop, push.drain
+    gone = removals.gone if removals is not None else ()
     t_open = w.t_open = clock()
+    if churn is not None:
+        churn.start(t_open)
     last = t_open
     i = first
     b = 0
     while True:
         now = clock()
-        if i > first and now - t_open >= seconds:
+        if i > first and now - t_open - w.recycle_s >= seconds:
             break
         if i + backlog > len(uids) or b >= len(hint_frames):
             if i == first:
                 raise ClusterFull(f"no pods prebuilt for one backlog of {backlog}")
-            w.short = (f"every prebuilt pod ({i - first}) was answered {now - t_open:.3f} s into a "
+            w.short = (f"every prebuilt pod ({i - first}) was answered {now - t_open - w.recycle_s:.3f} s into a "
                        f"window of {seconds} s: it closed there, early")
             break
+        if recycle and i > first:
+            t0 = clock()
+            bound = [u for u, n in zip(uids[i - backlog: i], nodes[i - backlog - first:]) if n and u not in gone]
+            if bound:
+                removals.send(conn, push, bound)
+            w.recycle_objects += len(bound)
+            w.recycle_s += clock() - t0
         if companions is not None and companions[b][1]:
             t0 = clock()
             conn.call_many(*companions[b])
@@ -126,8 +227,17 @@ def closed_loop(conn, push, pods, hint_frames, first: int, backlog: int,
         w.hint_s += clock() - t0
         w.hint_frames += 1
         b += 1
+        fresh = True  # no wire call has followed the hint frame yet
         for k in range(i, i + backlog):
+            if churn is not None and churn.due(clock()):
+                if _ask_churn(conn, push, churn, removals, w, clock, echo is not None,
+                              on_boundary and (lambda first: on_boundary(clock() - t_open, first and fresh))):
+                    fresh = False
             uid = uids[k]
+            if uid in gone:
+                nodes.append(None)
+                answer_t.append(last)
+                continue
             node = pop(uid)
             if node is None:
                 drain()
@@ -135,8 +245,9 @@ def closed_loop(conn, push, pods, hint_frames, first: int, backlog: int,
             if node is None:
                 now = clock()
                 if on_boundary is not None:
-                    on_boundary(now - t_open, k == i)
+                    on_boundary(now - t_open, fresh)
                     now = clock()
+                fresh = False
                 node = conn.schedule_raw(frames[k])
                 last = clock()
                 push.note(uid, node)
@@ -157,10 +268,59 @@ def closed_loop(conn, push, pods, hint_frames, first: int, backlog: int,
         i += backlog
         if max_backlogs is not None and b >= max_backlogs:
             break
-    w.asked = len(nodes)
+    w.asked = sum(1 for n in nodes if n is not None)
     w.bound = sum(1 for n in nodes if n)
     w.t_close = last
     return w
+
+
+def _ask_churn(conn, push, churn: ChurnState, removals: Removals, w: Window, clock, echo: bool,
+               boundary=None) -> int:
+    """The next churn pod, due now: its hint frame, then the pod, from the
+    map or over the wire, a preemption verdict acted on (module docstring).
+    ``boundary(first)`` runs before each ask, ``first`` for the first.
+    Returns how many times it was asked for over the wire."""
+    j = churn.next
+    churn.next += 1
+    cp = churn.pods
+    uid = cp.uids[j]
+    due = churn.t0 + j * cp.interval_s
+    conn.call_raw(cp.hint_frame(j))
+    node = push.pop(uid)
+    if node is None:
+        push.drain()
+        node = push.pop(uid)
+    frame = cp.frames[j]
+    asks = 0
+    while not node and asks < PREEMPT_ASKS:
+        if boundary is not None:
+            boundary(asks == 0)
+        asks += 1
+        r = conn.schedule_result(frame)
+        if r is not None and r.node_name:
+            node = r.node_name
+            push.note(uid, node)
+            push.drain()
+            break
+        push.drain()  # the batch that carried it committed before its verdict
+        if r is not None and r.nominated_node:
+            push.note_nomination(uid, r.nominated_node, r.victim_uids)
+            victims = list(r.victim_uids)
+            removals.send(conn, push, victims)
+            w.nominations += 1
+            w.victims_removed += len(victims)
+            frame = cp.nominated_frame(j, r.nominated_node)
+        else:
+            push.note(uid, "")
+    at = clock()
+    node = node or ""
+    if node and echo:
+        conn.post(cp.bound_frame(j, node))
+    w.churn_pods += 1
+    w.preempt_retries += max(asks - 1, 0)
+    w.churn_due_to_bound_s.append(at - due if node else None)
+    churn.answers.append((node, due, at))
+    return asks
 
 
 def open_loop(conn, push, pods, first: int, offsets, make_hint_frame,

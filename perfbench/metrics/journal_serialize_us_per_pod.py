@@ -1,7 +1,7 @@
 """layer: journal.  source: program_span (`serialize_us` accumulated on
-the `drain/journal_append` span: serialize.to_dict(pod) alone, two clock
-reads a pod).  moves: pods_per_s.  The part of the append that is not the
-journal's."""
+the `drain/journal_append` span: serialize.pod_to_data(pod), the generated
+Pod dumper, alone, two clock reads a pod).  moves: pods_per_s.  The part of
+the append that is not the journal's."""
 
 from perfbench import spanread
 

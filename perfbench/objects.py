@@ -34,6 +34,14 @@ A configuration may also state that a pod goes back bound once it is
 answered (``pod.bind_echo``: ``"answered"``; its ``assumed``
 names the source): ``Pods.bound_frame`` is that echo, the pod's own bytes
 with the node written in, made when the loop asks for it.
+
+A configuration may give the row's ``churn`` op (``pod.churn``: a pod
+template, an interval, a number, a namespace; upstream creates one pod of
+the template every interval, before the measured op and through it):
+``Churn`` holds those pods, named from the seed apart from the others.  A
+preemptor asked for again after its nomination carries the nominated node
+in its status (``Churn.nominated_frame``), as the pod does that the host
+scheduler requeues once it has recorded the nomination.
 """
 
 from __future__ import annotations
@@ -79,6 +87,16 @@ class Nodes:
 
 
 _UNBOUND = b'"node_name": ""'  # spec.node_name of a pending pod, as json.dumps writes it
+_UNNOMINATED = b'"nominated_node_name": ""'  # status.nominated_node_name, likewise
+
+
+def _written_in(raw: bytes, field: bytes, value: str, uid: str) -> bytes:
+    """``raw`` with the empty string field ``field`` set to ``value``; a pod
+    whose JSON does not hold the field empty exactly once is refused."""
+    if raw.count(field) != 1:
+        raise ValueError(f"pod {uid}: its template does not write {field.decode()} exactly once, "
+                         "so it cannot be echoed as bound or asked for as nominated")
+    return raw.replace(field, field[:-2] + b'"%s"' % value.encode())
 
 
 class _Frames:
@@ -131,11 +149,39 @@ class Pods:
         """Pod ``k``'s bind echo: the pod as the plugin forwards it once
         the host scheduler has bound it to ``node`` (an AddObject of the
         pod with ``spec.node_name`` set), ready to send."""
-        raw = self.jsons[k]
-        if raw.count(_UNBOUND) != 1:
-            raise ValueError(f"pod {self.uids[k]}: its template does not write spec.node_name as "
-                             f"{_UNBOUND.decode()} exactly once, so it cannot be echoed as bound")
-        return wire.add_frame("Pod", raw.replace(_UNBOUND, b'"node_name": "%s"' % node.encode()))
+        return wire.add_frame("Pod", _written_in(self.jsons[k], _UNBOUND, node, self.uids[k]))
+
+
+class Churn(Pods):
+    """``count`` pods of the configuration's churn template (``pod.churn``),
+    in its namespace, named from the seed on a stream of their own (``c-``
+    names), so that the other pods are drawn as without a churn.
+    ``hint_frame(j)`` is pod ``j`` as a one-pod PendingPods frame,
+    ``nominated_frame(j, node)`` its Schedule request once the host has
+    recorded its nomination."""
+
+    def __init__(self, config: dict, seed: int, count: int):
+        churn = config["pod"]["churn"]
+        text = json.dumps(churn["template"], sort_keys=True)
+        rng = random.Random((seed << 3) ^ 0xC4A2)
+        ns = churn["namespace"]
+        self.names = [f"c-{j}-{rng.getrandbits(32):08x}" for j in range(count)]
+        self.namespaces = [ns] * count
+        self.uids = [f"{ns}/{n}" for n in self.names]
+        self.jsons = [_fill(text, n, j, {}).replace("{namespace}", ns).encode() for j, n in enumerate(self.names)]
+        self.frames = _Frames(self.jsons)
+        self.interval_s = float(churn["interval_ms"]) / 1000.0
+
+    def hint_frame(self, j: int) -> bytes:
+        return wire.pending_pods_frame([self.jsons[j]])
+
+    def nominated_frame(self, j: int, node: str) -> bytes:
+        return wire.schedule_frame(_written_in(self.jsons[j], _UNNOMINATED, node, self.uids[j]))
+
+
+def removal_frames(uids) -> tuple[bytes, int]:
+    """RemoveObject frames of the pods ``uids``, in that order, and how many."""
+    return b"".join(wire.remove_frame("Pod", u) for u in uids), len(uids)
 
 
 class Companions:

@@ -22,7 +22,10 @@ class Ctx:
     ``companion_s``, the pods' companion objects sent inside the window and
     the seconds their adds took, ``echo_objects`` and ``echo_s``, the bind
     echoes posted inside it and the loop's own seconds in making and
-    posting them, beside ``hint_frames`` and ``hint_s``),
+    posting them, beside ``hint_frames`` and ``hint_s``; where the
+    configuration has them, the churn's ``churn_pods``, ``nominations``,
+    ``victims_removed``, ``preempt_retries`` and ``churn_due_to_bound_s``,
+    and the recycle's ``recycle_objects`` and ``recycle_s``),
     ``records`` the flight recorder's per-batch records of
     the window (of a traced run: those closed before the profiler's stop
     began; ``window_records`` has them all, for a reader that divides a
@@ -86,7 +89,7 @@ def build(bench: dict, raw: dict, traced: bool, rehearsal: bool, rate) -> tuple[
     # are among them), beside the files the cache gained
     jax_compiles = max(int(ctx.delta("scheduler_jax_compiles_total")), int((compiled1 or 0) - (compiled0 or 0)))
     compiled_in_window = (ce["window_close"] - ce["window_open"]) + jax_compiles
-    failed = sum(1 for n in w.nodes if not n)
+    failed = w.asked - w.bound
     result = {
         "correct": bool(correct.verdict(numbers)),
         "attempted": w.asked,
@@ -108,6 +111,15 @@ def build(bench: dict, raw: dict, traced: bool, rehearsal: bool, rate) -> tuple[
     sent = raw.get("companions") or {}
     result["companion_objects"] = sent.get("of_nodes", 0) + sent.get("setup", 0) + w.companion_objects
     result["companion_s"] = w.companion_s
+    # where the configuration has them: the churn's pods and what their
+    # preemptions did inside the window, and the pods recycled between
+    # backlogs with the seconds taken out of the window's length
+    if raw.get("churn") is not None:
+        for key in ("churn_pods", "nominations", "victims_removed", "preempt_retries", "churn_due_to_bound_s"):
+            result[key] = getattr(w, key)
+    if raw.get("recycle") is not None:
+        result["recycle_objects"] = w.recycle_objects
+        result["recycle_s"] = w.recycle_s
     result["compared"] = numbers
 
     recs = raw["records"]
@@ -146,6 +158,11 @@ def build(bench: dict, raw: dict, traced: bool, rehearsal: bool, rate) -> tuple[
         "push": raw["push"], "compare_info": raw["verdict"]["info"], "serve_rc": raw["serve_rc"],
         "device": device, "rehearsal": rehearsal,
     }
+    if raw.get("churn") is not None:
+        summary["churn"] = dict(raw["churn"], window={k: result[k] for k in (
+            "churn_pods", "nominations", "victims_removed", "preempt_retries")})
+    if raw.get("recycle") is not None:
+        summary["recycle"] = dict(raw["recycle"], window=w.recycle_objects, window_s=w.recycle_s)
     if w.due_t:
         # pods due and not yet answered, at the window's middle and at the
         # last due time: a queue that grows says the rate is past the knee
@@ -202,6 +219,11 @@ def earlier_lines(summary: dict) -> list[str]:
     if summary["window_short"]:
         out.append(f"perfbench: WARNING {summary['window_short']}: this run is not a measurement at the "
                    "length asked for (traffic/<mix>.json: prebuild_pods_per_s; the cluster's room)")
+    churn = summary.get("churn")
+    if churn and churn["exhausted"]:
+        out.append(f"perfbench: WARNING the churn used every one of its {churn['prebuilt']} prebuilt pods "
+                   f"({churn['window']['churn_pods']} of them in the window): it stopped before the window "
+                   "closed, so what followed is not the row's traffic (cell.CHURN_SETUP_S)")
     if summary["rehearsal"]:
         out.append("perfbench: CPU REHEARSAL at toy sizes: not a chip run")
     return out

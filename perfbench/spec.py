@@ -56,12 +56,22 @@ def metrics_for(bench: dict, group: str, cell_name: str) -> list[dict]:
 
 
 def shrink(config: dict, mix: dict) -> None:
-    """Toy sizes for a CPU rehearsal (tests).  The shapes stay."""
-    config["cluster"]["nodes"] = 600
+    """Toy sizes for a CPU rehearsal (tests).  The shapes stay: where the
+    configuration recycles its measured pods, its initial pods keep their
+    share of each node (the room a backlog has is what is left beside
+    them), and a cluster already under the toy size keeps its size, as a
+    chunk under the toy chunk keeps its own."""
+    if config["pod"].get("recycle"):
+        nodes = min(int(config["cluster"]["nodes"]), 600)
+        config["initial_pods"] = int(config["initial_pods"]) * nodes // int(config["cluster"]["nodes"])
+        config["cluster"]["nodes"] = nodes
+        config["measure_pods"] = min(int(config["measure_pods"]), 150)
+    else:
+        config["cluster"]["nodes"] = 600
+        config["initial_pods"] = 40
+        config["measure_pods"] = 150
     config["serve"]["batch_size"] = 64
-    config["serve"]["chunk_size"] = 8
-    config["initial_pods"] = 40
-    config["measure_pods"] = 150
+    config["serve"]["chunk_size"] = min(int(config["serve"]["chunk_size"]), 8)
     mix["warmup"]["short_pods"] = 20
     if mix["loop"] == "open":
         mix["warmup"]["arrivals_s"] = 1.0

@@ -73,6 +73,26 @@ def pending_pods_frame(pod_jsons, seq: int = 0) -> bytes:
     return add_frame("PendingPods", b"[" + b",".join(pod_jsons) + b"]", seq)
 
 
+def remove_frame(kind: str, uid: str, seq: int = 0) -> bytes:
+    """One ``RemoveObject``: what the plugin's informer sends when an
+    object is deleted (go/tpubatchscore/plugin.go, the pod informer's
+    DeleteFunc), ready to send."""
+    env = _pb().Envelope(seq=seq)
+    env.remove.kind = kind
+    env.remove.uid = uid
+    return frame(env)
+
+
+# What the push map's ``order`` holds beside a decision ``(uid, node)``: an
+# event ``(uid, node, what)``.  ``what`` is ROLLED_BACK (the sidecar took an
+# undelivered decision back: an invalidation), REMOVED (the host deleted the
+# pod: a RemoveObject the loop sent) or, for a preemption verdict, the
+# tuple of the victims' uids in the answer's order (``node`` is the
+# nominated node).
+ROLLED_BACK = "rolled_back"
+REMOVED = "removed"
+
+
 def _read_exact(sock: socket.socket, n: int) -> bytes:
     buf = bytearray()
     while len(buf) < n:
@@ -139,6 +159,13 @@ class Conn:
         """A prebuilt one-pod Schedule frame; the node name ("" = none)."""
         results = self.call_raw(data).results
         return results[0].node_name if results else ""
+
+    def schedule_result(self, data: bytes):
+        """A prebuilt one-pod Schedule frame; the whole ``PodResult``
+        (node, nominated node, victim uids and names), or None where the
+        sidecar gave no outcome for the pod."""
+        results = self.call_raw(data).results
+        return results[0] if results else None
 
     def add_many(self, kind: str, object_jsons) -> None:
         """Pipelined adds (the informer's initial list)."""
@@ -243,7 +270,7 @@ class PushMap:
         if ack.response.error:
             raise RuntimeError(ack.response.error)
         self.map: dict[str, str] = {}
-        self.order: list[tuple[str, str]] = []  # (uid, node) in commit order
+        self.order: list[tuple] = []  # (uid, node) in commit order, and the events beside them
         self.batches: list[int] = []  # decisions per push frame
         self.frames = 0
         self.invalidations = 0
@@ -313,10 +340,14 @@ class PushMap:
 
     def _apply(self, push) -> None:
         if push.invalidate_all:
+            # every decision the sidecar held undelivered: those this map
+            # has not handed out
+            self.order.extend((uid, "", ROLLED_BACK) for uid in self.map)
             self.map.clear()
             self.invalidations += 1
         for uid in push.invalidate_uids:
             self.map.pop(uid, None)
+            self.order.append((uid, "", ROLLED_BACK))
             self.invalidations += 1
         n = 0
         for d in push.decisions:
@@ -334,6 +365,17 @@ class PushMap:
         not the push stream; it takes its place in the commit order ahead
         of that batch's pushes, which are applied only after this."""
         self.order.append((uid, node))
+
+    def note_nomination(self, uid: str, node: str, victims) -> None:
+        """A preemption verdict on the wire: the sidecar chose it after the
+        batch that carried the pod had committed, so it takes its place
+        after that batch's pushes (drain first)."""
+        self.order.append((uid, node, tuple(victims)))
+
+    def note_removed(self, uids) -> None:
+        """Pods the host deleted (RemoveObject frames the loop sent), in the
+        order sent; the invalidations they cause are applied after this."""
+        self.order.extend((uid, "", REMOVED) for uid in uids)
 
     def close(self) -> None:
         self._closed = True

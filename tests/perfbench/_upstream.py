@@ -16,6 +16,11 @@ claim and a volume a pod, ``pod.companions``: access modes, capacity and
 request in bytes, the CSI driver, the binding both ways, the pod's volume
 naming its claim).  What upstream writes and the wire has no field for has
 to stand in the configuration's ``assumed.no_wire_field``, with the reason.
+A pod template's ``priority`` and ``preemptionPolicy`` are held to the
+wire's ``priority`` and ``preemption_policy``.  A ``churn`` op (mode
+``create``, one template: upstream's PreemptionAsync) is held to the
+configuration's ``pod.churn``: its template, interval, number and
+namespace; any other churn is refused by name.
 
 ``spreading_row`` writes a configuration in the shape of upstream's
 TopologySpreading row (two pod templates, a label strategy of three
@@ -100,20 +105,32 @@ def _hold_spread(constraints: list, wire_constraints: list) -> None:
         }
 
 
-def hold_pod(pod: dict, wire_pod: dict, spaces, volumes=()) -> None:
+# pod spec fields upstream's templates may write that the wire has no field for
+POD_NO_WIRE_FIELD = {"terminationGracePeriodSeconds"}
+
+
+def hold_pod(pod: dict, wire_pod: dict, spaces, volumes=()) -> set:
     """One upstream pod template against one wire template.  ``volumes``:
     what the wire's pod has to carry beyond the template, where the op
-    that creates it adds a volume to every pod."""
+    that creates it adds a volume to every pod.  Returns the fields met
+    for which the wire has none."""
     assert set(pod) == {"apiVersion", "kind", "metadata", "spec"} and pod["kind"] == "Pod"
     assert set(pod["metadata"]) <= {"generateName", "labels"}, sorted(pod["metadata"])
     assert wire_pod["metadata"] == {"annotations": {}, "labels": pod["metadata"].get("labels", {}),
                                     "name": "{name}", "namespace": "{namespace}", "uid": ""}
     assert set(wire_pod) == {"metadata", "spec", "status"} and wire_pod["status"] == POD_STATUS
     spec, wire_spec = pod["spec"], wire_pod["spec"]
-    assert set(spec) <= {"containers", "affinity", "topologySpreadConstraints"}, sorted(spec)
-    compared = {"containers", "affinity", "topology_spread_constraints"}
-    assert {k: v for k, v in wire_spec.items() if k not in compared} == dict(POD_SPEC_DEFAULTS, volumes=list(volumes))
+    assert set(spec) <= {"containers", "affinity", "topologySpreadConstraints", "priority",
+                         "preemptionPolicy"} | POD_NO_WIRE_FIELD, sorted(spec)
+    compared = {"containers", "affinity", "topology_spread_constraints", "priority", "preemption_policy"}
+    defaults = dict(POD_SPEC_DEFAULTS, volumes=list(volumes))
+    assert {k: v for k, v in wire_spec.items() if k not in compared} == \
+        {k: v for k, v in defaults.items() if k not in compared}
     assert compared <= set(wire_spec)
+    assert wire_spec["priority"] == spec.get("priority", POD_SPEC_DEFAULTS["priority"]), \
+        f"spec.priority: {wire_spec['priority']} for {spec.get('priority')}"
+    assert wire_spec["preemption_policy"] == spec.get("preemptionPolicy", POD_SPEC_DEFAULTS["preemption_policy"]), \
+        f"spec.preemptionPolicy: {wire_spec['preemption_policy']}"
     (cont,), (wcont,) = spec["containers"], wire_spec["containers"]
     assert set(cont) == {"image", "name", "ports", "resources"}, sorted(cont)
     assert set(wcont) == {"images", "limits", "name", "ports", "requests", "restart_policy"}
@@ -132,6 +149,7 @@ def hold_pod(pod: dict, wire_pod: dict, spaces, volumes=()) -> None:
     else:
         assert wire_spec["affinity"] is None
     _hold_spread(spec.get("topologySpreadConstraints", []), wire_spec["topology_spread_constraints"])
+    return POD_NO_WIRE_FIELD & set(spec)
 
 
 def _no_wire_field(doc: dict, found: set) -> None:
@@ -235,7 +253,32 @@ OP_KEYS = {
     "createNamespaces": {"opcode", "prefix", "count"},
     "createPods": {"opcode", "countParam", "podTemplatePath", "namespace", "collectMetrics",
                    "persistentVolumeTemplatePath", "persistentVolumeClaimTemplatePath"},
+    "churn": {"opcode", "mode", "number", "intervalMilliseconds", "templatePaths", "namespace"},
 }
+
+
+def _hold_churn(doc: dict, home: str, ops: list, measured: dict) -> set:
+    """The row's churn op against ``pod.churn``: upstream's defaults where
+    the op leaves a key out (an interval of 500 ms, no limit, the op's own
+    namespace-<index>).  Returns the fields met for which the wire has none."""
+    up, pod = doc["upstream"], doc["pod"]
+    churns = [op for op in ops if op["opcode"] == "churn"]
+    if not churns:
+        assert "churn" not in pod and "churn_template" not in up, "pod.churn without a churn op in the row"
+        return set()
+    assert len(churns) == 1, f"opcode churn: {len(churns)} churn ops; one is held"
+    (op,) = churns
+    paths = op.get("templatePaths") or []
+    assert op.get("mode") == "create" and len(paths) == 1, \
+        f"opcode churn: mode {op.get('mode')!r} with {len(paths)} template(s); only mode create with one template is held"
+    assert ops.index(op) < ops.index(measured), "opcode churn: after the measured op"
+    churn = pod.get("churn")
+    assert churn and set(churn) == {"template", "interval_ms", "number", "namespace"}, f"pod.churn: {churn}"
+    assert os.path.basename(up["churn_template"]) == os.path.basename(paths[0]), "upstream.churn_template"
+    assert churn["interval_ms"] == op.get("intervalMilliseconds", 500), f"intervalMilliseconds: {churn['interval_ms']}"
+    assert churn["number"] == op.get("number", 0), f"churn number: {churn['number']}"
+    assert churn["namespace"] == op.get("namespace", f"namespace-{ops.index(op)}"), f"churn namespace: {churn['namespace']}"
+    return hold_pod(_yaml(home, up["churn_template"]), churn["template"], [churn["namespace"]])
 
 
 def _hold_nodes(doc: dict, home: str, op: dict) -> set:
@@ -301,8 +344,7 @@ def hold(doc: dict, home: str, entry: dict | None = None) -> None:
     assert not {"persistentVolumeTemplatePath", "persistentVolumeClaimTemplatePath"} & set(creates[0]), \
         "persistentVolumeTemplatePath on the initial pods' op"
     volumes, more = _hold_volumes(doc, home, measured, spaces)
-    _no_wire_field(doc, missing | more)
-    hold_pod(_yaml(home, up["pod_template"]), pod["template"], spaces, volumes)
+    missing |= more | hold_pod(_yaml(home, up["pod_template"]), pod["template"], spaces, volumes)
     if paths[0] == paths[1] and volumes:
         # one template upstream, two on the wire: the initial pods' is the
         # measured pods' without the volume the op adds
@@ -313,7 +355,12 @@ def hold(doc: dict, home: str, entry: dict | None = None) -> None:
         assert "initial_template" not in pod and "initial_pod_template" not in up
     else:
         assert "initial_template" in pod and os.path.basename(up["initial_pod_template"]) == paths[0]
-        hold_pod(_yaml(home, up["initial_pod_template"]), pod["initial_template"], spaces)
+        missing |= hold_pod(_yaml(home, up["initial_pod_template"]), pod["initial_template"], spaces)
+    missing |= _hold_churn(doc, home, ops, measured)
+    # the recycle is the harness's, not the row's: the file says why
+    assert pod.get("recycle") in (None, "measured"), f"pod.recycle: {pod.get('recycle')!r}"
+    assert not pod.get("recycle") or doc["assumed"].get("recycle"), "pod.recycle without assumed.recycle"
+    _no_wire_field(doc, missing)
 
 
 SPREADING_EXCERPT = """\

@@ -3,6 +3,7 @@ the same command, a child of its own.  A rehearsal says it is not a chip
 run, and without --rehearsal the same command refuses the CPU."""
 
 import json
+import os
 
 import pytest
 
@@ -16,6 +17,21 @@ def _metric_names(group, cell):
     return {m["name"] for m in b[group] if "workloads" not in m or cell in m["workloads"]}
 
 
+def _stated(cell):
+    """What the cell's configuration states of the objects beside its pods:
+    companions, the bind echo, a churn, a recycle."""
+    for w in _pb.bench()["workloads"]:
+        if w["name"] == cell:
+            (c,) = [c for c in _pb.bench()["configs"] if c["name"] == w["config"]]
+            with open(os.path.join(_pb.ROOT, c["file"])) as f:
+                pod = json.load(f)["pod"]
+            return {k: bool(pod.get(k)) for k in ("companions", "bind_echo", "churn", "recycle")}
+
+
+CHURN_KEYS = {"churn_pods", "nominations", "victims_removed", "preempt_retries", "churn_due_to_bound_s"}
+RECYCLE_KEYS = {"recycle_objects", "recycle_s"}
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_rehearsal_of_each_cell(cell, tmp_path):
     trace = 1 if cell == CELLS[0] else 0
@@ -23,10 +39,26 @@ def test_rehearsal_of_each_cell(cell, tmp_path):
     assert rc == 0, err[-3000:]
     res = json.loads(out[-1])
     keys = set(res)
+    stated = _stated(cell)
     assert _pb.RESULT_KEYS <= keys
-    assert keys - _pb.RESULT_KEYS <= {"breakdown", "rehearsal", "compared", "companion_objects", "companion_s"}
-    # an accepted configuration has no companion objects and sends none
-    assert (res["companion_objects"], res["companion_s"]) == (0, 0.0)
+    assert keys - _pb.RESULT_KEYS <= {"breakdown", "rehearsal", "compared", "companion_objects", "companion_s",
+                                      "window_short"} | CHURN_KEYS | RECYCLE_KEYS
+    # the churn's and the recycle's counters where the configuration states them, and only there
+    assert (CHURN_KEYS <= keys) == stated["churn"] and bool(CHURN_KEYS & keys) == stated["churn"]
+    assert (RECYCLE_KEYS <= keys) == stated["recycle"] and bool(RECYCLE_KEYS & keys) == stated["recycle"]
+    timeline = json.loads(out[-2])["timeline"]
+    sent = timeline["companions"]
+    if not stated["companions"]:
+        # a configuration without companions sends none
+        assert (res["companion_objects"], res["companion_s"]) == (0, 0.0)
+        assert {k: v for k, v in sent.items() if "echo" not in k} == {
+            "of_nodes": 0, "setup": 0, "setup_s": 0.0, "window": 0, "window_s": 0.0}
+    else:
+        assert res["companion_objects"] > 0 and sent["window"] > 0 and res["companion_s"] == sent["window_s"] > 0
+    if stated["bind_echo"]:
+        assert sent["window_echoes"] == res["attempted"] - res["failed"] and sent["setup_echoes"] > 0
+    else:
+        assert (sent["setup_echoes"], sent["window_echoes"], sent["window_echo_s"]) == (0, 0, 0.0)
     assert list(res)[-1] == "compared"  # the numbers compared come last
     assert "not a chip run" in res["rehearsal"]
     assert res["device"]["platform"] == "cpu"
@@ -37,12 +69,9 @@ def test_rehearsal_of_each_cell(cell, tmp_path):
         assert f"compared {name} = " in err and set(v) == {"value", "limit"}
     exact = {k: v for k, v in res["compared"].items() if k != "score_gap_mean"}
     assert all(v["value"] == 0 and v["limit"] == 0 for v in exact.values()), exact
-    timeline = json.loads(out[-2])["timeline"]
     assert timeline["batches"] > 0
     assert timeline["compiled_in_window"] == 0, {k: timeline[k] for k in (
         "jax_compiles_in_window", "compiled_programs", "cache_entries")}
-    assert timeline["companions"] == {"of_nodes": 0, "setup": 0, "setup_s": 0.0, "setup_echoes": 0, "window": 0,
-                                      "window_s": 0.0, "window_echoes": 0, "window_echo_s": 0.0}
     assert timeline["os_cpu_count"] and timeline["compare_info"]["journal_bindings"] >= res["attempted"]
     group = "per_layer" if trace else "end_to_end"
     assert set(res["metrics"]) <= _metric_names(group, cell)

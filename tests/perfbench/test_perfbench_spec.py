@@ -259,8 +259,8 @@ COMPANION_ALTERATIONS = {
     "a_field_the_wire_lacks_left_unsaid": (lambda doc, home: doc["assumed"]["no_wire_field"].pop("migratedPlugins"),
                                            "no_wire_field"),
     "an_opcode_it_does_not_know": (_excerpt("  - opcode: createPods\n    countParam: $initPods\n",
-                                            "  - opcode: churn\n  - opcode: createPods\n    countParam: $initPods\n"),
-                                   "opcode churn"),
+                                            "  - opcode: barrier\n  - opcode: createPods\n    countParam: $initPods\n"),
+                                   "opcode barrier"),
     "an_ops_key_it_does_not_know": (_excerpt("    collectMetrics: true\n", "    collectMetrics: true\n    skipWaitToCompletion: true\n"),
                                     "skipWaitToCompletion"),
     "a_strategy_it_does_not_know": (_excerpt("    nodeAllocatableStrategy:", "    uniqueNodeLabelStrategy:\n      labelKey: x\n    nodeAllocatableStrategy:"),
