@@ -22,6 +22,7 @@ from ..api import types as t
 from ..framework.config import MAX_NODE_SCORE
 from ..snapshot import _bucket
 from .common import FeaturizeContext, OpDef, PassContext, feature_fill, register
+from .helpers import bounded_quotient
 
 MB = 1024 * 1024
 MIN_THRESHOLD = 23 * MB
@@ -70,7 +71,10 @@ def score_fn(state, pf, ctx: PassContext, feasible):
     max_threshold = MAX_CONTAINER_THRESHOLD * pf["il_ncontainers"]
     clamped = jnp.clip(sum_scores, MIN_THRESHOLD, max_threshold)
     denom = jnp.maximum(max_threshold - MIN_THRESHOLD, 1)
-    return MAX_NODE_SCORE * (clamped - MIN_THRESHOLD) // denom
+    # The clip holds every row's quotient in [0, MaxNodeScore].
+    return bounded_quotient(
+        MAX_NODE_SCORE * (clamped - MIN_THRESHOLD), denom, MAX_NODE_SCORE
+    )
 
 
 feature_fill("il_image_ids", -1)

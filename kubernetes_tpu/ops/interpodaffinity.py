@@ -53,6 +53,7 @@ from ..framework.config import MAX_NODE_SCORE
 from ..intern import term_key
 from ..snapshot import _bucket
 from .common import FeaturizeContext, OpDef, PassContext, feature_fill, register
+from .helpers import bounded_quotient
 
 # Existing-term categories (intern.term_id).
 CAT_REQ_AFF, CAT_REQ_ANTI, CAT_PREF_AFF, CAT_PREF_ANTI = 0, 1, 2, 3
@@ -342,8 +343,12 @@ def score_fn(state, pf, ctx: PassContext, feasible):
     mn = jnp.min(jnp.where(feasible, raw, big))
     mx = jnp.max(jnp.where(feasible, raw, -big))
     diff = mx - mn
+    # A feasible row's raw lies in [mn, mx], so its quotient is in
+    # [0, MaxNodeScore]; the infeasible rows are masked below.
     norm = jnp.where(
-        diff > 0, MAX_NODE_SCORE * (raw - mn) // jnp.maximum(diff, 1), 0
+        diff > 0,
+        bounded_quotient(MAX_NODE_SCORE * (raw - mn), jnp.maximum(diff, 1), MAX_NODE_SCORE),
+        0,
     )
     return jnp.where(feasible, norm, 0)
 

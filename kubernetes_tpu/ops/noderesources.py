@@ -27,6 +27,7 @@ from ..framework.config import (
     REQUESTED_TO_CAPACITY_RATIO,
 )
 from .common import FeaturizeContext, OpDef, PassContext, register
+from .helpers import bounded_quotient
 
 # Kind tags for strategy resource columns: where the "requested" number for a
 # resource comes from (resource_allocation.go:89 calculateResourceAllocatableRequest).
@@ -109,17 +110,21 @@ def _requested_totals(state, pf, cols):
 
 def _least_requested(alloc, reqd):
     # least_allocated.go:97 — ((capacity-requested)*MaxNodeScore)/capacity,
-    # 0 when capacity == 0 or requested > capacity. Int64 truncating division.
+    # 0 when capacity == 0 or requested > capacity. Int64 truncating division;
+    # on the `ok` rows 0 <= requested <= capacity, so the quotient is in
+    # [0, MaxNodeScore] and bounded_quotient is exact there.
     ok = (alloc > 0) & (reqd <= alloc)
     safe_alloc = jnp.maximum(alloc, 1)
-    return jnp.where(ok, ((alloc - reqd) * MAX_NODE_SCORE) // safe_alloc, 0)
+    score = bounded_quotient((alloc - reqd) * MAX_NODE_SCORE, safe_alloc, MAX_NODE_SCORE)
+    return jnp.where(ok, score, 0)
 
 
 def _most_requested(alloc, reqd):
     # most_allocated.go — requested*MaxNodeScore/capacity, 0 outside [0, cap].
     ok = (alloc > 0) & (reqd <= alloc)
     safe_alloc = jnp.maximum(alloc, 1)
-    return jnp.where(ok, (reqd * MAX_NODE_SCORE) // safe_alloc, 0)
+    score = bounded_quotient(reqd * MAX_NODE_SCORE, safe_alloc, MAX_NODE_SCORE)
+    return jnp.where(ok, score, 0)
 
 
 def _ratio_scorer(shape):
@@ -157,7 +162,10 @@ def score_fn(state, pf, ctx: PassContext, feasible=None):
         present = alloc > 0
         node_score += jnp.where(present, scorer(alloc, reqd) * weight, 0)
         weight_sum += jnp.where(present, weight, 0)
-    return jnp.where(weight_sum > 0, node_score // jnp.maximum(weight_sum, 1), 0)
+    # Each scorer is in [0, MaxNodeScore] and each weight in [1, 100], so
+    # node_score <= MaxNodeScore * weight_sum on every row.
+    quotient = bounded_quotient(node_score, jnp.maximum(weight_sum, 1), MAX_NODE_SCORE)
+    return jnp.where(weight_sum > 0, quotient, 0)
 
 
 def balanced_score_fn(state, pf, ctx: PassContext, feasible=None):

@@ -47,7 +47,7 @@ from ..api import types as t
 from ..framework.config import MAX_NODE_SCORE
 from ..snapshot import _bucket
 from .common import FeaturizeContext, OpDef, PassContext, feature_fill, register
-from .helpers import domain_tables
+from .helpers import bounded_quotient, domain_tables
 from . import nodeaffinity, tainttoleration
 
 from ..intern import InternTable
@@ -314,10 +314,15 @@ def score_fn(state, pf, ctx: PassContext, feasible):
     mn = jnp.min(jnp.where(scored, raw, big))
     mn = jnp.where(scored.any(), mn, 0)
     mx = jnp.max(jnp.where(scored, raw, 0))
+    # A scored row's raw lies in [mn, mx] with mn >= 0, so mx + mn - raw is
+    # in [0, mx] and the quotient in [0, MaxNodeScore]; the other rows are
+    # masked below.
     norm = jnp.where(
         mx == 0,
         MAX_NODE_SCORE,
-        MAX_NODE_SCORE * (mx + mn - raw) // jnp.maximum(mx, 1),
+        bounded_quotient(
+            MAX_NODE_SCORE * (mx + mn - raw), jnp.maximum(mx, 1), MAX_NODE_SCORE
+        ),
     )
     norm = jnp.where(scored, norm, 0)
     # No soft constraints → plugin is skipped (PreScore returns Skip):
